@@ -443,6 +443,9 @@ def main(argv=None):
              "(merges under 'prefix_cache')",
     )
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rates = (
         [float(r) for r in args.rates.split(",")] if args.rates else None
     )

@@ -72,8 +72,8 @@ def _bench_paged_attn_rows(rows, record):
     b, kvs, g, hd, ps, mp = 8, 4, 2, 64, 16, 8
     pool_pages = b * mp
     q = jnp.asarray(rng.randn(b, kvs, g, hd).astype(np.float32))
-    kp = jnp.asarray(rng.randn(pool_pages, ps, kvs, hd).astype(np.float32))
-    vp = jnp.asarray(rng.randn(pool_pages, ps, kvs, hd).astype(np.float32))
+    kp = jnp.asarray(rng.randn(pool_pages, kvs, ps, hd).astype(np.float32))
+    vp = jnp.asarray(rng.randn(pool_pages, kvs, ps, hd).astype(np.float32))
     pt = jnp.asarray(
         rng.permutation(pool_pages).reshape(b, mp).astype(np.int32)
     )
@@ -657,6 +657,9 @@ def main(argv=None):
              "Chrome-trace JSON (open in https://ui.perfetto.dev)",
     )
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for n, us, derived in run(
         smoke=args.smoke, kv_path=args.kv_path, paged_attn=args.paged_attn,

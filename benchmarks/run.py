@@ -13,6 +13,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_apsd, bench_bvq, bench_e2e, bench_kernels, bench_lru,
         bench_server, bench_serving, roofline_report,

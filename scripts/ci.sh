@@ -193,9 +193,10 @@ else
 fi
 
 echo "== tier-1 tests (gate) =="
-# Mesh-dependent tests in test_launch.py / test_models.py run on every JAX
-# via launch/mesh.py:activate_mesh (presence-keyed jax.set_mesh ->
-# jax.sharding.use_mesh -> legacy Mesh-context fallback); only the
+# Mesh-dependent tests in test_launch.py / test_models.py build their
+# meshes through launch/mesh.py (Auto axes, jax.set_mesh); only the
 # genuinely multi-device test_substrate.py case stays skipif-guarded on
-# single-device CPU, so the whole suite gates.
+# single-device CPU, so the whole suite gates.  tests/test_tpu_compile.py
+# compiles the serving kernels for a described v5e; it skips where no TPU
+# compiler is installed.
 python -m pytest -x -q

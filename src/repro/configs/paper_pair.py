@@ -1,6 +1,11 @@
 """The paper's own TLM/DLM serving pair (Fig. 31.1.6 system config):
 LLaMA2-7B target + LLaMA-68M-class draft.  These run the W4A8+LRU (TLM)
-and BVQ (DLM) serving paths in serving/quantized_lm.py."""
+and BVQ (DLM) serving paths in serving/quantized_lm.py.
+
+``PAIRS`` names the two widths ``launch/serve.build_pair`` builds: the
+published pair ("paper") and its smoke-scale stand-in ("smoke", what the
+CPU tests serve), each with the BVQ settings of its draft."""
+from repro.core.bvq import BVQConfig
 from repro.models.common import Family, ModelConfig
 
 TLM = ModelConfig(
@@ -24,3 +29,19 @@ DLM_SMOKE = ModelConfig(
     n_layers=2, d_model=64, n_heads=4, n_kv=4, d_ff=128, vocab=512,
     dtype="float32",
 )
+
+# BVQ of the draft: the paper's v=8, C=256 codebooks over 128-column blocks
+# (~1 index bit per weight); k-means only — QAT refinement tunes trained
+# weights, and served weights here are random
+DLM_BVQ = BVQConfig(
+    vec_dim=8, codebook_size=256, block_cols=128, kmeans_iters=16, qat_steps=0
+)
+DLM_SMOKE_BVQ = BVQConfig(
+    vec_dim=4, codebook_size=64, block_cols=32, kmeans_iters=8, qat_steps=0
+)
+
+# widths name -> (target config, draft config, draft BVQ settings)
+PAIRS = {
+    "paper": (TLM, DLM, DLM_BVQ),
+    "smoke": (TLM_SMOKE, DLM_SMOKE, DLM_SMOKE_BVQ),
+}

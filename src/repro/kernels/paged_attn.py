@@ -24,11 +24,18 @@ Layout (one grid step = one (request, kv-head) pair x one page):
                                      W window tokens just written
   q          (B, KVS, G, hd)       — single decode token (W = 1), or
              (B, W, KVS, G, hd)    — multi-token verify window
-  k_pool     (P, page_size, KVS, hd)
-  v_pool     (P, page_size, KVS, hd)
-  k_scale    (P, page_size, KVS, 1) f32, optional — per-slot-per-head
-  v_scale    (P, page_size, KVS, 1) f32, optional   dequant scales
+  k_pool     (P, KVS, page_size, hd)   — head-major pages
+  v_pool     (P, KVS, page_size, hd)
+  k_scale    (P, KVS, page_size, 1) f32, optional — per-slot-per-head
+  v_scale    (P, KVS, page_size, 1) f32, optional   dequant scales
   out        same shape as q, f32
+
+The pools are head-major so one grid step's block, ``(1, 1, page_size,
+hd)``, has the page's slots and the head dim as its two minor dimensions:
+Mosaic requires those to be multiples of the (8, 128) tile or the whole
+array dimension, which a token-major ``(P, page_size, KVS, hd)`` pool
+cannot give (its block would carry one of KVS heads as the second-minor
+dimension).
 
 Compressed pools (``kv_quant="int8"``): pass int8 k/v pools plus the scale
 pools and the kernel dequantizes INSIDE the page loop — each page's int8
@@ -36,9 +43,12 @@ bytes stream pool->VMEM compressed (≈4x less traffic than f32) and expand
 to f32 only in registers, right before the QK^T dot.  Both the 4-D decode
 and 5-D verify-window paths share the epilogue.
 
-TPU note: real-hardware efficiency wants hd a multiple of 128 and
-page_size a multiple of the sublane tile; interpret mode (CPU tests) takes
-any shape.
+TPU note: compiled (``interpret=False``, the default off the CPU), the
+block's minor dims must be multiples of (8, 128) or whole array dims:
+page_size a multiple of 8, hd a multiple of 128 or the whole head dim.
+Every variant (decode, verify, int8 scales, tree mask) compiles for a v5e
+at KVS 32, hd 128 and page_size 16 or 32; tests/test_tpu_compile.py keeps
+the page-16 compiles.  Interpret mode (CPU tests) takes any shape.
 
 Invariants (the contract with the serving engine):
 
@@ -150,8 +160,8 @@ def _attend_page(k, v, len_ref, q_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, **kw):
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (page_size, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)  # (page_size, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
     _attend_page(k, v, len_ref, q_ref, o_ref, m_ref, l_ref, acc_ref, **kw)
 
 
@@ -159,23 +169,23 @@ def _kernel_quant(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                   o_ref, m_ref, l_ref, acc_ref, **kw):
     """int8 pools: dequantize this page in VREGs (per-slot scale broadcast
     over hd) right before the dots — the page crossed HBM->VMEM as int8."""
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, :, 0, :]
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, :, 0, :]
+    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
+    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
     _attend_page(k, v, len_ref, q_ref, o_ref, m_ref, l_ref, acc_ref, **kw)
 
 
 def _kernel_tree(pt_ref, len_ref, q_ref, k_ref, v_ref, tm_ref, o_ref,
                  m_ref, l_ref, acc_ref, **kw):
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
     _attend_page(k, v, len_ref, q_ref, o_ref, m_ref, l_ref, acc_ref,
                  tm=tm_ref[0], **kw)
 
 
 def _kernel_quant_tree(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                        tm_ref, o_ref, m_ref, l_ref, acc_ref, **kw):
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, :, 0, :]
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, :, 0, :]
+    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
+    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
     _attend_page(k, v, len_ref, q_ref, o_ref, m_ref, l_ref, acc_ref,
                  tm=tm_ref[0], **kw)
 
@@ -183,12 +193,12 @@ def _kernel_quant_tree(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # (B, KVS, G, hd) or (B, W, KVS, G, hd)
-    k_pool: jnp.ndarray,  # (P, page_size, KVS, hd)
+    k_pool: jnp.ndarray,  # (P, KVS, page_size, hd)
     v_pool: jnp.ndarray,
     page_table: jnp.ndarray,  # (B, max_pages) int32
     lengths: jnp.ndarray,  # (B,) int32 — valid tokens incl. the window
     interpret: Optional[bool] = None,
-    k_scale: Optional[jnp.ndarray] = None,  # (P, page_size, KVS, 1) f32
+    k_scale: Optional[jnp.ndarray] = None,  # (P, KVS, page_size, 1) f32
     v_scale: Optional[jnp.ndarray] = None,
     tree_mask: Optional[jnp.ndarray] = None,  # (B, W, W) window visibility
 ) -> jnp.ndarray:
@@ -217,7 +227,7 @@ def paged_decode_attention_pallas(
         b, kvs, g, hd = q.shape
         w = 1
         qk = q
-    _, page_size, pool_kvs, pool_hd = k_pool.shape
+    _, pool_kvs, page_size, pool_hd = k_pool.shape
     assert (pool_kvs, pool_hd) == (kvs, hd), (k_pool.shape, q.shape)
     quantized = k_scale is not None
     assert quantized == (v_scale is not None), "pass both scales or neither"
@@ -226,7 +236,7 @@ def paged_decode_attention_pallas(
     scale = 1.0 / math.sqrt(hd)
     grid = (b, kvs, n_pages)
     page_spec = lambda width: pl.BlockSpec(
-        (1, page_size, 1, width), lambda i, j, p, pt, ln: (pt[i, p], 0, j, 0)
+        (1, 1, page_size, width), lambda i, j, p, pt, ln: (pt[i, p], j, 0, 0)
     )
     in_specs = [
         pl.BlockSpec((1, 1, rows, hd), lambda i, j, p, pt, ln: (i, j, 0, 0)),

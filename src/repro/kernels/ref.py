@@ -31,11 +31,11 @@ def block_rotate_ref(x: jnp.ndarray, m: int, k: int, transpose: bool = False):
 
 
 def w4a8_matmul_ref2(xq, wp, sx, sw):
-    """Oracle for kernels.w4a8_matmul.w4a8_matmul_pallas (packed input)."""
-    w = unpack_int4(wp, axis=0).astype(jnp.int32)
+    """Oracle for kernels.w4a8_matmul.w4a8_matmul_pallas (packed input):
+    int8 x int8 with an int32 accumulator, the MXU's integer dot."""
     acc = jax.lax.dot_general(
-        xq.astype(jnp.int32),
-        w,
+        xq.astype(jnp.int8),
+        unpack_int4(wp, axis=0),
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
@@ -48,20 +48,22 @@ def bvq_matmul_ref2(x: jnp.ndarray, bw: BVQWeight):
 
 
 def gather_pages_ref(pool: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
-    """(P, ps, KVS, hd) pool + (B, max_pages) table -> (B, max_pages*ps, KVS,
-    hd) contiguous per-request K/V (the dense view of a paged cache)."""
+    """(P, KVS, ps, hd) head-major pool + (B, max_pages) table -> (B,
+    max_pages*ps, KVS, hd) contiguous per-request K/V (the dense view of a
+    paged cache)."""
     b, mp = page_table.shape
-    _, ps, kvs, hd = pool.shape
-    return pool[page_table].reshape(b, mp * ps, kvs, hd)
+    _, kvs, ps, hd = pool.shape
+    pages = pool[page_table]  # (B, mp, KVS, ps, hd)
+    return pages.transpose(0, 1, 3, 2, 4).reshape(b, mp * ps, kvs, hd)
 
 
 def paged_attn_ref(
     q: jnp.ndarray,  # (B, KVS, G, hd) f32, or (B, W, KVS, G, hd) for a window
-    k_pool: jnp.ndarray,  # (P, page_size, KVS, hd)
+    k_pool: jnp.ndarray,  # (P, KVS, page_size, hd)
     v_pool: jnp.ndarray,
     page_table: jnp.ndarray,  # (B, max_pages) int32 (unused slots: any valid id)
     lengths: jnp.ndarray,  # (B,) int32 valid tokens (incl. the window when 5-D)
-    k_scale: jnp.ndarray = None,  # (P, page_size, KVS, 1) f32 (int8 pools)
+    k_scale: jnp.ndarray = None,  # (P, KVS, page_size, 1) f32 (int8 pools)
     v_scale: jnp.ndarray = None,
     tree_mask: jnp.ndarray = None,  # (B, W, W) visibility among window slots
 ) -> jnp.ndarray:

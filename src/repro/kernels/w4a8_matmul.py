@@ -25,15 +25,17 @@ __all__ = ["w4a8_matmul_pallas"]
 
 
 def _unpack_nibbles(wp: jnp.ndarray) -> jnp.ndarray:
-    """(bk2, bn) int8 packed -> (2*bk2, bn) int32 sign-extended int4.
+    """(bk2, bn) int8 packed -> (2*bk2, bn) int8 sign-extended int4.
 
     Element 2i of K is the low nibble, 2i+1 the high nibble (matches
-    core.quantization.pack_int4 with axis=0)."""
+    core.quantization.pack_int4 with axis=0).  The shifts run in int32;
+    the result narrows to int8 because the MXU takes int8 x int8 with an
+    int32 accumulator (Mosaic refuses an int32 x int32 dot)."""
     p = wp.astype(jnp.int32)
     lo = (p << 28) >> 28
     hi = p >> 4
     bk2, bn = wp.shape
-    return jnp.stack([lo, hi], axis=1).reshape(2 * bk2, bn)
+    return jnp.stack([lo, hi], axis=1).reshape(2 * bk2, bn).astype(jnp.int8)
 
 
 def _w4a8_kernel(xq_ref, wp_ref, sx_ref, sw_ref, o_ref, acc_ref, *, k_steps: int):
@@ -41,8 +43,8 @@ def _w4a8_kernel(xq_ref, wp_ref, sx_ref, sw_ref, o_ref, acc_ref, *, k_steps: int
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    xq = xq_ref[...].astype(jnp.int32)  # (bm, bk)
-    w = _unpack_nibbles(wp_ref[...])  # (bk, bn) int32
+    xq = xq_ref[...]  # (bm, bk) int8
+    w = _unpack_nibbles(wp_ref[...])  # (bk, bn) int8
     acc_ref[...] += jax.lax.dot_general(
         xq,
         w,

@@ -1,7 +1,10 @@
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ.setdefault(
+    "XLA_FLAGS", "--xla_force_host_platform_device_count=512"
+)
 # ^ MUST precede any jax import: jax locks the device count on first init.
+# An XLA_FLAGS the caller already set is left as it is.
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and extract roofline terms from the compiled artifacts.

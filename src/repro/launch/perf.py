@@ -1,7 +1,9 @@
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import.
+os.environ.setdefault(
+    "XLA_FLAGS", "--xla_force_host_platform_device_count=512"
+)
+# ^ MUST precede any jax import; an XLA_FLAGS already set is left as it is.
 
 """Perf-iteration harness (§Perf hillclimbing): re-lower a dry-run cell
 under config variants and diff the roofline terms.

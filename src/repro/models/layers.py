@@ -160,12 +160,12 @@ class PagedCache:
     path right after the gather — so pages stay int8 at rest.
     """
 
-    k: jnp.ndarray  # (P(+scratch), page_size, kvh, hd)
+    k: jnp.ndarray  # (P(+scratch), kvh, page_size, hd) — head-major
     v: jnp.ndarray
     page_table: jnp.ndarray  # (B, max_pages) int32
     length: jnp.ndarray  # (B,) int32 — tokens already written per request
     impl: str = "gather"  # "gather" | "pallas"
-    k_scale: Optional[jnp.ndarray] = None  # (P(+scratch), page_size, kvh, 1)
+    k_scale: Optional[jnp.ndarray] = None  # (P(+scratch), kvh, page_size, 1)
     v_scale: Optional[jnp.ndarray] = None
     # (B, S, S) intra-window visibility (speculation-tree ancestor mask);
     # None keeps the causal window semantics bit-exact (chain mode)
@@ -177,8 +177,8 @@ def forward_cache_ctx(cache, b: int, s: int, paged_impl: str):
     ``(offset, positions (B, S), paged_ctx)`` for any cache form.
 
     A cache carrying ``page_table`` is the device-resident paged pool
-    (``{"lengths" (B,), "page_table" (B, mp), "attn": {"k": (L, P, ps,
-    kvh, hd), ...}}``): offset is the per-row length vector and paged_ctx
+    (``{"lengths" (B,), "page_table" (B, mp), "attn": {"k": (L, P, kvh,
+    ps, hd), ...}}``): offset is the per-row length vector and paged_ctx
     the ``(page_table, impl, tree_mask)`` triple the per-layer attention
     needs (``tree_mask``/``win_pos`` cache keys are the speculation-tree
     extras — see ``PagedCache``).  A dense cache (or None) yields the
@@ -232,13 +232,14 @@ def paged_attention_update(
 
     Returns ``(out (B, S, H, hd), new_pools)`` where ``new_pools`` is the
     updated storage dict — ``{"k", "v"}`` plus ``{"k_scale", "v_scale"}``
-    for compressed pools.  The scatter is one flat ``.at[].set`` per pool
-    array — rows write disjoint pages by construction (inactive rows all
-    target the scratch page, where duplicate writes are harmless); for
-    compressed pools the span quantizes first and values + scales land in
-    the SAME dispatch, so a readable slot always carries its own scale."""
+    for compressed pools.  The scatter is one ``.at[page, :, slot].set``
+    per pool array — rows write disjoint pages by construction (inactive
+    rows all target the scratch page, where duplicate writes are
+    harmless); for compressed pools the span quantizes first and values +
+    scales land in the SAME dispatch, so a readable slot always carries
+    its own scale."""
     b, s, h, hd = q.shape
-    n_pages, ps, kvh, _ = pc.k.shape
+    n_pages, kvh, ps, _ = pc.k.shape  # head-major pages
     mp = pc.page_table.shape[1]
     quantized = pc.k_scale is not None
     pos = pc.length[:, None] + jnp.arange(s)[None, :]  # (B, S) absolute slots
@@ -249,16 +250,13 @@ def paged_attention_update(
     # reserves peak+window, so it cannot happen from serve_batch) divert to
     # the pool's last page (the engine's scratch) rather than silently
     # overwriting the request's own committed KV in its last page
-    page = jnp.where(pos >= mp * ps, n_pages - 1, page)
-    flat = (page * ps + pos % ps).reshape(-1)  # (B*S,) into (P*ps, kvh, hd)
+    page = jnp.where(pos >= mp * ps, n_pages - 1, page).reshape(-1)
+    slot = (pos % ps).reshape(-1)  # (B*S,) in-page slot per token
 
     def scatter(pool, span):
-        width = pool.shape[-1]
-        return (
-            pool.reshape(n_pages * ps, kvh, width)
-            .at[flat]
-            .set(span.astype(pool.dtype).reshape(b * s, kvh, width))
-            .reshape(pool.shape)
+        # pool[page, :, slot] indexes (B*S, kvh, width): one row per token
+        return pool.at[page, :, slot].set(
+            span.astype(pool.dtype).reshape(b * s, kvh, pool.shape[-1])
         )
 
     if quantized:
@@ -291,14 +289,14 @@ def paged_attention_update(
     # the allocator's reservation invariant), then the identical dense
     # math — bit-identical to the host-dense path: masked columns
     # contribute exact zeros, so the width difference never shows
-    kd = new_k[pc.page_table.reshape(-1)].reshape(b, mp * ps, kvh, hd)
-    vd = new_v[pc.page_table.reshape(-1)].reshape(b, mp * ps, kvh, hd)
+    kd = gather_pages(new_k, pc.page_table)
+    vd = gather_pages(new_v, pc.page_table)
     if quantized:
         # explicit f32 dequant, then the UNCHANGED fp attention math — this
         # is what keeps the gather path numerically equivalent (same dots,
         # small f32 tolerance) to the kernel's in-page dequant epilogue
-        ksd = new_ks[pc.page_table.reshape(-1)].reshape(b, mp * ps, kvh, 1)
-        vsd = new_vs[pc.page_table.reshape(-1)].reshape(b, mp * ps, kvh, 1)
+        ksd = gather_pages(new_ks, pc.page_table)
+        vsd = gather_pages(new_vs, pc.page_table)
         kd = (kd.astype(jnp.float32) * ksd).astype(q.dtype)
         vd = (vd.astype(jnp.float32) * vsd).astype(q.dtype)
     if pc.tree_mask is not None:
@@ -308,6 +306,15 @@ def paged_attention_update(
     else:
         out = flash_attention(q, kd, vd, causal=True, q_offset=pc.length)
     return out, new_pools
+
+
+def gather_pages(pool: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
+    """Dense token-major view of a head-major pool: ``(P, kvh, ps, w)``
+    gathered through a ``(B, mp)`` table -> ``(B, mp * ps, kvh, w)``."""
+    b, mp = page_table.shape
+    _, kvh, ps, w = pool.shape
+    pages = pool[page_table.reshape(-1)].reshape(b, mp, kvh, ps, w)
+    return pages.transpose(0, 1, 3, 2, 4).reshape(b, mp * ps, kvh, w)
 
 
 def _tree_window_attention(
@@ -804,12 +811,10 @@ def moe_apply_a2a(
         out = jnp.zeros((n_tok, d), jnp.float32).at[flat_tok].add(contrib)
         return out.astype(x_loc.dtype).reshape(b_loc, s_loc, d)
 
-    from jax.experimental.shard_map import shard_map
-
     tok_spec = (
         P(batch_axes, "model", None) if seq_sharded else P(batch_axes, None, None)
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -820,7 +825,7 @@ def moe_apply_a2a(
             P("model", None, None),
         ),
         out_specs=tok_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, params["router"], params["w_gate"], params["w_up"], params["w_down"])
 

@@ -303,7 +303,7 @@ def _make_paged_step(model: ServingModel):
 
     @partial(jax.jit, donate_argnums=(2,))
     def step(params, tokens, store, page_table, lengths):
-        # tokens (B, W) int32; store arrays (L, P+1, ps, kvh, hd|1)
+        # tokens (B, W) int32; store arrays (L, P+1, kvh, ps, hd|1)
         cache = {
             "lengths": lengths,
             "page_table": page_table,
@@ -475,15 +475,13 @@ def _scatter_prefill(store, k_dense, v_dense, pages, n, start=0):
     per-slot-per-head rule the decode steps apply in
     models/layers.paged_attention_update) and values + scales land in one
     dispatch, so a page's scale can never be stale relative to its bytes."""
-    pool_k = store["k"]
-    nl, p1, ps, kvh, hd = pool_k.shape
+    p1, ps = store["k"].shape[1], store["k"].shape[3]  # (L, P+1, kvh, ps, hd)
     s_max = k_dense.shape[1]
     cap = pages.shape[0] * ps  # table span; may overhang s_max by < ps
     pos = jnp.arange(cap)
-    scratch = (p1 - 1) * ps + pos % ps  # harmless dup writes per layer
-    flat = jnp.where(
-        (pos >= start) & (pos < n), pages[pos // ps] * ps + pos % ps, scratch
-    )
+    # rows outside [start, n) land on the scratch page: harmless dup writes
+    page = jnp.where((pos >= start) & (pos < n), pages[pos // ps], p1 - 1)
+    slot = pos % ps
     src_k = k_dense[:, jnp.minimum(pos, s_max - 1)]
     src_v = v_dense[:, jnp.minimum(pos, s_max - 1)]
     if "k_scale" in store:
@@ -495,12 +493,9 @@ def _scatter_prefill(store, k_dense, v_dense, pages, n, start=0):
     out = {}
     for name, src in writes.items():
         pool = store[name]
-        width = pool.shape[-1]
-        out[name] = (
-            pool.reshape(nl, p1 * ps, kvh, width)
-            .at[:, flat]
-            .set(src.astype(pool.dtype))
-            .reshape(pool.shape)
+        # pool[:, page, :, slot] indexes (cap, L, kvh, width)
+        out[name] = pool.at[:, page, :, slot].set(
+            jnp.swapaxes(src, 0, 1).astype(pool.dtype)
         )
     return out
 
@@ -528,9 +523,9 @@ def _compact_slots(store, src, dst):
     safe."""
     out = {}
     for name, a in store.items():
-        nl, p1, ps = a.shape[0], a.shape[1], a.shape[2]
-        flat = a.reshape(nl, p1 * ps, *a.shape[3:])
-        out[name] = flat.at[:, dst].set(flat[:, src]).reshape(a.shape)
+        ps = a.shape[3]  # (L, P+1, kvh, ps, width)
+        rows = a[:, src // ps, :, src % ps]  # (N, L, kvh, width)
+        out[name] = a.at[:, dst // ps, :, dst % ps].set(rows)
     return out
 
 
@@ -1082,22 +1077,21 @@ class Engine:
         ``cost_analysis()`` FLOPs / bytes accessed.  MUST run before the
         program's first profiled dispatch — the step fns donate their
         stores, so lowering from live args is only safe while the caller
-        still owns them.  Degrades to ``{}`` for callables without
-        ``.lower`` (the host-orchestrated prefill) or backends that don't
-        report cost analysis."""
+        still owns them.  ``{}`` for callables without ``.lower`` (the
+        host-orchestrated prefill) and for a compiled program whose backend
+        reports no cost analysis; any other error propagates."""
         cost = self._prog_cost.get(program)
         if cost is None:
             cost = {}
-            try:
+            if hasattr(fn, "lower"):
                 analysis = fn.lower(*args).compile().cost_analysis()
                 if isinstance(analysis, (list, tuple)):
-                    analysis = analysis[0] if analysis else {}
-                cost = {
-                    "flops": float(analysis.get("flops", 0.0)),
-                    "bytes": float(analysis.get("bytes accessed", 0.0)),
-                }
-            except Exception:
-                pass
+                    analysis = analysis[0] if analysis else None
+                if analysis:
+                    cost = {
+                        "flops": float(analysis.get("flops", 0.0)),
+                        "bytes": float(analysis.get("bytes accessed", 0.0)),
+                    }
             self._prog_cost[program] = cost
         return cost
 
@@ -2302,11 +2296,7 @@ class Engine:
 def _seed_from_key(key) -> int:
     """Fold a jax PRNG key into a per-request integer seed (the wrappers'
     bridge from the old key-threading API to per-request key streams)."""
-    try:
-        data = jax.random.key_data(key)
-    except (AttributeError, TypeError):
-        data = key
-    return int(np.asarray(data).ravel()[-1])
+    return int(np.asarray(jax.random.key_data(key)).ravel()[-1])
 
 
 def serve_sd(

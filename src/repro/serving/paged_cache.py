@@ -17,9 +17,10 @@ single-request serving path into a multi-tenant runtime:
 Two storage modes:
 
 * ``alloc_storage=True`` (legacy / benchmark baseline): host-side numpy
-  arrays (layer-stacked, ``(n_layers, num_pages, page_size, kv_heads,
-  head_dim)``); a consumer gathers a request's pages into a dense view and
-  scatters written spans back (``PagedSequence.append``/``gather_into``).
+  arrays (layer-stacked, head-major ``(n_layers, num_pages, kv_heads,
+  page_size, head_dim)``, the device layout); a consumer gathers a
+  request's pages into a dense view and scatters written spans back
+  (``PagedSequence.append``/``gather_into``).
 * ``alloc_storage=False`` (device-resident serving): this object is pure
   allocator/bookkeeper — KV bytes live in JAX device arrays built by
   ``device_pool_init`` and are written in place by the model forward
@@ -55,7 +56,7 @@ Invariants (what the engine's hot loop is allowed to assume):
   reads it).
 * **Scale freshness (``kv_quant="int8"``)** — a quantized pool stores K/V
   as int8 plus a per-slot-per-head float32 scale, laid out page-granular
-  exactly like the data (``(..., page, slot, kv_head, 1)``), so a page's
+  exactly like the data (``(..., page, kv_head, slot, 1)``), so a page's
   scales travel with the page through the table.  A scale entry must never
   outlive the value it was computed for: every write path stores value and
   scale together (host ``append`` quantizes both in one call; the device
@@ -105,6 +106,14 @@ _SCALE_BYTES = 4  # float32 per-slot-per-head scale
 
 def pages_for(n_tokens: int, page_size: int) -> int:
     return -(-n_tokens // page_size)  # ceil div
+
+
+def _token_major(a: np.ndarray) -> np.ndarray:
+    """Head-major host storage ``(n_layers, P, kv_heads, page_size, w)``
+    viewed as ``(n_layers, P, page_size, kv_heads, w)``.  A numpy view, so
+    ``view[:, pages, slots] = span`` writes (n_layers, n, kv_heads, w)
+    token spans straight into the storage."""
+    return a.swapaxes(2, 3)
 
 
 def kv_quantize_np(span: np.ndarray):
@@ -232,7 +241,7 @@ class PagedKVPool:
                     "keeps one device store per kind); host-mode storage "
                     "must pick 'none' or 'int8'"
                 )
-            shape = (n_layers, num_pages, page_size, kv_heads, head_dim)
+            shape = (n_layers, num_pages, kv_heads, page_size, head_dim)
             store_dt = np.int8 if kv_quant == "int8" else dtype
             self.k = np.zeros(shape, store_dt)
             self.v = np.zeros(shape, store_dt)
@@ -462,8 +471,9 @@ class PagedSequence:
 
     def flat_slots(self, positions) -> np.ndarray:
         """Flat pool-slot index (page * page_size + in-page slot) of each
-        absolute token position — the device-store row a position occupies
-        once pool arrays are viewed as (n_layers, P * page_size, ...).
+        absolute token position: one int that names the slot a position
+        occupies in every kv head of its page (consumers split it back into
+        ``(page, slot)`` to index the head-major store).
 
         Positions must be backed (< len(pages) * page_size).  This is the
         public indexing the engine's tree-path compaction uses to copy
@@ -554,13 +564,13 @@ class PagedSequence:
             vq, vs = kv_quantize_np(np.asarray(v_span, np.float32))
             # value and scale land together — a slot is never readable with
             # a scale from a previous tenant of the page
-            self.pool.k[:, pg, slot] = kq
-            self.pool.v[:, pg, slot] = vq
-            self.pool.k_scale[:, pg, slot] = ks
-            self.pool.v_scale[:, pg, slot] = vs
+            _token_major(self.pool.k)[:, pg, slot] = kq
+            _token_major(self.pool.v)[:, pg, slot] = vq
+            _token_major(self.pool.k_scale)[:, pg, slot] = ks
+            _token_major(self.pool.v_scale)[:, pg, slot] = vs
         else:
-            self.pool.k[:, pg, slot] = k_span
-            self.pool.v[:, pg, slot] = v_span
+            _token_major(self.pool.k)[:, pg, slot] = k_span
+            _token_major(self.pool.v)[:, pg, slot] = v_span
         self.length += l
 
     # -- device-resident bookkeeping (no host data path) --------------------
@@ -606,12 +616,13 @@ class PagedSequence:
         # the last page's tail may overhang a dst that is not a multiple of
         # page_size — clamp the copy (only junk slots past `length` drop)
         m = min(n * ps, k_dst.shape[1])
-        span = self.pool.k[:, pg].reshape(self.pool.n_layers, n * ps, *k_dst.shape[2:])
-        span_v = self.pool.v[:, pg].reshape(self.pool.n_layers, n * ps, *v_dst.shape[2:])
+        nl = self.pool.n_layers
+        span = _token_major(self.pool.k)[:, pg].reshape(nl, n * ps, *k_dst.shape[2:])
+        span_v = _token_major(self.pool.v)[:, pg].reshape(nl, n * ps, *v_dst.shape[2:])
         if self.pool.kv_quant == "int8":
-            sshape = (self.pool.n_layers, n * ps, self.pool.kv_heads, 1)
-            ks = self.pool.k_scale[:, pg].reshape(sshape)
-            vs = self.pool.v_scale[:, pg].reshape(sshape)
+            sshape = (nl, n * ps, self.pool.kv_heads, 1)
+            ks = _token_major(self.pool.k_scale)[:, pg].reshape(sshape)
+            vs = _token_major(self.pool.v_scale)[:, pg].reshape(sshape)
             k_dst[:, :m] = (span[:, :m].astype(np.float32) * ks[:, :m]).astype(
                 k_dst.dtype
             )
@@ -678,8 +689,8 @@ class PagedSequence:
         pg, slot = pg[sole], slot[sole]
         if len(pg) == 0:
             return
-        self.pool.k_scale[:, pg, slot] = 0.0
-        self.pool.v_scale[:, pg, slot] = 0.0
+        _token_major(self.pool.k_scale)[:, pg, slot] = 0.0
+        _token_major(self.pool.v_scale)[:, pg, slot] = 0.0
 
     def release(self) -> None:
         """Return every page reference and the unused reservation to the
@@ -706,8 +717,9 @@ class PagedSequence:
 
 
 def device_pool_init(pool: PagedKVPool, dtype=None):
-    """JAX-array KV storage for `pool`: ``(k, v)`` each of shape
-    ``(n_layers, num_pages + 1, page_size, kv_heads, head_dim)``.
+    """JAX-array KV storage for `pool`: ``(k, v)`` each of head-major shape
+    ``(n_layers, num_pages + 1, kv_heads, page_size, head_dim)`` — the
+    layout ``kernels/paged_attn.py`` reads one (page, kv head) block of.
 
     One extra SCRATCH page (index ``pool.num_pages``, never handed out by
     the allocator) absorbs writes from inactive batch rows, whose page
@@ -723,8 +735,8 @@ def device_pool_init(pool: PagedKVPool, dtype=None):
     shape = (
         pool.n_layers,
         pool.num_pages + 1,
-        pool.page_size,
         pool.kv_heads,
+        pool.page_size,
         pool.head_dim,
     )
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)

@@ -39,6 +39,7 @@ Params = Dict[str, Any]
 
 __all__ = [
     "quantize_dense_lm",
+    "init_quantized_lm",
     "apply_quantized_lm",
     "bvq_compress_lm",
     "apply_bvq_lm",
@@ -81,6 +82,55 @@ def _quant_pack(w: jnp.ndarray, bits: Optional[int]):
     return {"packed": q.pack_int4(wq, axis=0), "sw": sw.reshape(1, -1)}
 
 
+def _fold_layer(lp: Params, cfg: ModelConfig, r1, r2, bits: Optional[int],
+                rotate: bool) -> Params:
+    """One dense block's bf16 params -> its folded, rotated W4A8 form."""
+    d, h, kv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv, cfg.hd
+    wq_ = _fold_norm_into(lp["attn"]["wq"], lp["ln1"]["g"]).reshape(d, h * hd)
+    wk_ = _fold_norm_into(lp["attn"]["wk"], lp["ln1"]["g"]).reshape(d, kv * hd)
+    wv_ = _fold_norm_into(lp["attn"]["wv"], lp["ln1"]["g"]).reshape(d, kv * hd)
+    wo_ = lp["attn"]["wo"].reshape(h * hd, d)
+    wg_ = _fold_norm_into(lp["mlp"]["w_gate"], lp["ln2"]["g"])
+    wu_ = _fold_norm_into(lp["mlp"]["w_up"], lp["ln2"]["g"])
+    wd_ = lp["mlp"]["w_down"]
+    if rotate:
+        wq_, wk_, wv_ = (_rot_in(w, r1) for w in (wq_, wk_, wv_))
+        wg_, wu_ = _rot_in(wg_, r1), _rot_in(wu_, r1)
+        wo_ = _rot_out(wo_, r1)
+        wd_ = _rot_out(wd_, r1)
+        wd_ = _rot_in(wd_, r2)  # online R2 rotates the d_ff activation
+    return {
+        "wq": _quant_pack(wq_, bits),
+        "wk": _quant_pack(wk_, bits),
+        "wv": _quant_pack(wv_, bits),
+        "wo": _quant_pack(wo_, bits),
+        "w_gate": _quant_pack(wg_, bits),
+        "w_up": _quant_pack(wu_, bits),
+        "w_down": _quant_pack(wd_, bits),
+        "qk_extra": {
+            k: lp["attn"][k] for k in ("q_norm", "k_norm") if k in lp["attn"]
+        },
+    }
+
+
+def _fold_embed_head(embed_p: Params, final_norm: Params, cfg: ModelConfig,
+                     r1, bits: Optional[int], rotate: bool) -> Params:
+    """Embedding and head of the W4A8 form: the final norm folds into the
+    head, and R1 rotates the embedding's output and the head's input."""
+    embed = embed_p["tok"].astype(jnp.float32)
+    head = _fold_norm_into(embed_p["head"], final_norm["g"]).astype(jnp.float32)
+    if rotate:
+        embed = rot.local_rotate(embed, r1)  # (V, d): rotate output side
+        head = _rot_in(head, r1)
+    return {"embed": embed.astype(cfg.jdtype), "head": _quant_pack(head, bits)}
+
+
+def _plans(cfg: ModelConfig, rotate: bool):
+    if not rotate:
+        return None, None
+    return rot.plan_rotation(cfg.d_model), rot.plan_rotation(cfg.d_ff)
+
+
 def quantize_dense_lm(
     params: Params, cfg: ModelConfig, bits: Optional[int] = 4, rotate: bool = True
 ) -> Params:
@@ -90,50 +140,57 @@ def quantize_dense_lm(
     rotate=False skips the LRU rotations (the no-rotation ablation the
     paper's perplexity table compares against)."""
     assert cfg.family in (Family.DENSE, Family.VLM), "W4A8 path: dense family"
-    r1 = rot.plan_rotation(cfg.d_model) if rotate else None
-    r2 = rot.plan_rotation(cfg.d_ff) if rotate else None
-    d, h, kv, hd, f = cfg.d_model, cfg.n_heads, cfg.n_kv, cfg.hd, cfg.d_ff
+    r1, r2 = _plans(cfg, rotate)
+    layers = jax.vmap(
+        lambda lp: _fold_layer(lp, cfg, r1, r2, bits, rotate)
+    )(params["layers"])
+    out = _fold_embed_head(
+        params["embed"], params["final_norm"], cfg, r1, bits, rotate
+    )
+    out["layers"] = layers
+    return out
 
-    def fold_layer(lp: Params) -> Params:
-        wq_ = _fold_norm_into(lp["attn"]["wq"], lp["ln1"]["g"]).reshape(d, h * hd)
-        wk_ = _fold_norm_into(lp["attn"]["wk"], lp["ln1"]["g"]).reshape(d, kv * hd)
-        wv_ = _fold_norm_into(lp["attn"]["wv"], lp["ln1"]["g"]).reshape(d, kv * hd)
-        wo_ = lp["attn"]["wo"].reshape(h * hd, d)
-        wg_ = _fold_norm_into(lp["mlp"]["w_gate"], lp["ln2"]["g"])
-        wu_ = _fold_norm_into(lp["mlp"]["w_up"], lp["ln2"]["g"])
-        wd_ = lp["mlp"]["w_down"]
-        if rotate:
-            wq_, wk_, wv_ = (_rot_in(w, r1) for w in (wq_, wk_, wv_))
-            wg_, wu_ = _rot_in(wg_, r1), _rot_in(wu_, r1)
-            wo_ = _rot_out(wo_, r1)
-            wd_ = _rot_out(wd_, r1)
-            wd_ = _rot_in(wd_, r2)  # online R2 rotates the d_ff activation
-        return {
-            "wq": _quant_pack(wq_, bits),
-            "wk": _quant_pack(wk_, bits),
-            "wv": _quant_pack(wv_, bits),
-            "wo": _quant_pack(wo_, bits),
-            "w_gate": _quant_pack(wg_, bits),
-            "w_up": _quant_pack(wu_, bits),
-            "w_down": _quant_pack(wd_, bits),
-            "qk_extra": {
-                k: lp["attn"][k] for k in ("q_norm", "k_norm") if k in lp["attn"]
-            },
-        }
 
-    layers = jax.vmap(fold_layer)(params["layers"])
-    embed = params["embed"]["tok"].astype(jnp.float32)
-    head = _fold_norm_into(
-        params["embed"]["head"], params["final_norm"]["g"]
-    ).astype(jnp.float32)
-    if rotate:
-        embed = rot.local_rotate(embed, r1)  # (V, d): rotate output side
-        head = _rot_in(head, r1)
-    return {
-        "embed": embed.astype(cfg.jdtype),
-        "head": _quant_pack(head, bits),
-        "layers": layers,
-    }
+def init_quantized_lm(key: jax.Array, cfg: ModelConfig) -> Params:
+    """Random W4A8 (LRU-rotated) params built one layer at a time.
+
+    Draws the weights of ``quantize_dense_lm(lm.init_lm(key, cfg)[0], cfg)``
+    (same key splits) up to float rounding: each layer is initialised,
+    folded and packed by one program before the next one exists, and
+    written into a preallocated stack.  The build holds one layer's f32
+    fold and the packed model at most, where the whole-model path holds
+    every bf16 layer and their f32 folds at once — more than one 16 GB chip
+    has at llama2-7b widths.  The fold runs as one program because, op by
+    op, the host queues the rotation's full-size intermediates faster than
+    the device frees them; XLA then fuses the scaling constants, so a few
+    weights round to the neighbouring int4 step."""
+    from repro.models.lm import _layer_init
+
+    assert cfg.family in (Family.DENSE, Family.VLM), "W4A8 path: dense family"
+    r1, r2 = _plans(cfg, True)
+    ke, kl, _, _ = jax.random.split(key, 4)  # init_lm's split
+    final_norm, _ = L.rmsnorm_init(cfg.d_model, cfg.jdtype)
+    out = jax.jit(lambda k: _fold_embed_head(
+        L.embed_init(k, cfg)[0], final_norm, cfg, r1, 4, True
+    ))(ke)
+    fold = jax.jit(lambda k: _fold_layer(
+        _layer_init(k, cfg, 1, "dense")[0], cfg, r1, r2, 4, True
+    ))
+
+    @partial(jax.jit, donate_argnums=(0,))
+    def put(stack, layer, i):
+        return jax.tree.map(lambda s, x: s.at[i].set(x), stack, layer)
+
+    keys = jax.random.split(kl, cfg.n_layers)  # _stacked_layers' split
+    stack = jax.tree.map(
+        lambda x: jnp.zeros((cfg.n_layers,) + x.shape, x.dtype),
+        jax.eval_shape(fold, keys[0]),
+    )
+    for i in range(cfg.n_layers):
+        # wait for each layer to land, so no two layers' transients coexist
+        stack = jax.block_until_ready(put(stack, fold(keys[i]), i))
+    out["layers"] = stack
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -565,19 +622,17 @@ def _moe_a2a_quant(layer: Params, x: jnp.ndarray, cfg: ModelConfig, mesh,
         out = jnp.zeros((n_tok, d), jnp.float32).at[flat_tok].add(contrib)
         return out.astype(x_loc.dtype).reshape(b_loc, s_loc, d)
 
-    from jax.experimental.shard_map import shard_map
-
     tok_spec = (
         P(batch_axes, "model", None) if seq_sharded else P(batch_axes, None, None)
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(tok_spec, P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=tok_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, layer["router"],
               layer["w_gate"]["packed"], layer["w_gate"]["sw"],

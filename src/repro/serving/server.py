@@ -455,7 +455,7 @@ class CompletionServer:
 
 
 # ---------------------------------------------------------------------------
-# CLI: serve the smoke-scale toy pair
+# CLI: serve the paper pair (smoke or published widths)
 # ---------------------------------------------------------------------------
 
 
@@ -463,8 +463,11 @@ def main(argv=None):
     import argparse
 
     ap = argparse.ArgumentParser(
-        description="serve the smoke-scale TLM/DLM pair over HTTP"
+        description="serve the TLM/DLM pair over HTTP"
     )
+    ap.add_argument("--widths", choices=("paper", "smoke"), default="smoke",
+                    help="model pair: smoke-scale, or the published "
+                         "llama2-7b / llama-68m widths")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8000)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -490,6 +493,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.launch.serve import build_pair
+    from repro.runtime.compile_cache import enable_compile_cache
     from repro.serving.engine import Engine
     from repro.serving.api import EngineConfig
     from repro.serving.tracing import Tracer
@@ -508,8 +512,12 @@ def main(argv=None):
         )
         detokenize = tok.piece
 
-    print(f"building TLM/DLM pair (quantize={not args.no_quant}) ...")
-    target, draft = build_pair(seed=0, s_max=256, quantize=not args.no_quant)
+    enable_compile_cache()
+    print(f"building {args.widths} TLM/DLM pair "
+          f"(quantize={not args.no_quant}) ...")
+    target, draft = build_pair(
+        seed=0, s_max=256, quantize=not args.no_quant, widths=args.widths
+    )
     engine = Engine(target, draft, EngineConfig(
         max_batch=args.max_batch, page_size=args.page_size,
         par_mode=args.par_mode, prefix_cache=args.prefix_cache,

@@ -402,6 +402,24 @@ def _stop_ref(target, draft, prompt, max_tokens):
     return [int(t) for t in ref]
 
 
+def _stop_cut(ref, stops):
+    """Tokens a stop leaves of `ref`: scan the detokenized stream token by
+    token, and at the first token where any stop text has appeared keep
+    the tokens whose text ends at or before the earliest match.  A stop
+    built from ref[i] can match earlier than i (a repeated token, or text
+    that is a suffix of an earlier token's text)."""
+    from repro.serving.api import default_detokenize
+
+    text, ends = "", []
+    for t in ref:
+        text += default_detokenize(t)
+        ends.append(len(text))
+        hits = [m for m in (text.find(s) for s in stops) if m >= 0]
+        if hits:
+            return sum(1 for e in ends if e <= min(hits))
+    return len(ref)
+
+
 @pytest.mark.parametrize("par_mode", ["off", "wdos"])
 def test_stop_string_truncates_and_frees_pages(pair, par_mode):
     """Generation ends at the first stop match with finish_reason="stop";
@@ -444,11 +462,13 @@ def test_stop_earliest_of_multiple_stops_wins(pair):
     target, draft = pair
     prompts = _prompts(1, seed=17)
     ref = _stop_ref(target, draft, prompts[0], 12)
+    stops = (f"{ref[7]} ", f"{ref[2]} ")
+    cut = _stop_cut(ref, stops)
+    assert cut <= 2  # the later stop never decides the cut
     eng = Engine(target, draft, EngineConfig(max_batch=1, page_size=8))
-    outs, _ = eng.run(prompts, SamplingParams(
-        max_tokens=12, stop=(f"{ref[7]} ", f"{ref[2]} "),
-    ))
-    assert [int(t) for t in outs[0]] == ref[:2]
+    outs, _ = eng.run(prompts, SamplingParams(max_tokens=12, stop=stops))
+    assert [int(t) for t in outs[0]] == ref[:cut]
+    assert eng.request(0).finish_reason == "stop"
 
 
 def test_stop_streams_only_surviving_tokens(pair):
@@ -458,6 +478,7 @@ def test_stop_streams_only_surviving_tokens(pair):
     prompts = _prompts(1, seed=18)
     ref = _stop_ref(target, draft, prompts[0], 12)
     stop_s = f"{ref[4]} "
+    cut = _stop_cut(ref, (stop_s,))
     eng = Engine(target, draft, EngineConfig(max_batch=1, page_size=8))
     streamed = []
     eng.add_request(
@@ -466,7 +487,7 @@ def test_stop_streams_only_surviving_tokens(pair):
     )
     while eng.has_unfinished():
         eng.step()
-    assert streamed == ref[:4]
+    assert streamed == ref[:cut]
 
 
 def test_stop_validation_and_custom_detokenizer(pair):

@@ -6,7 +6,7 @@ import pytest
 
 from repro.models import lm
 from repro.models import whisper as W
-from repro.launch.mesh import activate_mesh
+from repro.launch.mesh import activate_mesh, make_cpu_mesh
 from repro.models.common import Family, ModelConfig
 
 KEY = jax.random.PRNGKey(0)
@@ -175,11 +175,9 @@ def test_whisper_decode_consistency():
 def test_moe_a2a_matches_dense_single_device():
     """On a 1-device mesh the a2a path must equal the dense reference
     (up to capacity drops — use generous capacity)."""
-    from jax.sharding import Mesh
-
     cfg = tiny(Family.MOE, n_experts=4, top_k=2, moe_impl="a2a",
                capacity_factor=4.0, seq_shard=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_cpu_mesh()
     p, _ = lm.init_lm(KEY, cfg, tp=1)
     toks = jax.random.randint(KEY, (2, 8), 0, cfg.vocab)
     with activate_mesh(mesh):

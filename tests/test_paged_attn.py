@@ -12,8 +12,8 @@ from repro.models import layers as L
 def _case(seed, b, kvs, g, hd, pool_pages, page_size, max_pages, lengths):
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(b, kvs, g, hd).astype(np.float32))
-    kp = jnp.asarray(rng.randn(pool_pages, page_size, kvs, hd).astype(np.float32))
-    vp = jnp.asarray(rng.randn(pool_pages, page_size, kvs, hd).astype(np.float32))
+    kp = jnp.asarray(rng.randn(pool_pages, kvs, page_size, hd).astype(np.float32))
+    vp = jnp.asarray(rng.randn(pool_pages, kvs, page_size, hd).astype(np.float32))
     # each request owns a disjoint shuffled page set (as the pool allocator
     # would hand out); unused table slots point at page 0 (masked)
     perm = rng.permutation(pool_pages)[: b * max_pages].reshape(b, max_pages)
@@ -125,8 +125,8 @@ def test_non_power_of_two_lengths(lengths):
 def _window_case(seed, b, w, kvs, g, hd, pool_pages, page_size, mp, lengths):
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(b, w, kvs, g, hd).astype(np.float32))
-    kp = jnp.asarray(rng.randn(pool_pages, page_size, kvs, hd).astype(np.float32))
-    vp = jnp.asarray(rng.randn(pool_pages, page_size, kvs, hd).astype(np.float32))
+    kp = jnp.asarray(rng.randn(pool_pages, kvs, page_size, hd).astype(np.float32))
+    vp = jnp.asarray(rng.randn(pool_pages, kvs, page_size, hd).astype(np.float32))
     perm = rng.permutation(pool_pages)[: b * mp].reshape(b, mp)
     return q, kp, vp, jnp.asarray(perm.astype(np.int32)), jnp.asarray(
         np.asarray(lengths, np.int32)
@@ -163,8 +163,8 @@ def test_window_last_query_equals_single_token_call():
 
 
 def _quantized_pools(kp, vp):
-    """Pool-shaped symmetric int8 quantization: (P, ps, KVS, hd) f32 ->
-    int8 values + (P, ps, KVS, 1) f32 scales (the engine's storage rule)."""
+    """Pool-shaped symmetric int8 quantization: (P, KVS, ps, hd) f32 ->
+    int8 values + (P, KVS, ps, 1) f32 scales (the engine's storage rule)."""
     from repro.serving.paged_cache import kv_quantize_np
 
     kq, ks = kv_quantize_np(np.asarray(kp, np.float32))

@@ -189,7 +189,7 @@ def test_device_pool_init_has_scratch_page():
 
     pool = PagedKVPool(3, 2, 8, num_pages=5, page_size=4, alloc_storage=False)
     k, v = device_pool_init(pool)
-    assert k.shape == (3, 6, 4, 2, 8)  # num_pages + 1 scratch
+    assert k.shape == (3, 6, 2, 4, 8)  # num_pages + 1 scratch, head-major
     assert v.shape == k.shape
 
 
@@ -281,7 +281,7 @@ def test_rewind_invalidates_scales_across_page_boundary():
     seq = pool.allocate_sequence(12)
     seq.append(*rspan(pool, 10, seed=3))  # 3 pages: 4+4+2
     pages = list(seq.pages)
-    flat = lambda p: pool.k_scale[:, p].reshape(pool.n_layers, -1, pool.kv_heads, 1)
+    flat = lambda p: pool.k_scale[:, p].swapaxes(1, 2)  # (L, ps, kvh, 1)
     assert np.all(flat(pages[2])[:, :2] > 0)
     # drop 7 positions: new length 3 sits mid-page-0; pages stay owned
     seq.rewind(7, release_pages=False)
@@ -318,8 +318,9 @@ def test_device_pool_store_shapes():
                        alloc_storage=False, kv_quant="int8")
     st = device_pool_store(pool)
     assert set(st) == {"k", "v", "k_scale", "v_scale"}
-    assert st["k"].shape == (3, 6, 4, 2, 8) and str(st["k"].dtype) == "int8"
-    assert st["k_scale"].shape == (3, 6, 4, 2, 1)
+    # head-major: (layers, pages + scratch, kv heads, page_size, hd)
+    assert st["k"].shape == (3, 6, 2, 4, 8) and str(st["k"].dtype) == "int8"
+    assert st["k_scale"].shape == (3, 6, 2, 4, 1)
     assert str(st["k_scale"].dtype) == "float32"
     dense = device_pool_store(pool, kv_quant="none")
     assert set(dense) == {"k", "v"} and str(dense["k"].dtype) == "float32"

@@ -36,6 +36,22 @@ def pair():
     return build_pair(seed=0, s_max=128, quantize=False)
 
 
+def _stop_cut(ref, stops):
+    """Tokens a stop leaves of `ref`: scan the detokenized stream token by
+    token, and at the first token where any stop text has appeared keep
+    the tokens whose text ends at or before the earliest match."""
+    from repro.serving.api import default_detokenize
+
+    text, ends = "", []
+    for t in ref:
+        text += default_detokenize(t)
+        ends.append(len(text))
+        hits = [m for m in (text.find(s) for s in stops) if m >= 0]
+        if hits:
+            return sum(1 for e in ends if e <= min(hits))
+    return len(ref)
+
+
 def _sync_ref(pair, prompt, sp):
     target, draft = pair
     eng = Engine(target, draft, EngineConfig(max_batch=1, page_size=8))
@@ -150,6 +166,7 @@ def test_stop_sequence_through_http(pair):
     prompt = _prompts(1, seed=3)[0]
     ref = _sync_ref(pair, prompt, SamplingParams(max_tokens=10))
     stop_s = f"{ref[4]} "  # the 5th token's text
+    cut = _stop_cut(ref, (stop_s,))  # may match before the 5th token
 
     async def fn(srv):
         # whole response: truncated BEFORE the stop string, reason "stop"
@@ -159,7 +176,7 @@ def test_stop_sequence_through_http(pair):
         )
         obj = json.loads(body)
         assert status == 200
-        assert obj["token_ids"] == ref[:4]
+        assert obj["token_ids"] == ref[:cut]
         assert obj["finish_reason"] == "stop"
         assert stop_s not in obj["text"]
         # streamed: same truncation, final chunk carries the reason
@@ -169,7 +186,7 @@ def test_stop_sequence_through_http(pair):
         events = [e for e in sse.decode().split("\n\n") if e.strip()]
         chunks = [json.loads(e[len("data: "):]) for e in events[:-1]]
         toks = [c["token"] for c in chunks if c["token"] is not None]
-        assert toks == ref[:4]
+        assert toks == ref[:cut]
         assert chunks[-1]["finish_reason"] == "stop"
 
     _with_server(pair)(fn)

@@ -6,7 +6,7 @@ import pytest
 
 from repro.core import bvq as bvq_mod
 from repro.core.apsd import APSDConfig
-from repro.core.quantization import sqnr_db
+from repro.core.quantization import sqnr_db, unpack_int4
 from repro.core.speculative import SDConfig
 from repro.models import lm
 from repro.models.common import Family, ModelConfig
@@ -45,6 +45,26 @@ def test_w4a8_cache_path_consistent(model):
     lgd, cache = qlm.apply_quantized_lm(qp, CFG, None, toks[:, 8:9], cache=cache)
     np.testing.assert_allclose(np.asarray(full[:, 7]), np.asarray(lgp[:, -1]), atol=1e-4)
     np.testing.assert_allclose(np.asarray(full[:, 8]), np.asarray(lgd[:, 0]), atol=1e-4)
+
+
+def test_layerwise_build_equals_whole_model_quantization(model):
+    """init_quantized_lm (one layer at a time, the path that fits llama2-7b
+    on one chip) draws and packs the weights of quantizing the whole
+    init_lm model, same key splits, up to float rounding: scales agree to
+    f32 precision and an int4 weight moves at most one step, rarely."""
+    want = qlm.quantize_dense_lm(model, CFG, bits=4, rotate=True)
+    got = qlm.init_quantized_lm(KEY, CFG)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree.flatten_with_path(got)[0],
+                            jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype, path
+        if path[-1].key == "packed":
+            step = np.abs(np.asarray(unpack_int4(g, axis=-2)).astype(np.int32)
+                          - np.asarray(unpack_int4(w, axis=-2)))
+            assert step.max() <= 1 and step.mean() < 1e-3, path
+        else:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-6, atol=1e-8, err_msg=str(path))
 
 
 def test_rotation_beats_no_rotation_under_outliers(model):

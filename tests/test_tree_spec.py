@@ -132,9 +132,10 @@ def test_tree_compaction_matches_fresh_prefill(pair):
     def pool_rows(engine, request, store_attr, name):
         seq = request.t_seq if store_attr == "_t_store" else request.d_seq
         store = getattr(engine, store_attr)[request.kv_kind]
-        arr = np.asarray(store[name])
-        flat = arr.reshape(arr.shape[0], -1, *arr.shape[3:])
-        return flat[:, seq.flat_slots(np.arange(length))]
+        arr = np.asarray(store[name])  # (L, P+1, kvh, ps, hd)
+        slots = seq.flat_slots(np.arange(length))
+        ps = arr.shape[3]
+        return arr[:, slots // ps, :, slots % ps]
 
     req2 = ref_eng._requests[rid2]
     for store_attr, name in (("_t_store", "k"), ("_t_store", "v"),
