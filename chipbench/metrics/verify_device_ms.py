@@ -1,0 +1,15 @@
+"""Decode programs: mean device time of one execution of the target's
+verify step in the window (the ``jit_step`` program that runs once a round;
+see ``trace.classify_steps``)."""
+from chipbench.trace import classify_steps
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    runs = ctx.trace.in_window(ctx.trace.modules)
+    names = classify_steps(runs, "jit_step")
+    if names is None:
+        return None
+    mine = [m.dur_ns for m in runs if m.name == names["verify"]]
+    return 1e-6 * sum(mine) / len(mine)
