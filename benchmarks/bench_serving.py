@@ -166,41 +166,6 @@ def _run_host(target, draft, prompts, bs, max_tokens, page_size=16):
     return outs, summary, time.perf_counter() - t0, None
 
 
-def _attribution(target, draft, eng, verify_window, rows, record):
-    """Join the profiled engine's measured per-program device time
-    (``Engine.profile_summary()``) against the analytic per-dispatch model
-    (``core/perfmodel.program_model``) via
-    ``benchmarks.roofline_report.attribution`` and land the result in
-    ``record["attribution"]``.  Utilization is modeled/measured per call —
-    on CPU smoke it is tiny; the value is the cross-PR trend, and the
-    presence of the fused_wdos row is what ci.sh asserts."""
-    from benchmarks.roofline_report import attribution
-    from repro.core.perfmodel import LMSpec, program_model
-
-    def _spec(m):
-        n_params = sum(
-            int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(m.params)
-        )
-        return LMSpec(m.cfg.name, n_params, m.cfg.n_layers, m.cfg.d_model)
-
-    measured = eng.profile_summary()
-    modeled = program_model(
-        _spec(target), _spec(draft), verify_window=verify_window
-    )
-    att = attribution(measured, modeled)
-    assert "fused_wdos" in att["programs"], (
-        f"attribution missing fused_wdos row (has {sorted(att['programs'])})"
-    )
-    record["attribution"] = att
-    fw = att["programs"]["fused_wdos"]
-    rows.append((
-        "serving_attribution", 0.0,
-        f"{len(att['programs'])} programs profiled; fused_wdos "
-        f"{fw['calls']} calls @ {fw['s_per_call']*1e3:.2f} ms/call "
-        f"(util {fw.get('utilization_pct', 0.0):.2f}%)",
-    ))
-
-
 def _par_ab(target, draft, prompts, max_tokens, rows, record,
             trace_out=None):
     """A/B the two round schedulers on a staggered-admission adaptive
@@ -211,13 +176,10 @@ def _par_ab(target, draft, prompts, max_tokens, rows, record,
     claims over in-order issue on exactly the slots that ran, validated
     against the measured serialized slot cost on this backend.
 
-    ``trace_out`` additionally records the wdos arm with a span tracer AND
-    sampled device-time attribution (``profile_every_n=2``): the exported
-    Chrome-trace JSON gains a "device" track of per-dispatch spans next to
-    the request rows (load it in https://ui.perfetto.dev), and the measured
-    per-program wall is joined against ``core/perfmodel.program_model``
-    into ``record["attribution"]`` (modeled-vs-measured utilization per
-    dispatch program — the trend line ci.sh archives across PRs)."""
+    ``trace_out`` additionally records the wdos arm with a span tracer:
+    the exported Chrome-trace JSON holds the engine track's round, slot and
+    leaf spans next to the request rows (load it in
+    https://ui.perfetto.dev)."""
     from repro.serving import (
         Engine, EngineConfig, SamplingParams, Tracer, validate_chrome_trace,
     )
@@ -229,7 +191,6 @@ def _par_ab(target, draft, prompts, max_tokens, rows, record,
         eng = Engine(target, draft, EngineConfig(
             max_batch=len(prompts), page_size=16,
             adaptive=True, short_dl=short_dl, long_dl=long_dl, par_mode=mode,
-            profile_every_n=2 if tracer is not None else 0,
         ), trace=tracer)
         t0 = time.perf_counter()
         for p in prompts:
@@ -267,28 +228,22 @@ def _par_ab(target, draft, prompts, max_tokens, rows, record,
             tracer.export(trace_out)
             n_ev = len(trace["traceEvents"])
             assert n_ev > len(prompts), "trace unexpectedly empty"
-            dev_tids = {
+            engine_tids = {
                 e["tid"] for e in trace["traceEvents"]
-                if e.get("ph") == "M" and e.get("name") == "thread_name"
-                and e.get("args", {}).get("name") == "device"
+                if e.get("ph") == "M" and e.get("args", {}).get("name") == "engine"
             }
-            dev_progs = {
+            engine_spans = {
                 e["name"] for e in trace["traceEvents"]
-                if e.get("ph") == "X" and e.get("tid") in dev_tids
+                if e.get("ph") == "X" and e.get("tid") in engine_tids
             }
-            assert "fused_wdos" in dev_progs, (
-                f"device track missing fused_wdos spans (has {dev_progs})"
+            assert "fused_slot" in engine_spans, (
+                f"engine track missing fused_slot spans (has {engine_spans})"
             )
             rows.append((
                 "serving_wdos_trace", 0.0,
-                f"{n_ev} events -> {trace_out} (Perfetto-loadable; device "
-                f"track: {', '.join(sorted(dev_progs))})",
+                f"{n_ev} events -> {trace_out} (Perfetto-loadable)",
             ))
-            record["par"][mode]["trace"] = {
-                "path": trace_out, "events": n_ev,
-                "device_programs": sorted(dev_progs),
-            }
-            _attribution(target, draft, eng, long_dl + 1, rows, record)
+            record["par"][mode]["trace"] = {"path": trace_out, "events": n_ev}
     off_r = record["par"]["off"]["rounds_to_drain"]
     wd_r = record["par"]["wdos"]["rounds_to_drain"]
     rows.append((

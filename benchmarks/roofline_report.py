@@ -85,62 +85,6 @@ def roofline_table(records):
     return "\n".join(lines)
 
 
-def attribution(measured, modeled):
-    """Join the serving engine's MEASURED device-time attribution
-    (``Engine.profile_summary()``: per-program calls / wall seconds /
-    cost_analysis FLOPs+bytes) against the MODELED per-dispatch seconds
-    (``core/perfmodel.program_model``).  Returns the machine-readable
-    record ``bench_serving`` writes into ``BENCH_serving.json`` under
-    ``"attribution"`` plus a rendered markdown table.
-
-    ``utilization_pct`` is modeled/measured per call: how close the real
-    dispatch runs to the paper's weight-bound step model (low on CPU
-    smoke — the number is a trend line across PRs, not an absolute)."""
-    programs = {}
-    for prog, m in sorted(measured.items()):
-        calls = int(m.get("calls", 0))
-        wall = float(m.get("wall_s", 0.0))
-        per_call = wall / calls if calls else 0.0
-        modeled_s = modeled.get(prog)
-        row = {
-            "calls": calls,
-            "wall_s": wall,
-            "s_per_call": per_call,
-            "gflops_per_s": (
-                m.get("flops", 0.0) / per_call / 1e9 if per_call else 0.0
-            ),
-            "gbytes_per_s": (
-                m.get("bytes", 0.0) / per_call / 1e9 if per_call else 0.0
-            ),
-        }
-        if modeled_s is not None:
-            row["modeled_s_per_call"] = modeled_s
-            row["utilization_pct"] = (
-                100.0 * modeled_s / per_call if per_call else 0.0
-            )
-        programs[prog] = row
-    lines = [
-        "| program | calls | wall ms | ms/call | GFLOP/s | GB/s "
-        "| modeled ms/call | util % |",
-        "|---|---|---|---|---|---|---|---|",
-    ]
-    for prog, row in programs.items():
-        modeled_ms = (
-            _ms(row["modeled_s_per_call"])
-            if "modeled_s_per_call" in row else "-"
-        )
-        util = (
-            f"{row['utilization_pct']:.2f}"
-            if "utilization_pct" in row else "-"
-        )
-        lines.append(
-            f"| {prog} | {row['calls']} | {_ms(row['wall_s'])} "
-            f"| {_ms(row['s_per_call'])} | {row['gflops_per_s']:.2f} "
-            f"| {row['gbytes_per_s']:.2f} | {modeled_ms} | {util} |"
-        )
-    return {"programs": programs, "table": "\n".join(lines)}
-
-
 def run():
     """benchmarks.run hook: emit summary rows if dryrun.json exists."""
     path = os.path.join(os.path.dirname(__file__), "..", "dryrun.json")

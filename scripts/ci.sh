@@ -78,10 +78,6 @@ obs = json.load(open("BENCH_serving.json")).get("observability")
 if obs:
     print("observability:", {k: round(v, 4) if isinstance(v, float) else v
                              for k, v in sorted(obs.items())})
-att = json.load(open("BENCH_serving.json")).get("attribution")
-if att:
-    print("device-time attribution (modeled vs measured, wdos arm):")
-    print(att["table"])
 EOF
 
 echo "== compressed-KV gate (int8 capacity win + acceptance bound) =="
@@ -142,11 +138,8 @@ EOF
 
 echo "== wdos round-timeline trace (Chrome-trace schema gate) =="
 # The bench's --trace-out must round-trip through the Chrome-trace schema
-# checker non-empty — the same JSON a developer drops into Perfetto.  The
-# checker also enforces the device-track rules (thread-name metadata for
-# every tid, non-overlapping device spans); this stanza additionally
-# asserts the device track EXISTS and carries the fused wdos program,
-# with its modeled-vs-measured row landed in BENCH_serving.json.
+# checker non-empty — the same JSON a developer drops into Perfetto — with
+# the engine track carrying the wdos fused-slot spans.
 python - <<'EOF'
 import json
 from repro.serving import validate_chrome_trace
@@ -158,17 +151,12 @@ assert len(events) > 10, f"trace suspiciously small: {len(events)} events"
 meta = {e["tid"]: e["args"]["name"] for e in events if e["ph"] == "M"}
 tracks = set(meta.values())
 assert "engine" in tracks and any(t.startswith("row") for t in tracks), tracks
-assert "device" in tracks, f"no device track in {sorted(tracks)}"
-dev_tids = {tid for tid, name in meta.items() if name == "device"}
-dev_progs = {e["name"] for e in events
-             if e["ph"] == "X" and e["tid"] in dev_tids}
-assert "fused_wdos" in dev_progs, f"device track spans: {sorted(dev_progs)}"
-att = json.load(open("BENCH_serving.json"))["attribution"]["programs"]
-assert "fused_wdos" in att, sorted(att)
-assert att["fused_wdos"]["calls"] >= 1 and "utilization_pct" in att["fused_wdos"]
+eng_tids = {tid for tid, name in meta.items() if name == "engine"}
+eng_spans = {e["name"] for e in events
+             if e["ph"] == "X" and e["tid"] in eng_tids}
+assert "fused_slot" in eng_spans, f"engine track spans: {sorted(eng_spans)}"
 print(f"TRACE_wdos.json OK: {len(events)} events across "
-      f"{len(tracks)} tracks {sorted(tracks)}; device programs "
-      f"{sorted(dev_progs)}; attribution rows {sorted(att)}")
+      f"{len(tracks)} tracks {sorted(tracks)}")
 EOF
 
 echo "== perf-regression sentinel (BENCH_history.jsonl trajectory gate) =="

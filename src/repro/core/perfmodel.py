@@ -46,7 +46,6 @@ __all__ = [
     "SDMode",
     "step_time",
     "verify_time",
-    "program_model",
     "simulate_decoding",
     "DecodingResult",
     "fig6_pairs",
@@ -193,57 +192,6 @@ def verify_time(
     lm: LMSpec, hw: HWConfig, precision: Precision, window: int, **kw
 ) -> float:
     return step_time(lm, hw, precision, window=window, **kw)
-
-
-def program_model(
-    target_lm: LMSpec,
-    draft_lm: LMSpec,
-    hw: Optional[HWConfig] = None,
-    precision: Precision = Precision.W4A8,
-    *,
-    verify_window: int,
-    draft_window: int = 1,
-    tree_window: Optional[int] = None,
-    pipelined: bool = True,
-) -> Dict[str, float]:
-    """Modeled seconds per dispatch for each program the serving engine
-    executes — the MODELED side of the measured-vs-modeled attribution
-    join (``benchmarks/roofline_report.attribution`` divides the engine's
-    ``profile_summary()`` walls by these).
-
-    Program names match ``Engine._profiled``'s: ``draft``/``verify`` are
-    the two-phase dispatches, ``fused_wdos`` the cross-request PAR slot —
-    modeled as ``max(verify, draft)``, i.e. the paper's claim that the
-    draft subgraph rides inside the verify slot's shadow (THE overlap
-    question the device track answers empirically) — ``draft_slot`` the
-    masked draft-only micro-step, and the ``tree_*`` variants the same
-    shapes at the tree window width.  ``prefill`` and ``compaction`` are
-    deliberately absent: one is prompt-length-dependent, the other a pure
-    page copy with no weight traffic — neither fits the weight-bound
-    step model."""
-    hw = hw if hw is not None else HWConfig()
-    draft = step_time(draft_lm, hw, precision, window=draft_window,
-                      pipelined=pipelined)
-    verify = step_time(target_lm, hw, precision, window=verify_window,
-                       pipelined=pipelined)
-    out = {
-        "draft": draft,
-        "verify": verify,
-        "fused_wdos": max(verify, draft),
-        "draft_slot": draft,
-    }
-    if tree_window is not None:
-        t_draft = step_time(draft_lm, hw, precision, window=tree_window,
-                            pipelined=pipelined)
-        t_verify = step_time(target_lm, hw, precision, window=tree_window,
-                             pipelined=pipelined)
-        out.update({
-            "tree_draft": t_draft,
-            "tree_verify": t_verify,
-            "fused_tree": max(t_verify, t_draft),
-            "tree_draft_slot": t_draft,
-        })
-    return out
 
 
 # ---------------------------------------------------------------------------

@@ -72,13 +72,16 @@ def lru_rotate_transpose(
     return jnp.concatenate([upper, x[..., b:]], axis=-1)
 
 
+@jax.named_scope("w4a8_linear")
 def w4a8_linear(
     x: jnp.ndarray,
     packed_w: jnp.ndarray,  # (K//2, N) int8 nibble-packed
     sw: jnp.ndarray,  # (1, N)
     use_pallas: bool = True,
 ) -> jnp.ndarray:
-    """Dynamic-A8 linear over packed W4 weights: y = Q8(x) @ W4 * sx * sw."""
+    """Dynamic-A8 linear over packed W4 weights: y = Q8(x) @ W4 * sx * sw.
+    Its operations carry the named scope ``w4a8_linear`` (op metadata only;
+    a profiler trace attributes their device time to it)."""
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     xq, sx = q.quantize_act_int8(x2)
@@ -89,8 +92,10 @@ def w4a8_linear(
     return y.reshape(*lead, -1).astype(x.dtype)
 
 
+@jax.named_scope("bvq_linear")
 def bvq_linear(x: jnp.ndarray, bw: BVQWeight, use_pallas: bool = True) -> jnp.ndarray:
-    """y = x @ reconstruct(bw) with on-the-fly codebook decode."""
+    """y = x @ reconstruct(bw) with on-the-fly codebook decode.  The decode
+    and the matmul carry the named scope ``bvq_linear``."""
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     if use_pallas:

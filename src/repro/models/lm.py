@@ -515,10 +515,11 @@ def apply_lm(
     x = L.rmsnorm(params["final_norm"], x)
     if last_logit_only:
         x = x[:, -1:, :]
-    logits = x @ params["embed"]["head"].astype(cfg.jdtype)
-    if cfg.vocab_padded != cfg.vocab:
-        pad_mask = jnp.arange(cfg.vocab_padded) < cfg.vocab
-        logits = jnp.where(pad_mask, logits, -1e9)
+    with jax.named_scope("lm_head"):
+        logits = x @ params["embed"]["head"].astype(cfg.jdtype)
+        if cfg.vocab_padded != cfg.vocab:
+            pad_mask = jnp.arange(cfg.vocab_padded) < cfg.vocab
+            logits = jnp.where(pad_mask, logits, -1e9)
     if new_cache is not None:
         new_cache["lengths" if paged else "length"] = offset + s
     return logits, new_cache

@@ -37,13 +37,14 @@ OBSERVABILITY (docs/OBSERVABILITY.md is the reference): every engine
 carries a ``MetricsRegistry`` (``observability.py`` — zero-dependency
 counters/gauges/histograms, Prometheus-text ``render()``) that the
 batcher, the async front-end, the HTTP server, and the benchmarks all
-share; pass ``Engine(..., trace=Tracer())`` to additionally record
-per-request span timelines exportable as Chrome-trace/Perfetto JSON
-(``tracing.py``).  Instrumentation is off-by-default-cheap and never
-adds host syncs — bit-identity is unaffected with tracing enabled.
-``EngineConfig.profile_every_n`` samples device-time attribution (each
-dispatch program bracketed + cost-stamped onto a "device" trace track),
-and every engine carries a ``flight_recorder.FlightRecorder`` — a
+share.  The engine's and server's spans (``tracing.py``) always go to
+``jax.profiler``, on the same clock as the device's programs, so a
+profiler session (``jax.profiler.start_trace``) attributes each idle gap
+of the chip to the host work under way; pass ``Engine(...,
+trace=Tracer())`` to also record per-request span timelines exportable as
+Chrome-trace/Perfetto JSON.  Instrumentation never adds host syncs —
+bit-identity is unaffected with tracing and the profiler on.  Every
+engine carries a ``flight_recorder.FlightRecorder`` — a
 bounded ring of per-round records with anomaly postmortems, served at
 ``GET /debug/flight``.
 

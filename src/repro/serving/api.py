@@ -175,6 +175,9 @@ class RequestOutput:
     new_token_ids: List[int]
     finished: bool
     outputs: List[CompletionOutput]
+    # end of the engine round that produced this output, on
+    # time.perf_counter (the server's stream_lag_seconds reads it)
+    round_end: Optional[float] = None
 
     @property
     def token_ids(self) -> List[int]:
@@ -270,14 +273,6 @@ class EngineConfig:
     # never branches (a chain-shaped tree), 1.0 branches at every frontier
     # position the node budget allows
     branch_threshold: float = 0.6
-    # Sampled device-time profiling: every Nth engine round, each dispatched
-    # program (prefill / draft / verify / fused_wdos / tree variants /
-    # compaction) is bracketed with block_until_ready timing, stamped once
-    # with XLA cost_analysis() FLOPs/bytes at compile time, and emitted as a
-    # span on the tracer's "device" track.  0 disables (the default); timing
-    # never changes the math, so tokens stay bit-identical with profiling on
-    # (tests/test_observability.py).  Unprofiled rounds pay one int compare.
-    profile_every_n: int = 0
     # Flight recorder (serving/flight_recorder.py): bounded ring of
     # per-round records with anomaly triggers (slow round, acceptance
     # collapse, pool exhaustion, admission stall).  flight_ring=0 disables
@@ -320,10 +315,6 @@ class EngineConfig:
                 raise ValueError("set num_pages or pool_bytes, not both")
             if self.pool_bytes <= 0:
                 raise ValueError(f"pool_bytes must be > 0, got {self.pool_bytes}")
-        if self.profile_every_n < 0:
-            raise ValueError(
-                f"profile_every_n must be >= 0, got {self.profile_every_n}"
-            )
         if self.flight_ring < 0:
             raise ValueError(
                 f"flight_ring must be >= 0, got {self.flight_ring}"

@@ -343,17 +343,18 @@ class AsyncEngine:
                 # see the Request before its record drops
                 for rid in releases:
                     eng.release_request(rid)
-                if has_work or cmds:
-                    # republish the stats snapshot: single attribute
-                    # assignment (atomic under the GIL), so a concurrent
-                    # stats() sees either the old or the new complete view.
-                    # Published BEFORE the outputs below so that by the
-                    # time a consumer observes its stream finish/abort,
-                    # stats() already reflects that state (freed pages,
-                    # decremented active count)
-                    self._snapshot = eng.stats_snapshot()
-                for rid, item in posts:
-                    loop.call_soon_threadsafe(self._post, rid, item)
+                if has_work or cmds:  # (posts come only from these)
+                    with eng.tracer.span("async", "post", outputs=len(posts)):
+                        # republish the stats snapshot: single attribute
+                        # assignment (atomic under the GIL), so a concurrent
+                        # stats() sees either the old or the new complete
+                        # view.  Published BEFORE the outputs below so that
+                        # by the time a consumer observes its stream
+                        # finish/abort, stats() already reflects that state
+                        # (freed pages, decremented active count)
+                        self._snapshot = eng.stats_snapshot()
+                        for rid, item in posts:
+                            loop.call_soon_threadsafe(self._post, rid, item)
                 if not has_work:
                     if self._stopping:
                         break

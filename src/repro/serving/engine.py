@@ -754,17 +754,6 @@ class Engine:
             self._now = lambda: time.perf_counter() - _t0
         self._init_metrics()
 
-        # sampled device-time profiling: every profile_every_n-th round,
-        # each dispatched program is bracketed with block_until_ready
-        # timing (the ONLY place the engine ever adds a device sync —
-        # timing never changes the math, so tokens stay bit-identical) and
-        # stamped once with its compile-time cost_analysis FLOPs/bytes.
-        self._profile_every = cfg.profile_every_n
-        self._profile_round = False
-        self._round_idx = 0
-        self._prog_cost: Dict[str, dict] = {}
-        self._prog_wall: Dict[str, float] = {}
-        self._prog_calls: Dict[str, int] = {}
         # flight recorder: bounded ring of per-round records with anomaly
         # triggers (serving/flight_recorder.py); fed at every round-wall
         # site INCLUDING empty rounds (pool exhaustion shows up as rounds
@@ -1070,66 +1059,6 @@ class Engine:
         """(target PoolStats, draft PoolStats) — page residency right now."""
         return self._t_pool.stats(), self._d_pool.stats()
 
-    # -- sampled device-time profiling ---------------------------------------
-
-    def _program_cost(self, program: str, fn, args) -> dict:
-        """One-time compile-time stamp per program name: XLA
-        ``cost_analysis()`` FLOPs / bytes accessed.  MUST run before the
-        program's first profiled dispatch — the step fns donate their
-        stores, so lowering from live args is only safe while the caller
-        still owns them.  ``{}`` for callables without ``.lower`` (the
-        host-orchestrated prefill) and for a compiled program whose backend
-        reports no cost analysis; any other error propagates."""
-        cost = self._prog_cost.get(program)
-        if cost is None:
-            cost = {}
-            if hasattr(fn, "lower"):
-                analysis = fn.lower(*args).compile().cost_analysis()
-                if isinstance(analysis, (list, tuple)):
-                    analysis = analysis[0] if analysis else None
-                if analysis:
-                    cost = {
-                        "flops": float(analysis.get("flops", 0.0)),
-                        "bytes": float(analysis.get("bytes accessed", 0.0)),
-                    }
-            self._prog_cost[program] = cost
-        return cost
-
-    def _profiled(self, program: str, fn, *args):
-        """Run one dispatch.  On a profiled round (``profile_every_n``-th
-        step), bracket it with ``block_until_ready`` timing, accumulate
-        per-program wall/calls for ``profile_summary()``, and emit a span
-        on the tracer's "device" track carrying the program's compile-time
-        FLOPs/bytes stamp.  Off-round cost: one bool check."""
-        if not self._profile_round:
-            return fn(*args)
-        cost = self._program_cost(program, fn, args)
-        t0 = self._now()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        t1 = self._now()
-        self._prog_wall[program] = self._prog_wall.get(program, 0.0) + (t1 - t0)
-        self._prog_calls[program] = self._prog_calls.get(program, 0) + 1
-        self.tracer.rec("device", program, t0, t1, cat="device", **cost)
-        return out
-
-    def profile_summary(self) -> Dict[str, dict]:
-        """Measured device-time attribution: per dispatched program, the
-        bracketed call count, summed wall seconds, and the one-time
-        cost_analysis stamp.  Empty unless ``profile_every_n > 0`` sampled
-        at least one round — ``benchmarks/roofline_report.attribution``
-        joins this against ``core/perfmodel.program_model``."""
-        out: Dict[str, dict] = {}
-        for prog, calls in self._prog_calls.items():
-            cost = self._prog_cost.get(prog) or {}
-            out[prog] = {
-                "calls": calls,
-                "wall_s": self._prog_wall.get(prog, 0.0),
-                "flops": cost.get("flops", 0.0),
-                "bytes": cost.get("bytes", 0.0),
-            }
-        return out
-
     # -- flight-recorder feed ------------------------------------------------
 
     def _flight_base(self) -> Tuple[float, float, int]:
@@ -1187,7 +1116,7 @@ class Engine:
             m[slot] = req.kv_kind == "int8"
         return jnp.asarray(m)
 
-    def _dispatch(self, program, step_fn, params, tokens, stores, table,
+    def _dispatch(self, step_fn, params, tokens, stores, table,
                   lengths, kvq_dev, *extra):
         """One logical batched forward over every storage kind.
 
@@ -1197,21 +1126,17 @@ class Engine:
         them), and a row only ever READS the store of its kind, so the
         wrong-kind dispatch leaves unread garbage — never corruption.
         ``extra`` forwards step-specific trailing operands (the tree
-        steps' win_pos / tree_mask).  ``program`` names the dispatch for
-        the sampled device-time profiler (``_profiled`` is a passthrough
-        on unprofiled rounds)."""
+        steps' win_pos / tree_mask)."""
         if kvq_dev is None:
             k0 = self._kinds[0]
-            logits, stores[k0] = self._profiled(
-                program, step_fn, params, tokens, stores[k0], table,
-                lengths, *extra,
+            logits, stores[k0] = step_fn(
+                params, tokens, stores[k0], table, lengths, *extra,
             )
             return logits
         outs = {}
         for k in self._kinds:
-            outs[k], stores[k] = self._profiled(
-                program, step_fn, params, tokens, stores[k], table,
-                lengths, *extra,
+            outs[k], stores[k] = step_fn(
+                params, tokens, stores[k], table, lengths, *extra,
             )
         return jnp.where(kvq_dev[:, None, None], outs["int8"], outs["none"])
 
@@ -1316,9 +1241,16 @@ class Engine:
                 )
         return store, dense
 
-    def _admit(self) -> None:
-        """Admit whatever fits and prefill it into both pools."""
-        for slot, req in self._batcher.admit():
+    def _admit(self, rnd: int) -> None:
+        """Admit whatever fits and prefill it into both pools.  Profiler
+        spans (round ``rnd``): ``engine.admit`` over the batcher's
+        admission and page allocation, then per request one
+        ``engine.prefill`` per model and an ``engine.admit`` over the
+        request's own bookkeeping (prefix-cache donation)."""
+        span = self.tracer.span
+        with span("engine", "admit", round=rnd):
+            admitted = self._batcher.admit()
+        for slot, req in admitted:
             t_adm = self._now()
             req.admit_ts = t_adm
             if req.submit_ts is not None:
@@ -1327,33 +1259,35 @@ class Engine:
                 f"row{slot}", "admit", cat="lifecycle", rid=req.rid
             )
             kind = req.kv_kind
-            # "prefill" brackets the whole host-orchestrated prefill (the
-            # forward + device scatter); its cost stamp degrades to {} —
-            # _prefill_into is not a single jitted program
-            self._t_store[kind], t_kv = self._profiled(
-                "prefill", self._prefill_into,
-                req, self.target, self._t_iface, req.t_seq,
-                self._t_store[kind], self._t_tables, slot, "target",
-            )
-            self._d_store[kind], d_kv = self._profiled(
-                "prefill", self._prefill_into,
-                req, self.draft, self._d_iface, req.d_seq,
-                self._d_store[kind], self._d_tables, slot, "draft",
-            )
-            if self._prefix is not None:
-                if req.prefix_match is not None:
-                    self._m_tokens_saved.inc(req.prefix_match.tokens_matched)
-                if t_kv is not None and d_kv is not None:
-                    # donate the freshly prefilled FULL blocks: the tree
-                    # pins the pages (pool incref) and mirrors the dense
-                    # FP rows for future hits' seeded tail prefills
-                    self._prefix.insert(
-                        req.prompt, kind,
-                        {"target": req.t_seq.pages, "draft": req.d_seq.pages},
-                        {"target": t_kv, "draft": d_kv},
-                        upto=req.prompt.shape[0] - 1,
-                    )
-            req.state = RequestState.DECODE
+            m = req.prefix_match.tokens_matched if req.prefix_match else 0
+            tokens = max(req.prompt.shape[0] - 1 - m, 0)
+            with span("engine", "prefill", round=rnd, model="target",
+                      rid=req.rid, tokens=tokens):
+                self._t_store[kind], t_kv = self._prefill_into(
+                    req, self.target, self._t_iface, req.t_seq,
+                    self._t_store[kind], self._t_tables, slot, "target",
+                )
+            with span("engine", "prefill", round=rnd, model="draft",
+                      rid=req.rid, tokens=tokens):
+                self._d_store[kind], d_kv = self._prefill_into(
+                    req, self.draft, self._d_iface, req.d_seq,
+                    self._d_store[kind], self._d_tables, slot, "draft",
+                )
+            with span("engine", "admit", round=rnd, row=slot, rid=req.rid):
+                if self._prefix is not None:
+                    if req.prefix_match is not None:
+                        self._m_tokens_saved.inc(m)
+                    if t_kv is not None and d_kv is not None:
+                        # donate the freshly prefilled FULL blocks: the tree
+                        # pins the pages (pool incref) and mirrors the dense
+                        # FP rows for future hits' seeded tail prefills
+                        self._prefix.insert(
+                            req.prompt, kind,
+                            {"target": req.t_seq.pages, "draft": req.d_seq.pages},
+                            {"target": t_kv, "draft": d_kv},
+                            upto=req.prompt.shape[0] - 1,
+                        )
+                req.state = RequestState.DECODE
             self.tracer.rec(
                 f"row{slot}", "prefill", t_adm, self._now(),
                 cat="prefill", rid=req.rid,
@@ -1372,11 +1306,6 @@ class Engine:
         tree-speculation round instead: top-k branch drafting into a
         fixed-width window, one causally-tree-masked verify dispatch, and
         the lossless multi-branch accept walk (core/speculative.py)."""
-        self._round_idx += 1
-        self._profile_round = (
-            self._profile_every > 0
-            and self._round_idx % self._profile_every == 0
-        )
         if self.cfg.spec_mode == "tree":
             if self.cfg.par_mode == "wdos":
                 return self._step_fused_tree()
@@ -1386,10 +1315,17 @@ class Engine:
         return self._step_two_phase()
 
     def _step_two_phase(self) -> List[RequestOutput]:
+        """Two-phase chain round.  The engine thread's time is split into
+        non-overlapping profiler leaf spans, each with ``round``: admit,
+        prefill, table_upload, then per draft step ``draft_dispatch`` /
+        ``draft_pull`` / ``draft_sample``, then ``verify_dispatch``,
+        ``verify_pull``, ``accept`` and ``emit``."""
         cfg = self.cfg
+        span = self.tracer.span
+        rnd = self._batcher.step_count + 1
         t_step = self._now()
         fb = self._flight_base()
-        self._admit()
+        self._admit(rnd)
         active = self._batcher.active()
         if not active:
             self._batcher.step_count += 1
@@ -1405,9 +1341,14 @@ class Engine:
         kvq_dev = self._kvq_mask(active)
 
         t0 = self._now()
-        d_table, d_len0 = self._d_tables.load((s, r.d_seq) for s, r in active)
-        t_table, t_len0 = self._t_tables.load((s, r.t_seq) for s, r in active)
-        t_draft0 = self._now()
+        with span("engine", "table_upload", round=rnd):
+            d_table, d_len0 = self._d_tables.load((s, r.d_seq) for s, r in active)
+            t_table, t_len0 = self._t_tables.load((s, r.t_seq) for s, r in active)
+            t_draft0 = self._now()
+            cur = np.zeros((cfg.max_batch,), np.int32)
+            for slot, req in active:
+                cur[slot] = req.last_tok
+            cur_dev = jnp.asarray(cur)
         self._m_table_upload.inc(t_draft0 - t0)
 
         # ---- draft phase: round_dl proposal steps + 1 straggler step, all
@@ -1417,20 +1358,23 @@ class Engine:
         # can draw from its own (temperature/top-k, per-request-key) draft
         # distribution — greedy rows still take the argmax (np and jnp share
         # the first-max tie rule, so the round stays bit-identical for them).
-        cur = np.zeros((cfg.max_batch,), np.int32)
-        for slot, req in active:
-            cur[slot] = req.last_tok
-        cur_dev = jnp.asarray(cur)
         draft_cols: List[Any] = []
         q_cols: List[np.ndarray] = []  # per-position draft logits (sampled rounds)
         for j in range(round_dl + 1):
-            logits = self._dispatch(
-                "draft", self._d_step, self.draft.params, cur_dev[:, None],
-                self._d_store, d_table, d_len0 + j, kvq_dev,
-            )
-            if j < round_dl:
-                if any_sampled:
+            with span("engine", "draft_dispatch", round=rnd, j=j):
+                logits = self._dispatch(
+                    self._d_step, self.draft.params, cur_dev[:, None],
+                    self._d_store, d_table, d_len0 + j, kvq_dev,
+                )
+                if j < round_dl and not any_sampled:
+                    cur_dev = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+                    draft_cols.append(cur_dev)
+            if j < round_dl and any_sampled:
+                with span("engine", "draft_pull", round=rnd, j=j,
+                          bytes=logits.nbytes // logits.shape[1]):
                     last = np.asarray(logits[:, -1, :])
+                with span("engine", "draft_sample", round=rnd, j=j,
+                          rows=len(active)):
                     q_cols.append(last)
                     nxt = np.argmax(last, axis=-1).astype(np.int32)
                     for slot, req in active:
@@ -1442,15 +1386,14 @@ class Engine:
                             )
                     draft_cols.append(nxt)
                     cur_dev = jnp.asarray(nxt)
-                else:
-                    cur_dev = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-                    draft_cols.append(cur_dev)
-            # else: straggler — feeds d_{round_dl-1}, completing the cache for
-            # fully-accepted rows; over-written rows rewind it away below.
+            # j == round_dl: straggler — feeds d_{round_dl-1}, completing the
+            # cache for fully-accepted rows; over-written rows rewind it away
         if any_sampled:
             drafts = np.stack(draft_cols, axis=1)  # (B, round_dl)
         else:
-            drafts = np.asarray(jnp.stack(draft_cols, axis=1))
+            with span("engine", "draft_pull", round=rnd, j=round_dl,
+                      bytes=4 * cfg.max_batch * round_dl):
+                drafts = np.asarray(jnp.stack(draft_cols, axis=1))
         t_verify0 = self._now()
         self.tracer.rec(
             "engine", "draft_phase", t_draft0, t_verify0,
@@ -1458,14 +1401,16 @@ class Engine:
         )
 
         # ---- verify phase: one batched pass scoring [last_tok, drafts...]
-        window = np.zeros((cfg.max_batch, round_dl + 1), np.int32)
-        window[:, 0] = cur
-        window[:, 1:] = drafts
-        v_logits = self._dispatch(
-            "verify", self._t_step, self.target.params, jnp.asarray(window),
-            self._t_store, t_table, t_len0, kvq_dev,
-        )
-        p_logits = np.asarray(v_logits)  # (B, round_dl+1, V)
+        with span("engine", "verify_dispatch", round=rnd):
+            window = np.zeros((cfg.max_batch, round_dl + 1), np.int32)
+            window[:, 0] = cur
+            window[:, 1:] = drafts
+            v_logits = self._dispatch(
+                self._t_step, self.target.params, jnp.asarray(window),
+                self._t_store, t_table, t_len0, kvq_dev,
+            )
+        with span("engine", "verify_pull", round=rnd, bytes=v_logits.nbytes):
+            p_logits = np.asarray(v_logits)  # (B, round_dl+1, V)
         self.tracer.rec(
             "engine", "verify_phase", t_verify0, self._now(),
             cat="phase", rows=len(active),
@@ -1474,43 +1419,51 @@ class Engine:
         # ---- per-request accept / commit: a pure length update per row —
         # the KV was written in place by the steps above, and rewind just
         # drops the tail (stale pool slots are masked, then overwritten)
-        work = []
-        progressed: List[Request] = []
-        for slot, req in active:
-            dl = dls[slot]
-            sp = req.sampling
-            if sp.greedy:
-                new, n_acc = speculative_accept_greedy_host(
-                    drafts[slot], p_logits[slot], dl
-                )
-            else:
-                q_logits = np.stack([q_cols[j][slot] for j in range(dl)])
-                new, n_acc = speculative_sample_host(
-                    req.accept_key(), drafts[slot], p_logits[slot], q_logits,
-                    dl, sp.temperature, sp.top_k, sp.top_p,
-                )
-            req.commit(new)
-            req.record_round(modes[slot], dl, n_acc, len(new))
-            req.rounds += 1
-            req.drafted += dl
-            req.accepted += n_acc
-            req.controller.observe(n_acc, dl)
-            self._m_drafted.inc(dl)
-            self._m_accepted.inc(n_acc)
-            self._m_round_accept.observe(n_acc / dl if dl else 0.0)
-            if self.tracer.enabled:
-                self.tracer.instant(
-                    f"row{slot}", "commit", cat="commit",
-                    rid=req.rid, drafted=dl, accepted=n_acc,
-                )
-            work.append((req, dl))
-            progressed.append(req)
-            # both models wrote round_dl+1 positions; keep n_acc + 1
-            # (draft invariant: cache == committed[:-1], incl. straggler)
-            for seq in (req.t_seq, req.d_seq):
-                seq.advance(round_dl + 1)
-                seq.rewind(round_dl - n_acc, release_pages=False)
-        self._batcher.model_round(work)
+        with span("engine", "accept", round=rnd, rows=len(active)):
+            work = []
+            progressed: List[Request] = []
+            for slot, req in active:
+                dl = dls[slot]
+                sp = req.sampling
+                if sp.greedy:
+                    new, n_acc = speculative_accept_greedy_host(
+                        drafts[slot], p_logits[slot], dl
+                    )
+                else:
+                    q_logits = np.stack([q_cols[j][slot] for j in range(dl)])
+                    new, n_acc = speculative_sample_host(
+                        req.accept_key(), drafts[slot], p_logits[slot],
+                        q_logits, dl, sp.temperature, sp.top_k, sp.top_p,
+                    )
+                req.commit(new)
+                req.record_round(modes[slot], dl, n_acc, len(new))
+                req.rounds += 1
+                req.drafted += dl
+                req.accepted += n_acc
+                req.controller.observe(n_acc, dl)
+                self._m_drafted.inc(dl)
+                self._m_accepted.inc(n_acc)
+                self._m_round_accept.observe(n_acc / dl if dl else 0.0)
+                if self.tracer.enabled:
+                    self.tracer.instant(
+                        f"row{slot}", "commit", cat="commit",
+                        rid=req.rid, drafted=dl, accepted=n_acc,
+                    )
+                work.append((req, dl))
+                progressed.append(req)
+                # both models wrote round_dl+1 positions; keep n_acc + 1
+                # (draft invariant: cache == committed[:-1], incl. straggler)
+                for seq in (req.t_seq, req.d_seq):
+                    seq.advance(round_dl + 1)
+                    seq.rewind(round_dl - n_acc, release_pages=False)
+            self._batcher.model_round(work)
+            self._retire_done(active)
+            t_end = self._end_round(t_step, len(active), "off")
+            self._flight_round(fb, t_step, t_end, len(active), "two_phase")
+        return self._emit(rnd, progressed, t_end)
+
+    def _retire_done(self, active) -> None:
+        """Retire every finished request of ``active`` (end of a round)."""
         for slot, req in active:
             if req.done:
                 self._t_tables.clear_row(slot)
@@ -1521,18 +1474,27 @@ class Engine:
                         rid=req.rid, reason=req.finish_reason or "length",
                     )
                 self._batcher.retire(slot)
+
+    def _end_round(self, t_step: float, rows: int, par_mode: str) -> float:
+        """Close a round: count it, observe its wall (``round_wall_seconds``)
+        and record its in-memory ``step#k`` span.  Returns the end time."""
         self._batcher.step_count += 1
         self._m_steps.inc()
         t_end = self._now()
         self._m_round_wall.observe(t_end - t_step)
         self.tracer.rec(
             "engine", f"step#{self._batcher.step_count}", t_step, t_end,
-            cat="step", par_mode="off", rows=len(active),
+            cat="step", par_mode=par_mode, rows=rows,
         )
         self._refresh_gauges()
-        self._flight_round(fb, t_step, t_end, len(active), "two_phase")
+        return t_end
 
-        return [self._output_for(req, t_end) for req in progressed]
+    def _emit(self, rnd: int, reqs, t_end: float) -> List[RequestOutput]:
+        """The round's ``RequestOutput``s, under the ``engine.emit`` span;
+        each carries the round's end on ``time.perf_counter``."""
+        with self.tracer.span("engine", "emit", round=rnd):
+            round_end = time.perf_counter()
+            return [self._output_for(req, t_end, round_end) for req in reqs]
 
     # -- tree speculation (spec_mode="tree") ---------------------------------
 
@@ -1609,39 +1571,33 @@ class Engine:
         """Flush queued tree-compaction moves: one fixed-width
         ``_compact_slots`` dispatch per (pool, kind) that has any, padded
         with scratch-page self-copies so each compiles once.  Each
-        dispatch counts in ``tree_compactions_total`` and the flush spans
-        the engine track (a tree round otherwise hides its KV relocation
-        cost in the step gap)."""
-        if not any(
-            src for mv in (moves_t, moves_d) for (src, _) in mv.values()
-        ):
+        dispatch counts in ``tree_compactions_total`` and the flush is the
+        ``engine.compaction`` span (a tree round otherwise hides its KV
+        relocation cost in the step gap)."""
+        jobs = [
+            (stores, pool, k, src, dst)
+            for moves, stores, pool in (
+                (moves_t, self._t_store, self._t_pool),
+                (moves_d, self._d_store, self._d_pool),
+            )
+            for k, (src, dst) in moves.items() if src
+        ]
+        if not jobs:
             return
-        t0 = self._now()
         cap = self.cfg.max_batch * self.cfg.tree_budget
-        n_dispatched = 0
-        for moves, stores, pool in (
-            (moves_t, self._t_store, self._t_pool),
-            (moves_d, self._d_store, self._d_pool),
-        ):
-            scratch = pool.num_pages * pool.page_size  # the extra page's 1st slot
-            for k, (src, dst) in moves.items():
-                if not src:
-                    continue
+        rnd = self._batcher.step_count + 1  # the round still running
+        with self.tracer.span("engine", "compaction", cat="phase", round=rnd,
+                              dispatches=len(jobs)):
+            for stores, pool, k, src, dst in jobs:
+                scratch = pool.num_pages * pool.page_size  # extra page's 1st slot
                 s = np.full((cap,), scratch, np.int64)
                 d = np.full((cap,), scratch, np.int64)
                 s[: len(src)] = src
                 d[: len(dst)] = dst
-                stores[k] = self._profiled(
-                    "compaction", _compact_slots,
-                    stores[k], jnp.asarray(s), jnp.asarray(d),
+                stores[k] = _compact_slots(
+                    stores[k], jnp.asarray(s), jnp.asarray(d)
                 )
                 self._m_tree_compactions.inc()
-                n_dispatched += 1
-        if self.tracer.enabled:
-            self.tracer.rec(
-                "engine", "compaction", t0, self._now(),
-                cat="phase", dispatches=n_dispatched,
-            )
 
     def _step_two_phase_tree(self) -> List[RequestOutput]:
         """Tree-speculation round, two-phase schedule: grow every active
@@ -1651,11 +1607,15 @@ class Engine:
         and pad slots hold not-yet-read garbage — then verify every
         complete tree in ONE tree-masked target dispatch and walk the
         multi-branch accept rule per row.  Dispatch count matches a chain
-        round of the same depth (round_depth + 1 draft + 1 verify)."""
+        round of the same depth (round_depth + 1 draft + 1 verify).  The
+        profiler leaf spans are the chain round's, plus
+        ``engine.compaction``."""
         cfg = self.cfg
+        span = self.tracer.span
+        rnd = self._batcher.step_count + 1
         t_step = self._now()
         fb = self._flight_base()
-        self._admit()
+        self._admit(rnd)
         active = self._batcher.active()
         if not active:
             self._batcher.step_count += 1
@@ -1678,8 +1638,9 @@ class Engine:
         kvq_dev = self._kvq_mask(active)
 
         t0 = self._now()
-        d_table, d_len0 = self._d_tables.load((s, r.d_seq) for s, r in active)
-        t_table, t_len0 = self._t_tables.load((s, r.t_seq) for s, r in active)
+        with span("engine", "table_upload", round=rnd):
+            d_table, d_len0 = self._d_tables.load((s, r.d_seq) for s, r in active)
+            t_table, t_len0 = self._t_tables.load((s, r.t_seq) for s, r in active)
         t_draft0 = self._now()
         self._m_table_upload.inc(t_draft0 - t0)
 
@@ -1701,16 +1662,21 @@ class Engine:
         # straggler feeding the complete tree (lands the leaf KV for
         # fully-accepted paths; rewind drops the rest)
         for j in range(round_depth + 1):
-            tok_dev, pos_dev, tm_dev = window_inputs()
-            logits = self._dispatch(
-                "tree_draft", self._d_tree_step, self.draft.params, tok_dev,
-                self._d_store, d_table, d_len0, kvq_dev, pos_dev, tm_dev,
-            )
+            with span("engine", "draft_dispatch", round=rnd, j=j):
+                tok_dev, pos_dev, tm_dev = window_inputs()
+                logits = self._dispatch(
+                    self._d_tree_step, self.draft.params, tok_dev,
+                    self._d_store, d_table, d_len0, kvq_dev, pos_dev, tm_dev,
+                )
             if j < round_depth:
-                l_np = np.asarray(logits)
-                for slot, req in active:
-                    if not req.tree_full:
-                        _sample_tree_level(req, cfg, l_np[slot])
+                with span("engine", "draft_pull", round=rnd, j=j,
+                          bytes=logits.nbytes):
+                    l_np = np.asarray(logits)
+                with span("engine", "draft_sample", round=rnd, j=j,
+                          rows=len(active)):
+                    for slot, req in active:
+                        if not req.tree_full:
+                            _sample_tree_level(req, cfg, l_np[slot])
         t_verify0 = self._now()
         self.tracer.rec(
             "engine", "tree_draft", t_draft0, t_verify0,
@@ -1718,12 +1684,14 @@ class Engine:
         )
 
         # ---- verify phase: one tree-masked batched pass over full trees
-        tok_dev, pos_dev, tm_dev = window_inputs()
-        v_logits = self._dispatch(
-            "tree_verify", self._t_tree_step, self.target.params, tok_dev,
-            self._t_store, t_table, t_len0, kvq_dev, pos_dev, tm_dev,
-        )
-        p_logits = np.asarray(v_logits)  # (B, W, V)
+        with span("engine", "verify_dispatch", round=rnd):
+            tok_dev, pos_dev, tm_dev = window_inputs()
+            v_logits = self._dispatch(
+                self._t_tree_step, self.target.params, tok_dev,
+                self._t_store, t_table, t_len0, kvq_dev, pos_dev, tm_dev,
+            )
+        with span("engine", "verify_pull", round=rnd, bytes=v_logits.nbytes):
+            p_logits = np.asarray(v_logits)  # (B, W, V)
         self.tracer.rec(
             "engine", "tree_verify", t_verify0, self._now(),
             cat="phase", rows=len(active), spec="tree",
@@ -1734,39 +1702,23 @@ class Engine:
         progressed: List[Request] = []
         moves_t = {k: ([], []) for k in self._kinds}
         moves_d = {k: ([], []) for k in self._kinds}
-        for slot, req in active:
-            self._tree_verify_commit(
-                slot, req, p_logits[slot], modes[slot], dls[slot],
-                moves_t, moves_d, work,
-            )
-            progressed.append(req)
+        with span("engine", "accept", round=rnd, rows=len(active)):
+            for slot, req in active:
+                self._tree_verify_commit(
+                    slot, req, p_logits[slot], modes[slot], dls[slot],
+                    moves_t, moves_d, work,
+                )
+                progressed.append(req)
         self._compact_pools(moves_t, moves_d)
-        self._batcher.model_round(work)
-        for slot, req in active:
-            if req.done:
-                self._t_tables.clear_row(slot)
-                self._d_tables.clear_row(slot)
-                if self.tracer.enabled:
-                    self.tracer.instant(
-                        f"row{slot}", "finish", cat="lifecycle",
-                        rid=req.rid, reason=req.finish_reason or "length",
-                    )
-                self._batcher.retire(slot)
-        self._batcher.step_count += 1
-        self._m_steps.inc()
-        t_end = self._now()
-        self._m_round_wall.observe(t_end - t_step)
-        self.tracer.rec(
-            "engine", f"step#{self._batcher.step_count}", t_step, t_end,
-            cat="step", par_mode="off", rows=len(active),
-        )
-        self._refresh_gauges()
-        self._flight_round(fb, t_step, t_end, len(active), "two_phase_tree")
+        with span("engine", "accept", round=rnd, rows=len(active)):
+            self._batcher.model_round(work)
+            self._retire_done(active)
+            t_end = self._end_round(t_step, len(active), "off")
+            self._flight_round(fb, t_step, t_end, len(active), "two_phase_tree")
+        return self._emit(rnd, progressed, t_end)
 
-        return [self._output_for(req, t_end) for req in progressed]
-
-    def _output_for(self, req: Request,
-                    now: Optional[float] = None) -> RequestOutput:
+    def _output_for(self, req: Request, now: Optional[float] = None,
+                    round_end: Optional[float] = None) -> RequestOutput:
         """One streaming RequestOutput: the newly deliverable tokens since
         the last step (``Request.take_delta`` — stop-string holdback may
         defer tokens, never retract them) plus the cumulative deliverable
@@ -1797,6 +1749,7 @@ class Engine:
                 token_ids=[int(t) for t in req.out[: req.emittable_len()]],
                 finish_reason=req.finish_reason,
             )],
+            round_end=round_end,
         )
 
     # -- the fused cross-request PAR round (par_mode="wdos") -----------------
@@ -1817,11 +1770,20 @@ class Engine:
         round count and bit-identical tokens).  Mid-window phase state
         carries across steps; every active row completes at least one
         verify per round (its remaining cycle is at most ``max_dl + 1``
-        slots), so each round streams tokens for every active request."""
+        slots), so each round streams tokens for every active request.
+
+        Profiler leaf spans: the chain round's admit, prefill,
+        table_upload, then per slot ``engine.slot_dispatch`` (planning,
+        inputs and the fused or draft-only dispatch), the pulls
+        (``verify_pull``, ``draft_pull`` with ``j`` the slot), the draft
+        rows' ``draft_sample`` and the verify rows' ``accept``; then
+        ``accept`` (round bookkeeping) and ``emit``."""
         cfg = self.cfg
+        span = self.tracer.span
+        rnd = self._batcher.step_count + 1
         t_step = self._now()
         fb = self._flight_base()
-        self._admit()
+        self._admit(rnd)
         if not self._batcher.active():
             self._batcher.step_count += 1
             self._m_steps.inc()
@@ -1843,94 +1805,102 @@ class Engine:
         # page tables are lifetime-stable: one cached upload serves every
         # slot of the step (rows retired mid-step are inert via the masks)
         t0 = self._now()
-        d_table = self._d_tables.table_dev()
-        t_table = self._t_tables.table_dev()
+        with span("engine", "table_upload", round=rnd):
+            d_table = self._d_tables.table_dev()
+            t_table = self._t_tables.table_dev()
         self._m_table_upload.inc(self._now() - t0)
 
-        for _ in range(horizon):
+        for i_slot in range(horizon):
             active = self._batcher.active()
             if not active:
                 break
             by_slot = dict(active)
-            for _, req in active:
-                if req.pending_dl is None:
-                    req.begin_window(req.controller.draft_len())
-            plan = sch.plan_mixed_slot([
-                sch.RowPhase(slot=s, window=r.pending_dl,
-                             drafted=len(r.pending))
-                for s, r in active
-            ])
-
-            # assemble the slot's per-row inputs (O(B) int32 host work)
-            d_tok = np.zeros((b, 1), np.int32)
-            d_len = np.zeros((b,), np.int32)
-            d_mask = np.zeros((b,), bool)
-            for slot in plan.draft_rows:
-                req = by_slot[slot]
-                d_tok[slot, 0] = req.draft_tip
-                d_len[slot] = req.d_seq.length + len(req.pending)
-                d_mask[slot] = True
-            for slot in plan.verify_rows:
-                # the window's straggler: the draft side feeds the final
-                # proposal WHILE the target verifies — intra-request overlap
-                # riding along in the same fused program
-                req = by_slot[slot]
-                d_tok[slot, 0] = int(req.pending[-1])
-                d_len[slot] = req.d_seq.length + req.pending_dl
-                d_mask[slot] = True
-
             slot_t0 = self._now()
-            if plan.verify_rows:
-                v_tok = np.zeros((b, wv), np.int32)
-                t_len = np.zeros((b,), np.int32)
-                v_mask = np.zeros((b,), bool)
-                for slot in plan.verify_rows:
+            with span("engine", "slot_dispatch", round=rnd, slot=i_slot):
+                for _, req in active:
+                    if req.pending_dl is None:
+                        req.begin_window(req.controller.draft_len())
+                plan = sch.plan_mixed_slot([
+                    sch.RowPhase(slot=s, window=r.pending_dl,
+                                 drafted=len(r.pending))
+                    for s, r in active
+                ])
+
+                # assemble the slot's per-row inputs (O(B) int32 host work)
+                d_tok = np.zeros((b, 1), np.int32)
+                d_len = np.zeros((b,), np.int32)
+                d_mask = np.zeros((b,), bool)
+                for slot in plan.draft_rows:
                     req = by_slot[slot]
-                    v_tok[slot, 0] = req.last_tok
-                    v_tok[slot, 1: 1 + req.pending_dl] = req.pending
-                    t_len[slot] = req.t_seq.length
-                    v_mask[slot] = True
-                v_tok_dev, d_tok_dev = jnp.asarray(v_tok), jnp.asarray(d_tok)
-                t_len_dev, d_len_dev = jnp.asarray(t_len), jnp.asarray(d_len)
-                vm_dev, dm_dev = jnp.asarray(v_mask), jnp.asarray(d_mask)
-                vs, ds = {}, {}
-                for k in self._kinds:
-                    (vs[k], ds[k], self._t_store[k],
-                     self._d_store[k]) = self._profiled(
-                        "fused_wdos", self._fused_step,
-                        self.target.params, self.draft.params,
-                        v_tok_dev, d_tok_dev,
-                        self._t_store[k], self._d_store[k],
-                        t_table, t_len_dev, d_table, d_len_dev,
-                        vm_dev, dm_dev,
-                    )
-                if kvq_dev is None:
-                    v_logits, d_logits = vs[self._kinds[0]], ds[self._kinds[0]]
+                    d_tok[slot, 0] = req.draft_tip
+                    d_len[slot] = req.d_seq.length + len(req.pending)
+                    d_mask[slot] = True
+                for slot in plan.verify_rows:
+                    # the window's straggler: the draft side feeds the final
+                    # proposal WHILE the target verifies — intra-request
+                    # overlap riding along in the same fused program
+                    req = by_slot[slot]
+                    d_tok[slot, 0] = int(req.pending[-1])
+                    d_len[slot] = req.d_seq.length + req.pending_dl
+                    d_mask[slot] = True
+
+                v_logits = None
+                if plan.verify_rows:
+                    v_tok = np.zeros((b, wv), np.int32)
+                    t_len = np.zeros((b,), np.int32)
+                    v_mask = np.zeros((b,), bool)
+                    for slot in plan.verify_rows:
+                        req = by_slot[slot]
+                        v_tok[slot, 0] = req.last_tok
+                        v_tok[slot, 1: 1 + req.pending_dl] = req.pending
+                        t_len[slot] = req.t_seq.length
+                        v_mask[slot] = True
+                    v_tok_dev, d_tok_dev = jnp.asarray(v_tok), jnp.asarray(d_tok)
+                    t_len_dev, d_len_dev = jnp.asarray(t_len), jnp.asarray(d_len)
+                    vm_dev, dm_dev = jnp.asarray(v_mask), jnp.asarray(d_mask)
+                    vs, ds = {}, {}
+                    for k in self._kinds:
+                        (vs[k], ds[k], self._t_store[k],
+                         self._d_store[k]) = self._fused_step(
+                            self.target.params, self.draft.params,
+                            v_tok_dev, d_tok_dev,
+                            self._t_store[k], self._d_store[k],
+                            t_table, t_len_dev, d_table, d_len_dev,
+                            vm_dev, dm_dev,
+                        )
+                    if kvq_dev is None:
+                        v_logits, d_logits = vs[self._kinds[0]], ds[self._kinds[0]]
+                    else:
+                        sel = kvq_dev[:, None, None]
+                        v_logits = jnp.where(sel, vs["int8"], vs["none"])
+                        d_logits = jnp.where(sel, ds["int8"], ds["none"])
                 else:
-                    sel = kvq_dev[:, None, None]
-                    v_logits = jnp.where(sel, vs["int8"], vs["none"])
-                    d_logits = jnp.where(sel, ds["int8"], ds["none"])
-                v_np = np.asarray(v_logits)
-            else:
-                d_tok_dev = jnp.asarray(d_tok)
-                d_len_dev, dm_dev = jnp.asarray(d_len), jnp.asarray(d_mask)
-                ds = {}
-                for k in self._kinds:
-                    ds[k], self._d_store[k] = self._profiled(
-                        "draft_slot", self._draft_slot_step,
-                        self.draft.params, d_tok_dev, self._d_store[k],
-                        d_table, d_len_dev, dm_dev,
-                    )
-                if kvq_dev is None:
-                    d_logits = ds[self._kinds[0]]
-                else:
-                    d_logits = jnp.where(
-                        kvq_dev[:, None, None], ds["int8"], ds["none"]
-                    )
-                v_np = None
+                    d_tok_dev = jnp.asarray(d_tok)
+                    d_len_dev, dm_dev = jnp.asarray(d_len), jnp.asarray(d_mask)
+                    ds = {}
+                    for k in self._kinds:
+                        ds[k], self._d_store[k] = self._draft_slot_step(
+                            self.draft.params, d_tok_dev, self._d_store[k],
+                            d_table, d_len_dev, dm_dev,
+                        )
+                    if kvq_dev is None:
+                        d_logits = ds[self._kinds[0]]
+                    else:
+                        d_logits = jnp.where(
+                            kvq_dev[:, None, None], ds["int8"], ds["none"]
+                        )
+            v_np = None
+            if v_logits is not None:
+                with span("engine", "verify_pull", round=rnd,
+                          bytes=v_logits.nbytes):
+                    v_np = np.asarray(v_logits)
             # only drafting rows consume draft logits; skip the (B, V)
             # device->host pull on all-verify slots
-            d_np = np.asarray(d_logits[:, -1, :]) if plan.draft_rows else None
+            d_np = None
+            if plan.draft_rows:
+                with span("engine", "draft_pull", round=rnd, j=i_slot,
+                          bytes=d_logits.nbytes // d_logits.shape[1]):
+                    d_np = np.asarray(d_logits[:, -1, :])
             slot_t1 = self._now()
             self._batcher.record_fused_slot(plan, slot_t1 - slot_t0, wv)
             if self.tracer.enabled:
@@ -1961,88 +1931,82 @@ class Engine:
             # draft rows: append the next proposal (same argmax/sampling
             # rule and the same (round, position) key indices as the
             # two-phase path, so tokens are bit-identical across modes)
-            for slot in plan.draft_rows:
-                req = by_slot[slot]
-                sp = req.sampling
-                row = d_np[slot]
-                if sp.greedy:
-                    nxt = int(np.argmax(row))
-                else:
-                    nxt = sample_token_host(
-                        req.draft_key(len(req.pending)), row,
-                        sp.temperature, sp.top_k, sp.top_p,
-                    )
-                    req.pending_q.append(row.copy())
-                req.pending.append(nxt)
+            if plan.draft_rows:
+                with span("engine", "draft_sample", round=rnd, j=i_slot,
+                          rows=len(plan.draft_rows)):
+                    for slot in plan.draft_rows:
+                        req = by_slot[slot]
+                        sp = req.sampling
+                        row = d_np[slot]
+                        if sp.greedy:
+                            nxt = int(np.argmax(row))
+                        else:
+                            nxt = sample_token_host(
+                                req.draft_key(len(req.pending)), row,
+                                sp.temperature, sp.top_k, sp.top_p,
+                            )
+                            req.pending_q.append(row.copy())
+                        req.pending.append(nxt)
 
             # verify rows: per-row accept/commit, then advance/rewind both
             # sequences back to committed-1 (the rewind-bounds invariant)
-            for slot in plan.verify_rows:
-                req = by_slot[slot]
-                dl = req.pending_dl
-                sp = req.sampling
-                mode = req.controller.mode
-                drafts = np.asarray(req.pending, np.int64)
-                if sp.greedy:
-                    new, n_acc = speculative_accept_greedy_host(
-                        drafts, v_np[slot], dl
-                    )
-                else:
-                    new, n_acc = speculative_sample_host(
-                        req.accept_key(), drafts, v_np[slot],
-                        np.stack(req.pending_q), dl,
-                        sp.temperature, sp.top_k, sp.top_p,
-                    )
-                req.commit(new)
-                req.record_round(mode, dl, n_acc, len(new))
-                req.rounds += 1
-                req.drafted += dl
-                req.accepted += n_acc
-                req.controller.observe(n_acc, dl)
-                self._m_drafted.inc(dl)
-                self._m_accepted.inc(n_acc)
-                self._m_round_accept.observe(n_acc / dl if dl else 0.0)
-                if self.tracer.enabled:
-                    self.tracer.instant(
-                        f"row{slot}", "commit", cat="commit",
-                        rid=req.rid, drafted=dl, accepted=n_acc,
-                    )
-                work.append((req, dl))
-                # target wrote wv positions, draft dl + 1 (incl. straggler);
-                # both keep exactly n_acc + 1
-                req.t_seq.advance(wv)
-                req.t_seq.rewind(wv - 1 - n_acc, release_pages=False)
-                req.d_seq.advance(dl + 1)
-                req.d_seq.rewind(dl - n_acc, release_pages=False)
-                req.clear_window()
-                if req.done:
-                    # retire MID-STEP: the freed slot's mask bits go False
-                    # for the remaining slots (its stale table rows are
-                    # never dereferenced), and its pages are free for the
-                    # next step's admissions
-                    self._t_tables.clear_row(slot)
-                    self._d_tables.clear_row(slot)
-                    if self.tracer.enabled:
-                        self.tracer.instant(
-                            f"row{slot}", "finish", cat="lifecycle",
-                            rid=req.rid,
-                            reason=req.finish_reason or "length",
+            if plan.verify_rows:
+                with span("engine", "accept", round=rnd,
+                          rows=len(plan.verify_rows)):
+                    for slot in plan.verify_rows:
+                        self._fused_verify_commit(
+                            slot, by_slot[slot], v_np[slot], wv, work
                         )
-                    self._batcher.retire(slot)
 
-        self._batcher.model_round(work)
-        self._batcher.step_count += 1
-        self._m_steps.inc()
-        t_end = self._now()
-        self._m_round_wall.observe(t_end - t_step)
-        self.tracer.rec(
-            "engine", f"step#{self._batcher.step_count}", t_step, t_end,
-            cat="step", par_mode="wdos", rows=len(touched),
-        )
-        self._refresh_gauges()
-        self._flight_round(fb, t_step, t_end, len(touched), "fused")
+        with span("engine", "accept", round=rnd, rows=len(touched)):
+            self._batcher.model_round(work)
+            t_end = self._end_round(t_step, len(touched), "wdos")
+            self._flight_round(fb, t_step, t_end, len(touched), "fused")
+        return self._emit(rnd, touched.values(), t_end)
 
-        return [self._output_for(req, t_end) for req in touched.values()]
+    def _fused_verify_commit(self, slot: int, req: Request, p_win, wv: int,
+                             work) -> None:
+        """Accept/commit one verify row of a fused slot; retire it
+        mid-step when it finishes."""
+        dl = req.pending_dl
+        sp = req.sampling
+        mode = req.controller.mode
+        drafts = np.asarray(req.pending, np.int64)
+        if sp.greedy:
+            new, n_acc = speculative_accept_greedy_host(drafts, p_win, dl)
+        else:
+            new, n_acc = speculative_sample_host(
+                req.accept_key(), drafts, p_win, np.stack(req.pending_q), dl,
+                sp.temperature, sp.top_k, sp.top_p,
+            )
+        req.commit(new)
+        req.record_round(mode, dl, n_acc, len(new))
+        req.rounds += 1
+        req.drafted += dl
+        req.accepted += n_acc
+        req.controller.observe(n_acc, dl)
+        self._m_drafted.inc(dl)
+        self._m_accepted.inc(n_acc)
+        self._m_round_accept.observe(n_acc / dl if dl else 0.0)
+        if self.tracer.enabled:
+            self.tracer.instant(
+                f"row{slot}", "commit", cat="commit",
+                rid=req.rid, drafted=dl, accepted=n_acc,
+            )
+        work.append((req, dl))
+        # target wrote wv positions, draft dl + 1 (incl. straggler); both
+        # keep exactly n_acc + 1
+        req.t_seq.advance(wv)
+        req.t_seq.rewind(wv - 1 - n_acc, release_pages=False)
+        req.d_seq.advance(dl + 1)
+        req.d_seq.rewind(dl - n_acc, release_pages=False)
+        req.clear_window()
+        if req.done:
+            # retire MID-STEP: the freed slot's mask bits go False for the
+            # remaining slots (its stale table rows are never
+            # dereferenced), and its pages are free for the next step's
+            # admissions
+            self._retire_done([(slot, req)])
 
     def _step_fused_tree(self) -> List[RequestOutput]:
         """One tree-speculation round as a horizon of fused dispatches
@@ -2053,11 +2017,14 @@ class Engine:
         fused program's draft logits.  Phase state (the partial tree)
         carries across engine steps exactly like the chain window, and
         ``rounds`` increments only at commit, so a request's trees and
-        tokens are identical to the two-phase tree scheduler's."""
+        tokens are identical to the two-phase tree scheduler's.  Profiler
+        leaf spans as in ``_step_fused``, plus ``engine.compaction``."""
         cfg = self.cfg
+        span = self.tracer.span
+        rnd = self._batcher.step_count + 1
         t_step = self._now()
         fb = self._flight_base()
-        self._admit()
+        self._admit(rnd)
         if not self._batcher.active():
             self._batcher.step_count += 1
             self._m_steps.inc()
@@ -2075,99 +2042,108 @@ class Engine:
         work: List[Tuple[Request, int]] = []
 
         t0 = self._now()
-        d_table = self._d_tables.table_dev()
-        t_table = self._t_tables.table_dev()
+        with span("engine", "table_upload", round=rnd):
+            d_table = self._d_tables.table_dev()
+            t_table = self._t_tables.table_dev()
         self._m_table_upload.inc(self._now() - t0)
 
-        for _ in range(horizon):
+        for i_slot in range(horizon):
             active = self._batcher.active()
             if not active:
                 break
             by_slot = dict(active)
-            for _, req in active:
-                if req.tree_dl is None:
-                    req.begin_tree(
-                        min(req.controller.draft_len(), cfg.tree_budget)
-                    )
-            plan = sch.plan_mixed_slot([
-                sch.RowPhase(slot=s, window=r.tree_dl, drafted=r.tree_depth)
-                for s, r in active
-            ])
-
-            # every active row re-feeds its current tree on the draft side
-            # at its BASE length (verify rows feed the complete tree — the
-            # straggler landing the leaf KV inside the verify slot)
-            d_tok = np.zeros((b, w), np.int32)
-            d_pos = np.zeros((b, w), np.int32)
-            d_tm = np.zeros((b, w, w), np.float32)
-            d_tm[:, diag, diag] = 1.0
-            d_len = np.zeros((b,), np.int32)
-            d_mask = np.zeros((b,), bool)
-            for slot, req in active:
-                d_tok[slot], d_pos[slot], d_tm[slot] = _tree_window_rows(req, w)
-                d_len[slot] = req.d_seq.length
-                d_mask[slot] = True
-
             slot_t0 = self._now()
-            if plan.verify_rows:
-                v_tok = np.zeros((b, w), np.int32)
-                t_pos = np.zeros((b, w), np.int32)
-                t_tm = np.zeros((b, w, w), np.float32)
-                t_tm[:, diag, diag] = 1.0
-                t_len = np.zeros((b,), np.int32)
-                v_mask = np.zeros((b,), bool)
-                for slot in plan.verify_rows:
-                    req = by_slot[slot]
-                    v_tok[slot], t_pos[slot], t_tm[slot] = _tree_window_rows(
-                        req, w
+            with span("engine", "slot_dispatch", round=rnd, slot=i_slot):
+                for _, req in active:
+                    if req.tree_dl is None:
+                        req.begin_tree(
+                            min(req.controller.draft_len(), cfg.tree_budget)
+                        )
+                plan = sch.plan_mixed_slot([
+                    sch.RowPhase(slot=s, window=r.tree_dl, drafted=r.tree_depth)
+                    for s, r in active
+                ])
+
+                # every active row re-feeds its current tree on the draft
+                # side at its BASE length (verify rows feed the complete
+                # tree — the straggler landing the leaf KV inside the
+                # verify slot)
+                d_tok = np.zeros((b, w), np.int32)
+                d_pos = np.zeros((b, w), np.int32)
+                d_tm = np.zeros((b, w, w), np.float32)
+                d_tm[:, diag, diag] = 1.0
+                d_len = np.zeros((b,), np.int32)
+                d_mask = np.zeros((b,), bool)
+                for slot, req in active:
+                    d_tok[slot], d_pos[slot], d_tm[slot] = _tree_window_rows(req, w)
+                    d_len[slot] = req.d_seq.length
+                    d_mask[slot] = True
+
+                v_logits = None
+                if plan.verify_rows:
+                    v_tok = np.zeros((b, w), np.int32)
+                    t_pos = np.zeros((b, w), np.int32)
+                    t_tm = np.zeros((b, w, w), np.float32)
+                    t_tm[:, diag, diag] = 1.0
+                    t_len = np.zeros((b,), np.int32)
+                    v_mask = np.zeros((b,), bool)
+                    for slot in plan.verify_rows:
+                        req = by_slot[slot]
+                        v_tok[slot], t_pos[slot], t_tm[slot] = _tree_window_rows(
+                            req, w
+                        )
+                        t_len[slot] = req.t_seq.length
+                        v_mask[slot] = True
+                    heads = (jnp.asarray(v_tok), jnp.asarray(d_tok))
+                    tails = (
+                        t_table, jnp.asarray(t_len), d_table, jnp.asarray(d_len),
+                        jnp.asarray(v_mask), jnp.asarray(d_mask),
+                        jnp.asarray(t_pos), jnp.asarray(t_tm),
+                        jnp.asarray(d_pos), jnp.asarray(d_tm),
                     )
-                    t_len[slot] = req.t_seq.length
-                    v_mask[slot] = True
-                heads = (jnp.asarray(v_tok), jnp.asarray(d_tok))
-                tails = (
-                    t_table, jnp.asarray(t_len), d_table, jnp.asarray(d_len),
-                    jnp.asarray(v_mask), jnp.asarray(d_mask),
-                    jnp.asarray(t_pos), jnp.asarray(t_tm),
-                    jnp.asarray(d_pos), jnp.asarray(d_tm),
-                )
-                vs, ds = {}, {}
-                for k in self._kinds:
-                    (vs[k], ds[k], self._t_store[k],
-                     self._d_store[k]) = self._profiled(
-                        "fused_tree", self._fused_tree_step,
-                        self.target.params, self.draft.params, *heads,
-                        self._t_store[k], self._d_store[k], *tails,
-                    )
-                if kvq_dev is None:
-                    v_logits, d_logits = vs[self._kinds[0]], ds[self._kinds[0]]
+                    vs, ds = {}, {}
+                    for k in self._kinds:
+                        (vs[k], ds[k], self._t_store[k],
+                         self._d_store[k]) = self._fused_tree_step(
+                            self.target.params, self.draft.params, *heads,
+                            self._t_store[k], self._d_store[k], *tails,
+                        )
+                    if kvq_dev is None:
+                        v_logits, d_logits = vs[self._kinds[0]], ds[self._kinds[0]]
+                    else:
+                        sel = kvq_dev[:, None, None]
+                        v_logits = jnp.where(sel, vs["int8"], vs["none"])
+                        d_logits = jnp.where(sel, ds["int8"], ds["none"])
                 else:
-                    sel = kvq_dev[:, None, None]
-                    v_logits = jnp.where(sel, vs["int8"], vs["none"])
-                    d_logits = jnp.where(sel, ds["int8"], ds["none"])
-                v_np = np.asarray(v_logits)
-            else:
-                d_tok_dev = jnp.asarray(d_tok)
-                tails = (
-                    d_table, jnp.asarray(d_len), jnp.asarray(d_mask),
-                    jnp.asarray(d_pos), jnp.asarray(d_tm),
-                )
-                ds = {}
-                for k in self._kinds:
-                    ds[k], self._d_store[k] = self._profiled(
-                        "tree_draft_slot", self._draft_tree_slot_step,
-                        self.draft.params, d_tok_dev, self._d_store[k],
-                        *tails,
+                    d_tok_dev = jnp.asarray(d_tok)
+                    tails = (
+                        d_table, jnp.asarray(d_len), jnp.asarray(d_mask),
+                        jnp.asarray(d_pos), jnp.asarray(d_tm),
                     )
-                if kvq_dev is None:
-                    d_logits = ds[self._kinds[0]]
-                else:
-                    d_logits = jnp.where(
-                        kvq_dev[:, None, None], ds["int8"], ds["none"]
-                    )
-                v_np = None
+                    ds = {}
+                    for k in self._kinds:
+                        ds[k], self._d_store[k] = self._draft_tree_slot_step(
+                            self.draft.params, d_tok_dev, self._d_store[k],
+                            *tails,
+                        )
+                    if kvq_dev is None:
+                        d_logits = ds[self._kinds[0]]
+                    else:
+                        d_logits = jnp.where(
+                            kvq_dev[:, None, None], ds["int8"], ds["none"]
+                        )
+            v_np = None
+            if v_logits is not None:
+                with span("engine", "verify_pull", round=rnd,
+                          bytes=v_logits.nbytes):
+                    v_np = np.asarray(v_logits)
             # tree growth consumes the WHOLE window's logits (one row per
             # frontier node), not just the last column
-            d_np = np.asarray(d_logits) if plan.draft_rows else None
+            d_np = None
+            if plan.draft_rows:
+                with span("engine", "draft_pull", round=rnd, j=i_slot,
+                          bytes=d_logits.nbytes):
+                    d_np = np.asarray(d_logits)
             slot_t1 = self._now()
             self._batcher.record_fused_slot(
                 plan, slot_t1 - slot_t0, w, draft_width=w
@@ -2197,46 +2173,35 @@ class Engine:
 
             # draft rows: one more tree level (same fan-out rule and the
             # same (round, draw-index) key stream as the two-phase path)
-            for slot in plan.draft_rows:
-                _sample_tree_level(by_slot[slot], cfg, d_np[slot])
+            if plan.draft_rows:
+                with span("engine", "draft_sample", round=rnd, j=i_slot,
+                          rows=len(plan.draft_rows)):
+                    for slot in plan.draft_rows:
+                        _sample_tree_level(by_slot[slot], cfg, d_np[slot])
 
             # verify rows: accept/commit + queue compaction, retire done
             moves_t = {k: ([], []) for k in self._kinds}
             moves_d = {k: ([], []) for k in self._kinds}
-            for slot in plan.verify_rows:
-                req = by_slot[slot]
-                dl = req.tree_dl
-                self._tree_verify_commit(
-                    slot, req, v_np[slot], req.controller.mode, dl,
-                    moves_t, moves_d, work,
-                )
-                if req.done:
-                    self._t_tables.clear_row(slot)
-                    self._d_tables.clear_row(slot)
-                    if self.tracer.enabled:
-                        self.tracer.instant(
-                            f"row{slot}", "finish", cat="lifecycle",
-                            rid=req.rid,
-                            reason=req.finish_reason or "length",
+            if plan.verify_rows:
+                with span("engine", "accept", round=rnd,
+                          rows=len(plan.verify_rows)):
+                    for slot in plan.verify_rows:
+                        req = by_slot[slot]
+                        self._tree_verify_commit(
+                            slot, req, v_np[slot], req.controller.mode,
+                            req.tree_dl, moves_t, moves_d, work,
                         )
-                    self._batcher.retire(slot)
+                        if req.done:
+                            self._retire_done([(slot, req)])
             # flush compaction BEFORE the next fused dispatch: a committed
             # row's next window overlaps its old BFS slots
             self._compact_pools(moves_t, moves_d)
 
-        self._batcher.model_round(work)
-        self._batcher.step_count += 1
-        self._m_steps.inc()
-        t_end = self._now()
-        self._m_round_wall.observe(t_end - t_step)
-        self.tracer.rec(
-            "engine", f"step#{self._batcher.step_count}", t_step, t_end,
-            cat="step", par_mode="wdos", rows=len(touched),
-        )
-        self._refresh_gauges()
-        self._flight_round(fb, t_step, t_end, len(touched), "fused_tree")
-
-        return [self._output_for(req, t_end) for req in touched.values()]
+        with span("engine", "accept", round=rnd, rows=len(touched)):
+            self._batcher.model_round(work)
+            t_end = self._end_round(t_step, len(touched), "wdos")
+            self._flight_round(fb, t_step, t_end, len(touched), "fused_tree")
+        return self._emit(rnd, touched.values(), t_end)
 
     # -- drain / reporting ---------------------------------------------------
 

@@ -304,7 +304,8 @@ def apply_quantized_lm(
         u_ = _qlinear(z2, p["w_up"], use_pallas)
         hid = jax.nn.silu(g_.astype(jnp.float32)).astype(xc.dtype) * u_
         if rotate:  # the LRU's online stage (Pallas FWHT kernel)
-            hid = ops.lru_rotate(hid, r2, use_pallas=use_pallas)
+            with jax.named_scope("lru_rotation"):
+                hid = ops.lru_rotate(hid, r2, use_pallas=use_pallas)
         xc = xc + _qlinear(hid, p["w_down"], use_pallas)
         return xc, ys
 
@@ -317,7 +318,8 @@ def apply_quantized_lm(
     x = _norm_only(x)
     if last_logit_only:
         x = x[:, -1:, :]
-    logits = _qlinear(x, qparams["head"], use_pallas)
+    with jax.named_scope("lm_head"):
+        logits = _qlinear(x, qparams["head"], use_pallas)
     return logits, new_cache
 
 
@@ -432,7 +434,8 @@ def apply_bvq_lm(
     x = L.rmsnorm(qparams["final_norm"], x)
     if last_logit_only:
         x = x[:, -1:, :]
-    logits = x @ qparams["head"].astype(x.dtype)
+    with jax.named_scope("lm_head"):
+        logits = x @ qparams["head"].astype(x.dtype)
     return logits, new_cache
 
 # ---------------------------------------------------------------------------
@@ -723,5 +726,6 @@ def apply_quantized_moe_lm(
     x = L.rmsnorm(qparams["final_norm"], x)
     if last_logit_only:
         x = x[:, -1:, :]
-    logits = _qlinear(x, qparams["head"], use_pallas)
+    with jax.named_scope("lm_head"):
+        logits = _qlinear(x, qparams["head"], use_pallas)
     return logits, new_cache

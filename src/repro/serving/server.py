@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 from repro.serving.api import SamplingParams, default_detokenize
@@ -158,6 +159,11 @@ class CompletionServer:
         self._m_429 = m.counter(
             "http_429_total",
             "Completions rejected with 429 (backpressure fail-fast)",
+        )
+        self._m_stream_lag = m.histogram(
+            "stream_lag_seconds",
+            "End of the engine round that produced an output -> return of "
+            "that output's SSE write (one observation per written output)",
         )
 
     # -- lifecycle -----------------------------------------------------------
@@ -367,35 +373,13 @@ class CompletionServer:
                     writer.write(self._SSE_HEAD)
                     head_sent = True
                 rid = out.request_id
-                finish_reason = out.outputs[0].finish_reason
-                for i, tok in enumerate(out.new_token_ids):
-                    is_final = (
-                        out.finished and i == len(out.new_token_ids) - 1
+                with tracer.span("server", "sse_write", rid=rid,
+                                 tokens=len(out.new_token_ids)):
+                    index = await self._write_chunk(writer, out, index)
+                if out.round_end is not None:
+                    self._m_stream_lag.observe(
+                        time.perf_counter() - out.round_end
                     )
-                    chunk = {
-                        "id": out.request_id,
-                        "object": "completion.chunk",
-                        "index": index,
-                        "token": int(tok),
-                        "text": self._detokenize(int(tok)),
-                        "finish_reason": finish_reason if is_final else None,
-                    }
-                    writer.write(
-                        b"data: " + json.dumps(chunk).encode() + b"\n\n"
-                    )
-                    index += 1
-                if out.finished and not out.new_token_ids:
-                    # stop-truncation can finish a request with an empty
-                    # delta; the client still needs the finish_reason
-                    writer.write(
-                        b"data: " + json.dumps({
-                            "id": out.request_id,
-                            "object": "completion.chunk",
-                            "index": index, "token": None, "text": "",
-                            "finish_reason": finish_reason,
-                        }).encode() + b"\n\n"
-                    )
-                await writer.drain()
             if not head_sent:
                 writer.write(self._SSE_HEAD)
             writer.write(b"data: [DONE]\n\n")
@@ -409,6 +393,36 @@ class CompletionServer:
             watcher.cancel()
             await asyncio.gather(watcher, return_exceptions=True)
             await gen.aclose()
+
+    async def _write_chunk(self, writer, out, index: int) -> int:
+        """Write one output's SSE events (one per token) and drain; returns
+        the running token index after them."""
+        finish_reason = out.outputs[0].finish_reason
+        for i, tok in enumerate(out.new_token_ids):
+            is_final = out.finished and i == len(out.new_token_ids) - 1
+            chunk = {
+                "id": out.request_id,
+                "object": "completion.chunk",
+                "index": index,
+                "token": int(tok),
+                "text": self._detokenize(int(tok)),
+                "finish_reason": finish_reason if is_final else None,
+            }
+            writer.write(b"data: " + json.dumps(chunk).encode() + b"\n\n")
+            index += 1
+        if out.finished and not out.new_token_ids:
+            # stop-truncation can finish a request with an empty delta;
+            # the client still needs the finish_reason
+            writer.write(
+                b"data: " + json.dumps({
+                    "id": out.request_id,
+                    "object": "completion.chunk",
+                    "index": index, "token": None, "text": "",
+                    "finish_reason": finish_reason,
+                }).encode() + b"\n\n"
+            )
+        await writer.drain()
+        return index
 
     async def _respond_whole(self, reader, writer, agen, prompt) -> None:
         watcher = asyncio.ensure_future(self._watch_disconnect(reader))

@@ -1,32 +1,45 @@
-"""Span tracer: per-request timelines exportable as Chrome-trace JSON
-(loadable in Perfetto / ``chrome://tracing``) and as structured JSONL.
+"""Span tracer: engine and server spans on the profiler's clock, plus
+per-request timelines exportable as Chrome-trace JSON (loadable in
+Perfetto / ``chrome://tracing``) and as structured JSONL.
 
-The point of tracing here is to make the WDOS schedule *visible*: with
-``par_mode="wdos"`` different requests draft and verify out of phase
-inside shared fused dispatches, and the only honest way to check (or
-debug) that staggering is a timeline with one track per batch row.  The
-engine emits spans at dispatch boundaries only — the tracer never calls
-``block_until_ready`` and never touches device values, so it cannot add
-host syncs to the decode loop or perturb bit-identity (the parity suites
-run unchanged with tracing enabled; tests/test_observability.py).
+Two sinks, one API.  ``span(track, name, **args)`` is the single way a
+hot-path site records a span:
 
-Span hierarchy the engine emits (docs/OBSERVABILITY.md draws it):
+* it always enters a ``jax.profiler.TraceAnnotation`` named
+  ``<track>.<name>`` (``engine.draft_pull``, ``server.sse_write``) with
+  ``args`` as the event's metadata, so a ``jax.profiler`` trace shows the
+  host work between device programs on the same clock as the programs
+  themselves.  With no profiler session active this is one TraceMe
+  construction (about 2 us of host time) and the metadata is never encoded;
+* on an enabled ``Tracer`` it also records the span in memory for the
+  Chrome/JSONL export below.
+
+``rec(t0, t1)`` stamps an in-memory span after the fact; it is kept only
+for the round and phase spans the in-memory timeline draws (``step#k``,
+``draft_phase``, ``fused_slot``, the per-row lifecycle), which the
+profiler does not need: the engine's leaf spans already cover a round.
+
+In-memory span hierarchy (docs/OBSERVABILITY.md draws it):
 
     engine track:   step#k [par_mode] > draft_phase / verify_phase (off)
                                       > fused_slot (wdos)
+                    leaf spans: admit, prefill, table_upload, draft_*,
+                    verify_*, accept, emit, ... (also on the profiler)
     row<i> track:   admit > prefill > {draft | verify}* > commit > finish
     http track:     request / disconnect / completion instants (server)
 
 Every span/instant carries the request id in ``args`` where one applies,
-so a request's life is greppable across tracks — and the same events
-stream to a JSONL file (one JSON object per line) when the tracer is
-built with ``jsonl_path=...``, which is the machine-tailable log a
-serving deployment wants.
+and the same events stream to a JSONL file (one JSON object per line)
+when the tracer is built with ``jsonl_path=...``.
 
-Off by default: the engine holds ``NULL_TRACER`` unless one is passed
-(``Engine(..., trace=Tracer())``), and every ``NULL_TRACER`` method is a
-constant-time no-op — the disabled fast path is one attribute check per
-instrumentation site.
+The tracer never calls ``block_until_ready`` and never touches device
+values, so it cannot add host syncs to the decode loop or perturb
+bit-identity (tokens are identical with tracing and the profiler on;
+tests/test_observability.py, tests/test_profiler_spans.py).
+
+The engine holds ``NULL_TRACER`` unless one is passed
+(``Engine(..., trace=Tracer())``): its ``span`` is the bare
+``TraceAnnotation`` and every other method a constant-time no-op.
 
 Export: ``to_chrome_trace()`` returns the Chrome Trace Event JSON dict
 (``{"traceEvents": [...]}``, complete/``"X"`` events with microsecond
@@ -38,8 +51,9 @@ from __future__ import annotations
 import json
 import threading
 import time
-from contextlib import contextmanager
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "validate_chrome_trace"]
 
@@ -89,13 +103,10 @@ class Tracer:
             "ts": self.now(), "args": args,
         })
 
-    @contextmanager
     def span(self, track: str, name: str, cat: str = "", **args):
-        t0 = self.now()
-        try:
-            yield
-        finally:
-            self.rec(track, name, t0, self.now(), cat, **args)
+        """Context manager: a profiler span ``<track>.<name>`` carrying
+        ``args``, also recorded here as an in-memory span on ``track``."""
+        return _Span(self, track, name, cat, args)
 
     # -- export ---------------------------------------------------------------
 
@@ -141,6 +152,28 @@ class Tracer:
                 self._jsonl = None
 
 
+class _Span:
+    """``Tracer.span``: the profiler annotation plus the in-memory record."""
+
+    __slots__ = ("_tracer", "_track", "_name", "_cat", "_args", "_ann", "_t0")
+
+    def __init__(self, tracer: Tracer, track: str, name: str, cat: str,
+                 args: dict):
+        self._tracer, self._track, self._name = tracer, track, name
+        self._cat, self._args = cat, args
+        self._ann = TraceAnnotation(f"{track}.{name}", **args)
+
+    def __enter__(self):
+        self._t0 = self._tracer.now()
+        self._ann.__enter__()
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self._tracer.rec(self._track, self._name, self._t0, self._tracer.now(),
+                         self._cat, **self._args)
+        return False
+
+
 class NullTracer(Tracer):
     """The disabled fast path: every method is a constant-time no-op.
     Shared as ``NULL_TRACER`` — the engine's default when no tracer is
@@ -160,9 +193,8 @@ class NullTracer(Tracer):
     def instant(self, *a, **kw) -> None:
         pass
 
-    @contextmanager
-    def span(self, *a, **kw):
-        yield
+    def span(self, track: str, name: str, cat: str = "", **args):
+        return TraceAnnotation(f"{track}.{name}", **args)
 
     def events(self) -> List[dict]:
         return []
@@ -185,17 +217,10 @@ def validate_chrome_trace(obj: dict) -> List[str]:
     (empty = valid).  Used by the trace-export tests and the CI smoke so a
     regression can never silently produce a file Perfetto rejects.
 
-    Beyond the per-event field checks, two track-level rules:
-
-    * every non-metadata event's tid must be introduced by a
-      ``thread_name`` metadata event (Perfetto renders unnamed tids as
-      anonymous tracks — always a tracer bug here, since ``Tracer``
-      emits the M record at first use of a track);
-    * spans on a ``device*`` track must not overlap: the device executes
-      one bracketed dispatch at a time (``block_until_ready`` between
-      programs), so overlap means broken attribution.  Host tracks nest
-      spans (step ⊃ phase) and are exempt.  A 1 µs slack absorbs the
-      microsecond rounding + min-duration clamp of ``to_chrome_trace``.
+    Beyond the per-event field checks, every non-metadata event's tid must
+    be introduced by a ``thread_name`` metadata event (Perfetto renders
+    unnamed tids as anonymous tracks — always a tracer bug here, since
+    ``Tracer`` emits the M record at first use of a track).
     """
     problems: List[str] = []
     if not isinstance(obj, dict) or "traceEvents" not in obj:
@@ -204,7 +229,6 @@ def validate_chrome_trace(obj: dict) -> List[str]:
     if not isinstance(events, list):
         return ["'traceEvents' must be a list"]
     track_by_tid: Dict[int, str] = {}
-    device_spans: Dict[int, List[Tuple[float, float, int]]] = {}
     for i, ev in enumerate(events):
         if not isinstance(ev, dict):
             problems.append(f"event {i}: not a dict")
@@ -231,18 +255,6 @@ def validate_chrome_trace(obj: dict) -> List[str]:
         if not isinstance(ts, (int, float)) or ts < 0:
             problems.append(f"event {i}: bad ts {ts!r}")
             continue
-        dur = ev.get("dur")
-        if ph == "X" and not isinstance(dur, (int, float)):
+        if ph == "X" and not isinstance(ev.get("dur"), (int, float)):
             problems.append(f"event {i}: complete event missing dur")
-        elif ph == "X" and str(track_by_tid.get(tid, "")).startswith("device"):
-            device_spans.setdefault(tid, []).append((ts, ts + dur, i))
-    for tid, spans in device_spans.items():
-        spans.sort()
-        for (_, prev_end, prev_i), (ts, _, i) in zip(spans, spans[1:]):
-            if prev_end > ts + 1:  # 1 us slack for rounding/min-dur clamp
-                problems.append(
-                    f"device track tid {tid}: span at event {prev_i} "
-                    f"overlaps span at event {i} "
-                    f"(end {prev_end} > start {ts})"
-                )
     return problems

@@ -172,5 +172,3 @@ def test_negative_ring_capacity_rejected():
     from repro.serving import EngineConfig
     with pytest.raises(ValueError):
         EngineConfig(flight_ring=-1)
-    with pytest.raises(ValueError):
-        EngineConfig(profile_every_n=-2)

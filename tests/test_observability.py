@@ -191,21 +191,6 @@ def test_validator_requires_thread_name_metadata():
     assert validate_chrome_trace(_one_track_trace("engine", [(0, 5)])) == []
 
 
-def test_validator_device_track_overlap_rule():
-    overlapping = [(0, 10), (5, 10)]
-    # device tracks serialize dispatches -> overlap is broken attribution
-    probs = validate_chrome_trace(_one_track_trace("device", overlapping))
-    assert any("overlap" in p for p in probs)
-    # host tracks nest spans (step contains phase) and are exempt
-    assert validate_chrome_trace(
-        _one_track_trace("engine", overlapping)
-    ) == []
-    # back-to-back device spans are fine (1 us slack covers rounding)
-    assert validate_chrome_trace(
-        _one_track_trace("device", [(0, 10), (10, 4), (15, 2)])
-    ) == []
-
-
 # ---------------------------------------------------------------------------
 # Engine end-to-end: metrics populate, tracing never perturbs tokens
 # ---------------------------------------------------------------------------
@@ -359,114 +344,6 @@ def test_metrics_scrape_format_and_core_series(pair):
     # the scrape counted itself
     assert 'serving_http_requests_total{route="/metrics",status="200"} 1' \
         in text
-
-
-def test_profiled_wdos_bit_identity_and_device_track(pair):
-    """Sampled device-time attribution never perturbs tokens: a wdos run
-    with ``profile_every_n=2`` (and the flight recorder on, its default)
-    matches an uninstrumented two-phase run token-for-token, while the
-    trace gains a non-overlapping device track of per-program spans and
-    ``profile_summary()`` reports the fused program."""
-    target, draft = pair
-    prompts = _prompts(3, seed=11)
-    sp = SamplingParams(max_tokens=12)
-
-    ref_eng = Engine(target, draft, EngineConfig(max_batch=2, page_size=8))
-    ref, _ = ref_eng.run(prompts, sp)
-
-    tracer = Tracer()
-    eng = Engine(
-        target, draft,
-        EngineConfig(max_batch=2, page_size=8, par_mode="wdos",
-                     profile_every_n=2),
-        trace=tracer,
-    )
-    outs, _ = eng.run(prompts, sp)
-    for a, b in zip(ref, outs):
-        assert [int(t) for t in a] == [int(t) for t in b]
-
-    trace = tracer.to_chrome_trace()
-    assert validate_chrome_trace(trace) == []
-    meta = {e["tid"]: e["args"]["name"] for e in trace["traceEvents"]
-            if e["ph"] == "M"}
-    dev_tids = {tid for tid, name in meta.items() if name == "device"}
-    assert dev_tids, f"no device track in {sorted(meta.values())}"
-    dev_names = {e["name"] for e in trace["traceEvents"]
-                 if e["ph"] == "X" and e["tid"] in dev_tids}
-    assert "fused_wdos" in dev_names, dev_names
-
-    summary = eng.profile_summary()
-    assert "fused_wdos" in summary
-    fw = summary["fused_wdos"]
-    assert fw["calls"] >= 1 and fw["wall_s"] > 0
-    # every profiled program with a cost stamp reports finite flops/bytes
-    for prog, entry in summary.items():
-        assert entry["calls"] >= 1 and entry["wall_s"] > 0, prog
-
-    # the flight recorder rode along: every round recorded.  The tiny
-    # test models genuinely draft badly, so acceptance_collapse MAY fire
-    # (that's the detector working); the health anomalies must not.
-    snap = eng.flight_snapshot()
-    assert snap["enabled"] and snap["rounds_recorded"] > 0
-    assert snap["anomalies"]["pool_exhausted"] == 0
-    assert snap["anomalies"]["admission_stall"] == 0
-    json.dumps(snap)  # postmortem/ring payloads must stay JSON-serializable
-
-
-def test_profiled_tree_bit_identity_spans_and_metrics(pair):
-    """Tree speculation under profiling: tokens bit-identical to the
-    unprofiled tree engine; engine track carries tree_draft/tree_verify
-    spans; the tree metric families count real work; the device track
-    shows the tree dispatch programs."""
-    target, draft = pair
-    prompts = _prompts(4, seed=3)
-    sps = [SamplingParams(temperature=0.8, seed=100 + i, max_tokens=12)
-           for i in range(4)]
-    tree_cfg = dict(max_batch=4, page_size=8, draft_len=3,
-                    spec_mode="tree", tree_budget=14, spec_branches=2,
-                    branch_threshold=1.0)
-
-    def drain(eng):
-        outs = {}
-        rids = [eng.add_request(p, sp) for p, sp in zip(prompts, sps)]
-        while eng.has_unfinished():
-            for out in eng.step():
-                outs.setdefault(out.request_id, []).extend(
-                    int(t) for t in out.new_token_ids
-                )
-        return [outs[r] for r in rids]
-
-    ref = drain(Engine(target, draft, EngineConfig(**tree_cfg)))
-
-    tracer = Tracer()
-    eng = Engine(target, draft,
-                 EngineConfig(profile_every_n=1, **tree_cfg), trace=tracer)
-    got = drain(eng)
-    assert ref == got
-
-    m = eng.metrics
-    assert m.value("tree_nodes_total") > 0
-    assert m.value("tree_branches_total") > 0
-    assert m.get("tree_accept_depth").value() > 0  # one obs per verify
-    # compaction count matches the spans the engine recorded
-    n_compact = m.value("tree_compactions_total")
-
-    trace = tracer.to_chrome_trace()
-    assert validate_chrome_trace(trace) == []
-    meta = {e["tid"]: e["args"]["name"] for e in trace["traceEvents"]
-            if e["ph"] == "M"}
-    by_track = {}
-    for e in trace["traceEvents"]:
-        if e["ph"] == "X":
-            by_track.setdefault(meta[e["tid"]], set()).add(e["name"])
-    assert {"tree_draft", "tree_verify"} <= by_track["engine"], by_track
-    if n_compact:
-        assert "compaction" in by_track["engine"]
-        assert "compaction" in by_track["device"]
-    assert {"tree_draft", "tree_verify"} <= by_track["device"], by_track
-
-    summary = eng.profile_summary()
-    assert {"tree_draft", "tree_verify"} <= set(summary)
 
 
 def test_tree_and_anomaly_families_registered_when_idle(pair):
