@@ -8,17 +8,24 @@ first device is a TPU (it never sets ``JAX_PLATFORMS``), then:
 
 1. builds llama2-7b in W4A8 with the LRU rotation (one layer at a time) and
    llama-68m in BVQ, random weights drawn from ``--seed``;
-2. runs ``Engine.run`` on four greedy prompts (two prompt lengths, so two
+2. checks that every linear of the BVQ draft resolved to the Pallas kernel
+   (``ops.resolve_bvq_impl``), that ``ops.bvq_linear`` returns the kernel's
+   output, and that the kernel equals a float64 decode of the weight within
+   ``BVQ_TOL`` of the output's largest entry,
+   for layer 0 of llama-68m's own linears and for random INT4 codebooks at
+   the Qwen3-0.6B draft's shapes, at a draft step (16 bf16 rows) and at 40
+   f32 rows;
+3. runs ``Engine.run`` on four greedy prompts (two prompt lengths, so two
    prefill compiles) with the default ``EngineConfig`` (chain drafting,
    ``par_mode="off"``), and checks that the paged attention resolved to
    the Pallas kernel and that the compiled verify step calls it;
-3. decodes each prompt with ``launch/serve.greedy_reference_margins`` over
+4. decodes each prompt with ``launch/serve.greedy_reference_margins`` over
    a dense cache: the served tokens must equal the greedy reference up to
    their first divergence, which is tolerated only where the reference's
    top-1 minus top-2 logit margin is below ``LOGIT_TOL``, and, fed the
    served tokens, the reference must rank each within ``LOGIT_TOL`` of its
    top logit;
-4. starts ``CompletionServer`` over ``AsyncEngine(Engine(...))`` on a free
+5. starts ``CompletionServer`` over ``AsyncEngine(Engine(...))`` on a free
    localhost port and sends the four prompts concurrently through
    ``serving/http_client.py``, two streamed and two not; every response
    must be HTTP 200, finish with "length" after ``max_tokens`` tokens, and
@@ -49,6 +56,15 @@ S_MAX = 256  # per-request cache bound: prompt + MAX_TOKENS + draft window
 # A wrong token drawn at random sits about 4 logits below the top (the max
 # of 32000 unit-spread logits), so 1.0 separates rounding from faults.
 LOGIT_TOL = 1.0
+# The BVQ kernel's products are exact (INT4 codebook entries times bf16
+# parts of x) and its sums f32, so against a float64 decode its error is
+# f32 rounding, about 1e-7 of the output's largest entry; a wrong decode
+# or a misplaced tile reads order 1.
+BVQ_TOL = 1e-5
+# (K, N) of the Qwen3-0.6B draft's linears: wq, wk/wv, wo, w_gate/w_up,
+# w_down, served in 128-column blocks of 256-entry, 8-wide codebooks
+QWEN3_DRAFT_LINEARS = ((1024, 2048), (1024, 1024), (2048, 1024),
+                       (1024, 3072), (3072, 1024))
 
 
 class SmokeFailure(RuntimeError):
@@ -88,6 +104,76 @@ def _verify_calls_kernel(engine) -> bool:
         jax.ShapeDtypeStruct(tables.lengths.shape, jnp.int32),
     )
     return "tpu_custom_call" in lowered.as_text()
+
+
+def _bvq_dense64(bw):
+    """The (K, N) weight of a BVQ weight, decoded on the host in float64."""
+    import numpy as np
+
+    nb, rows, bc = bw.indices.shape
+    cb = (np.asarray(bw.codebooks, np.float64)
+          * np.asarray(bw.scales, np.float64))
+    w = cb[np.arange(nb)[:, None, None], np.asarray(bw.indices)]
+    return w.transpose(1, 3, 0, 2).reshape(bw.shape)
+
+
+def check_bvq_kernel(draft, seed: int, tol: float):
+    """The draft's linears resolved to the Pallas kernel, ``ops.bvq_linear``
+    returns the kernel's output, and the kernel (f32 out) equals a float64
+    decode at the served shapes.  Returns ``[(linear, rows, dtype, error /
+    largest output)]``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.bvq import BVQWeight
+    from repro.kernels import ops
+    from repro.kernels.bvq_matmul import bvq_matmul_pallas
+    from repro.serving import quantized_lm as qlm
+
+    counts = qlm.bvq_impl_counts(draft.params, draft.use_pallas)
+    _check(counts["reference"] == 0 and counts["pallas"] > 0,
+           f"the BVQ draft's linears resolved to {counts}")
+    is_bw = lambda t: isinstance(t, BVQWeight)
+    weights = []
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            draft.params, is_leaf=is_bw)[0]:
+        if is_bw(leaf):
+            if leaf.indices.ndim == 4:  # stacked over layers: take layer 0
+                leaf = jax.tree.map(lambda a: a[0], leaf)
+            weights.append((f"{draft.cfg.name}{jax.tree_util.keystr(path)}",
+                            leaf))
+    rng = np.random.RandomState(seed)
+    v, c, bc = 8, 256, 128
+    for k, n in QWEN3_DRAFT_LINEARS:
+        weights.append((f"qwen3-0.6b-draft({k}x{n})", BVQWeight(
+            jnp.asarray(rng.randint(-8, 8, (n // bc, c, v)).astype(np.int8)),
+            jnp.asarray(rng.uniform(0.5, 1.5, (n // bc, 1, 1))
+                        .astype(np.float32) / np.sqrt(k)),
+            jnp.asarray(rng.randint(0, c, (n // bc, k // v, bc))
+                        .astype(np.int32)),
+            (k, n), v,
+        )))
+    linear = jax.jit(ops.bvq_linear)
+    kernel = jax.jit(bvq_matmul_pallas)  # f32 out, where bvq_linear casts
+    report = []
+    for name, bw in weights:
+        _check(ops.resolve_bvq_impl(bw) == "pallas",
+               f"{name}: bvq_linear resolved to the reference")
+        w = _bvq_dense64(bw)
+        for m, dtype in ((16, jnp.bfloat16), (40, jnp.float32)):
+            x = jnp.asarray(rng.randn(m, bw.shape[0]), jnp.float32).astype(dtype)
+            y = kernel(x, bw)
+            _check(bool(jnp.array_equal(linear(x, bw), y.astype(dtype))),
+                   f"{name}: bvq_linear differs from the kernel's output")
+            want = np.asarray(x.astype(jnp.float32), np.float64) @ w
+            got = np.asarray(y, np.float64)
+            err = float(np.abs(got - want).max() / np.abs(want).max())
+            _check(err < tol, f"{name}, {m} {jnp.dtype(dtype).name} rows: "
+                   f"bvq_linear is {err:.3g} of its output's scale from a "
+                   f"float64 decode (tolerance {tol})")
+            report.append((name, m, jnp.dtype(dtype).name, err))
+    return report
 
 
 def check_reference(target, prompts, outs, max_tokens: int, tol: float):
@@ -211,6 +297,14 @@ def main(argv=None) -> int:
           f"{time.perf_counter() - t0:.1f} s; peak_bytes_in_use "
           f"{stats.get('peak_bytes_in_use')}; bytes_in_use "
           f"{stats.get('bytes_in_use')}")
+
+    t0 = time.perf_counter()
+    bvq_report = check_bvq_kernel(draft, args.seed, BVQ_TOL)
+    print(f"BVQ kernel: {len(bvq_report)} checks against a float64 decode "
+          f"in {time.perf_counter() - t0:.1f} s; largest error "
+          f"{max(r[3] for r in bvq_report):.3g} of the output's scale")
+    for name, m, dtype, err in bvq_report:
+        print(f"  {name} {m}x{dtype}: {err:.3g}")
 
     prompts = _prompts(args.seed, target.cfg.vocab)
     engine = Engine(target, draft, EngineConfig())

@@ -16,11 +16,21 @@ from repro.core import quantization as q
 from repro.core import rotation as rot
 from repro.core.bvq import BVQWeight
 from repro.kernels import ref
-from repro.kernels.bvq_matmul import bvq_matmul_pallas
+from repro.kernels.bvq_matmul import bvq_matmul_pallas, vmem_bytes
 from repro.kernels.fwht import block_rotate_pallas
 from repro.kernels.w4a8_matmul import w4a8_matmul_pallas
 
-__all__ = ["lru_rotate", "lru_rotate_transpose", "w4a8_linear", "bvq_linear"]
+__all__ = [
+    "lru_rotate",
+    "lru_rotate_transpose",
+    "w4a8_linear",
+    "bvq_linear",
+    "resolve_bvq_impl",
+]
+
+# the Pallas BVQ kernel's VMEM budget: well under the 16 MiB a kernel may
+# take by default on a v5e, so a compile never meets the limit
+_BVQ_VMEM_BUDGET = 12 * 2**20
 
 
 def lru_rotate(
@@ -92,13 +102,43 @@ def w4a8_linear(
     return y.reshape(*lead, -1).astype(x.dtype)
 
 
+def resolve_bvq_impl(
+    bw: BVQWeight, use_pallas: Optional[bool] = None, backend: Optional[str] = None
+) -> str:
+    """``"pallas"`` or ``"reference"``: the path ``bvq_linear`` takes for
+    ``bw`` (stacked layer weights too: only the trailing shapes count).
+
+    ``use_pallas`` of ``None`` selects by what the program observes: the
+    Pallas kernel on a TPU at a shape it compiles for (128-lane blocks, K
+    a whole number of 16-row index tiles, the block's tile within the
+    kernel's VMEM budget), the jnp reference everywhere else.  An explicit
+    ``True``/``False`` always wins (``True`` runs the kernel interpreted
+    off the TPU).  ``backend`` overrides ``jax.default_backend()``."""
+    if use_pallas is not None:
+        return "pallas" if use_pallas else "reference"
+    if backend is None:
+        backend = jax.default_backend()
+    k, _ = bw.shape
+    c, v = bw.codebooks.shape[-2:]
+    bc = bw.indices.shape[-1]
+    fits = (
+        bc % 128 == 0
+        and k % (16 * v) == 0
+        and vmem_bytes(k, bc, v, c) <= _BVQ_VMEM_BUDGET
+    )
+    return "pallas" if backend == "tpu" and fits else "reference"
+
+
 @jax.named_scope("bvq_linear")
-def bvq_linear(x: jnp.ndarray, bw: BVQWeight, use_pallas: bool = True) -> jnp.ndarray:
-    """y = x @ reconstruct(bw) with on-the-fly codebook decode.  The decode
-    and the matmul carry the named scope ``bvq_linear``."""
+def bvq_linear(
+    x: jnp.ndarray, bw: BVQWeight, use_pallas: Optional[bool] = None
+) -> jnp.ndarray:
+    """y = x @ reconstruct(bw) with on-the-fly codebook decode, on the path
+    ``resolve_bvq_impl`` picks.  The decode and the matmul carry the named
+    scope ``bvq_linear``; the kernel's custom call is ``bvq_matmul``."""
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    if use_pallas:
+    if resolve_bvq_impl(bw, use_pallas) == "pallas":
         y = bvq_matmul_pallas(x2, bw)
     else:
         y = ref.bvq_matmul_ref2(x2, bw)

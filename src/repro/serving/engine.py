@@ -138,7 +138,13 @@ class ServingModel:
     mode: str = "bf16"  # bf16 | w4a8 | bvq
     mesh: Any = None
     s_max: int = 512
-    use_pallas: bool = False
+    # Pallas kernels for the quantized linears.  None is each mode's
+    # default, and the two differ on purpose: the BVQ linears take the
+    # kernel where it compiles (a TPU at a supported shape;
+    # kernels/ops.resolve_bvq_impl), the W4A8 linears keep their jnp
+    # reference on every backend.  True or False forces the kernels, or
+    # the references, in both modes.
+    use_pallas: Optional[bool] = None
     # paged decode attention path: "auto" resolves per backend (the Pallas
     # paged kernel where its TPU dialect lowers, the exact device gather
     # everywhere else); "gather" replays the exact dense math over a
@@ -154,7 +160,7 @@ class ServingModel:
         if self.mode == "w4a8":
             return qlm.apply_quantized_lm(
                 params, self.cfg, self.mesh, tokens, cache=cache,
-                use_pallas=self.use_pallas, **paged_kw,
+                use_pallas=bool(self.use_pallas), **paged_kw,
             )
         if self.mode == "bvq":
             return qlm.apply_bvq_lm(
@@ -908,6 +914,18 @@ class Engine:
             "prefix_cow_total",
             "Copy-on-write privatizations of a partially-shared prefix page",
         )
+        # the path each BVQ model's linears resolved to (fixed per engine)
+        bvq_impl = m.gauge(
+            "bvq_linear_impl",
+            "BVQ linears per layer on each path of ops.bvq_linear: the "
+            "Pallas kernel or the jnp reference",
+            ("model", "impl"),
+        )
+        for role, model in (("target", self.target), ("draft", self.draft)):
+            if model.mode == "bvq" and model.params is not None:
+                counts = qlm.bvq_impl_counts(model.params, model.use_pallas)
+                for impl, n in counts.items():
+                    bvq_impl.labels(model=role, impl=impl).set(n)
 
     def _refresh_gauges(self) -> None:
         """Republish the level-style series (queue depth, active slots,

@@ -43,6 +43,7 @@ __all__ = [
     "apply_quantized_lm",
     "bvq_compress_lm",
     "apply_bvq_lm",
+    "bvq_impl_counts",
     "quantized_param_specs",
     "abstract_quantized",
 ]
@@ -365,18 +366,32 @@ def bvq_compress_lm(
     }
 
 
+def bvq_impl_counts(qparams: Params, use_pallas: Optional[bool] = None) -> dict:
+    """How many of a BVQ model's linears (per layer) take each path of
+    ``ops.bvq_linear``: ``{"pallas": n, "reference": n}``."""
+    counts = {"pallas": 0, "reference": 0}
+    for leaf in jax.tree.leaves(
+        qparams, is_leaf=lambda t: isinstance(t, bvq_mod.BVQWeight)
+    ):
+        if isinstance(leaf, bvq_mod.BVQWeight):
+            counts[ops.resolve_bvq_impl(leaf, use_pallas)] += 1
+    return counts
+
+
 def apply_bvq_lm(
     qparams: Params,
     cfg: ModelConfig,
     mesh,
     tokens: jnp.ndarray,
     cache: Optional[Params] = None,
-    use_pallas: bool = False,
+    use_pallas: Optional[bool] = None,
     last_logit_only: bool = False,
     paged_impl: str = "gather",
 ) -> Tuple[jnp.ndarray, Optional[Params]]:
     """BVQ draft-LM forward: weights decoded from codebooks on the fly.
-    A cache carrying ``page_table`` is the device-resident paged pool."""
+    A cache carrying ``page_table`` is the device-resident paged pool.
+    ``use_pallas=None`` lets each linear take the path
+    ``ops.resolve_bvq_impl`` picks."""
     tp = mesh.shape["model"] if mesh is not None else 1
     b, s = tokens.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv, cfg.hd

@@ -123,27 +123,78 @@ def test_w4a8_end_to_end_linear():
 # ---------------------------------------------------------------------------
 
 
+def _random_bvq(rng, k, n, vec, cbs, bc, lead=()):
+    """A BVQ weight in its served form, drawn directly: INT4 codebook
+    entries, int32 indices, f32 block scales (optionally layer-stacked)."""
+    nb = n // bc
+    return bvq.BVQWeight(
+        codebooks=jnp.asarray(rng.randint(-8, 8, lead + (nb, cbs, vec)).astype(np.int8)),
+        scales=jnp.asarray(rng.rand(*lead, nb, 1, 1).astype(np.float32) / np.sqrt(k)),
+        indices=jnp.asarray(rng.randint(0, cbs, lead + (nb, k // vec, bc)).astype(np.int32)),
+        shape=(k, n),
+        vec_dim=vec,
+    )
+
+
 @pytest.mark.parametrize(
-    "mk,nn,vec,cbs,bc",
+    "mk,nn,vec,cbs,bc,layers",
     [
-        ((8, 64), 48, 4, 32, 16),
-        ((32, 128), 128, 8, 64, 32),
-        ((1, 256), 64, 8, 16, 64),  # decode GEMV
-        ((16, 96), 96, 4, 16, 48),
+        ((8, 64), 48, 4, 32, 16, 0),
+        ((32, 128), 128, 8, 64, 32, 0),
+        ((1, 256), 64, 8, 16, 64, 0),  # decode GEMV
+        ((16, 96), 96, 4, 16, 48, 0),
+        # the served draft's blocks (v 8, C 256, 128 columns): a draft step,
+        # an odd row count, a prefill tile, and a layer-stacked weight
+        # sliced by a scan as apply_bvq_lm does
+        ((16, 1024), 256, 8, 256, 128, 0),
+        ((40, 3072), 256, 8, 256, 128, 0),
+        ((256, 1024), 384, 8, 256, 128, 0),
+        ((16, 3072), 256, 8, 256, 128, 3),
     ],
 )
-def test_bvq_matches_oracle(mk, nn, vec, cbs, bc):
+def test_bvq_matches_oracle(mk, nn, vec, cbs, bc, layers):
     m, k = mk
     rng = np.random.RandomState(7)
-    cfg = bvq.BVQConfig(
-        vec_dim=vec, codebook_size=cbs, block_cols=bc, kmeans_iters=4, qat_steps=0
-    )
-    w = jnp.asarray(rng.randn(k, nn).astype(np.float32))
-    bw = bvq.bvq_compress(w, cfg, jax.random.PRNGKey(0))
+    if cbs < 256:
+        cfg = bvq.BVQConfig(
+            vec_dim=vec, codebook_size=cbs, block_cols=bc, kmeans_iters=4, qat_steps=0
+        )
+        w = jnp.asarray(rng.randn(k, nn).astype(np.float32))
+        bw = bvq.bvq_compress(w, cfg, jax.random.PRNGKey(0))
+    else:
+        bw = _random_bvq(rng, k, nn, vec, cbs, bc, lead=(layers,) if layers else ())
     x = jnp.asarray(rng.randn(m, k).astype(np.float32))
-    got = bvq_matmul_pallas(x, bw)
-    want = ref.bvq_matmul_ref2(x, bw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+    if layers:
+        _, got = jax.lax.scan(lambda c, w: (c, bvq_matmul_pallas(x, w)), 0, bw)
+    else:
+        got = bvq_matmul_pallas(x, bw)
+    with jax.default_matmul_precision("highest"):
+        if layers:
+            want = jnp.stack([
+                ref.bvq_matmul_ref2(x, jax.tree.map(lambda a: a[i], bw))
+                for i in range(layers)
+            ])
+        else:
+            want = ref.bvq_matmul_ref2(x, bw)
+    want = np.asarray(want)
+    np.testing.assert_allclose(
+        np.asarray(got), want, rtol=1e-5, atol=min(1e-4, 1e-5 * np.abs(want).max())
+    )
+
+
+def test_resolve_bvq_impl_branches():
+    """The kernel on a TPU at a shape it compiles for; the reference on
+    the CPU, at 32-column blocks, or when the caller says so."""
+    rng = np.random.RandomState(9)
+    served = _random_bvq(rng, 1024, 256, 8, 256, 128)
+    small = _random_bvq(rng, 1024, 256, 8, 256, 32)
+    stacked = _random_bvq(rng, 1024, 256, 8, 256, 128, lead=(2,))
+    assert ops.resolve_bvq_impl(served, backend="tpu") == "pallas"
+    assert ops.resolve_bvq_impl(stacked, backend="tpu") == "pallas"
+    assert ops.resolve_bvq_impl(served, backend="cpu") == "reference"
+    assert ops.resolve_bvq_impl(small, backend="tpu") == "reference"
+    assert ops.resolve_bvq_impl(served, use_pallas=False, backend="tpu") == "reference"
+    assert ops.resolve_bvq_impl(small, use_pallas=True, backend="cpu") == "pallas"
 
 
 def test_bvq_linear_wrapper_batched():
@@ -152,7 +203,7 @@ def test_bvq_linear_wrapper_batched():
     w = jnp.asarray(rng.randn(64, 32).astype(np.float32))
     bw = bvq.bvq_compress(w, cfg, jax.random.PRNGKey(1))
     x = jnp.asarray(rng.randn(2, 3, 64).astype(np.float32))
-    y = ops.bvq_linear(x, bw)
+    y = ops.bvq_linear(x, bw, use_pallas=True)
     want = x.reshape(-1, 64) @ bvq.bvq_reconstruct(bw)
     np.testing.assert_allclose(
         np.asarray(y).reshape(-1, 32), np.asarray(want), rtol=1e-4, atol=1e-4

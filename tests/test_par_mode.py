@@ -264,3 +264,19 @@ def test_wdos_mixed_fp_int8_batch_bit_matches_two_phase(pair):
                       draft_len=3, kv_quant="none")
     np.testing.assert_array_equal(np.asarray(wdos[0]), np.asarray(dense[0]))
     np.testing.assert_array_equal(np.asarray(wdos[2]), np.asarray(dense[1]))
+
+
+def test_bvq_linear_impl_gauge(qpair):
+    """The engine reports the path its BVQ draft's linears resolved to:
+    the reference off the TPU, the kernel when the caller forces it."""
+    import dataclasses
+
+    target, draft = qpair
+    eng = Engine(target, draft, EngineConfig(max_batch=2, page_size=8))
+    read = lambda impl: eng.metrics.value("bvq_linear_impl", model="draft", impl=impl)
+    assert (read("reference"), read("pallas")) == (7, 0)
+    assert eng.metrics.value("bvq_linear_impl", model="target", impl="pallas") == 0
+    forced = Engine(target, dataclasses.replace(draft, use_pallas=True),
+                    EngineConfig(max_batch=2, page_size=8))
+    assert forced.metrics.value(
+        "bvq_linear_impl", model="draft", impl="pallas") == 7

@@ -2,7 +2,9 @@
 
 Nothing runs: each test lowers a Pallas kernel at the paper target's
 widths (llama2-7b: 32 kv heads of 128, 16-slot pages; the W4A8 down/up
-projection shapes) for a v5e that is described, not attached, and asserts
+projection shapes), or at the served BVQ drafts' (Qwen3-0.6B: K 1024 and
+3072 over 128-column blocks of 256-entry codebooks; llama-68m: K 768), for
+a v5e that is described, not attached, and asserts
 that the compiled program calls the kernel (``tpu_custom_call``).  What
 interpret-mode tests cannot see — a block shape Mosaic refuses, an
 operand type the MXU lacks — fails here.  The topology is described inside
@@ -15,6 +17,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.bvq import BVQWeight
+from repro.kernels.bvq_matmul import bvq_matmul_pallas
 from repro.kernels.paged_attn import paged_decode_attention_pallas
 from repro.kernels.w4a8_matmul import w4a8_matmul_pallas
 
@@ -94,5 +98,22 @@ def test_w4a8_matmul_compiles_for_v5e(one_chip):
         lambda x, w, sx, sw: w4a8_matmul_pallas(x, w, sx, sw, interpret=False),
         s((m, k), jnp.int8), s((k // 2, n), jnp.int8),
         s((m, 1), jnp.float32), s((1, n), jnp.float32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m", [16, 128])
+@pytest.mark.parametrize("k,n", [(1024, 3072), (3072, 1024), (768, 3072)])
+def test_bvq_matmul_compiles_for_v5e(one_chip, m, k, n):
+    v, c, bc = 8, 256, 128
+    s = lambda shape, dtype: _spec(one_chip, shape, dtype)
+    nb = n // bc
+    bw = BVQWeight(
+        s((nb, c, v), jnp.int8), s((nb, 1, 1), jnp.float32),
+        s((nb, k // v, bc), jnp.int32), (k, n), v,
+    )
+    text = _compiled_text(
+        lambda x, w: bvq_matmul_pallas(x, w, interpret=False),
+        s((m, k), jnp.bfloat16), bw,
     )
     assert "tpu_custom_call" in text
