@@ -1,7 +1,7 @@
 """Readings that set a cell's correctness limit: the program's and the
 control's, over many seeds, in one process.
 
-    python3 chipbench/calibrate.py --workload qwen3-8b.chat --seconds 51 --seeds 101,102,103
+    python3 chipbench/calibrate.py --workload <cell> --seconds 51 --seeds 101,102,103
 
 For each seed it makes one run of the cell as ``run.py`` would (weights,
 traffic, warm-up, ramp, a window of ``--seconds``), then scores the same
@@ -10,10 +10,11 @@ against the reference (the gaps a run compares) and by the control, the
 reference at int4 activations in the program's place (the gap of the token
 that the control puts first).  Both go through ``check.verdict``, the
 comparison ``run.py`` uses, so the control's line shows the ``correct`` it
-would get.  Beside the widest gap it prints the mean gap and the share of
-positions whose gap is above 0, for each side.  One JSON line per seed.
-The limit sits above the largest program reading and below the smallest
-control reading (see PERF.md).  Not part of a benchmark run.
+would get.  For each side it prints the widest and the mean gap (the two
+numbers with limits) and the share of positions whose gap is above 0.
+One JSON line per seed.  Each limit sits above the largest program reading
+and below the smallest control reading (see PERF.md).  Not part of a
+benchmark run.
 """
 from __future__ import annotations
 
@@ -61,7 +62,7 @@ def main(argv=None) -> int:
             allg = np.concatenate(per_request) if per_request else np.zeros(0)
             ok, checks = check.verdict(per_request, 0, ck)
             line[side] = {"correct": ok, "served_gap": checks["served_gap"]["value"],
-                          "mean_gap": float(allg.mean()) if allg.size else None,
+                          "mean_gap": checks["mean_gap"]["value"],
                           "flip_share": float((allg > 0).mean()) if allg.size else None,
                           "positions": int(allg.size)}
         print(json.dumps(line), flush=True)

@@ -8,8 +8,10 @@ finished is scored whole; one still streaming at the close is scored on
 the prefix it had served.  At each served position the gap is how far the
 served token's reference logit lies below the reference's best logit there
 (0 where it is the reference's own choice).  ``verdict`` holds the widest
-gap over the sample, the number of positions scored and the stream errors
-to their limits.
+gap over the sample, the mean gap over its positions, the number of
+positions scored and the stream errors to their limits.  The widest gap
+catches one token gone wrong; the mean gap a drift that moves every
+position a little, each too little for the widest gap to see.
 
 The control reads the same positions with the reference computed one
 precision lower (int4 activations, ``act_bits=4``) in the program's place:
@@ -77,11 +79,13 @@ def verdict(per_request: List[np.ndarray], failed: int, ck: dict) -> Tuple[bool,
     its value and limit."""
     allg = np.concatenate(per_request) if per_request else np.zeros(0)
     widest = float(allg.max()) if allg.size else None  # nothing scored
+    mean = float(allg.mean()) if allg.size else None
     checks = {
         "served_gap": {"value": widest, "limit": ck["served_gap_limit"]},
+        "mean_gap": {"value": mean, "limit": ck["mean_gap_limit"]},
         "stream_errors": {"value": failed, "limit": 0},
         "sampled_tokens": {"value": int(allg.size), "limit": ck["min_tokens"]},
     }
     correct = (allg.size >= max(ck["min_tokens"], 1) and failed == 0
-               and widest <= ck["served_gap_limit"])
+               and widest <= ck["served_gap_limit"] and mean <= ck["mean_gap_limit"])
     return correct, checks

@@ -9,11 +9,11 @@ the moment it was due.
 
 Phases of a run, in order:
 
-1. set-up (``setup_s``): served-form weights drawn on the device
-   (``weights.py``), the engine and server built, one short request per
-   prompt length and decoding kind sent so every program the window uses is
-   compiled or loaded from the persistent cache, then arrivals start
-   ``ramp_s`` before the window so it opens at steady state;
+1. set-up (``setup_s``): served-form weights drawn on the device (the
+   configuration's ``served/<name>.py``), the engine and server built, one
+   short request per prompt length and decoding kind sent so every program
+   the window uses is compiled or loaded from the persistent cache, then
+   arrivals start ``ramp_s`` before the window so it opens at steady state;
 2. the measured window of ``--seconds``; with ``--trace 1`` the profiler
    records it;
 3. at the close every request still in flight is dropped (the client hangs
@@ -34,11 +34,12 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
 
-from chipbench import check, flops, loadgen
+from chipbench import check, loadgen
 from chipbench import trace as tr
 from chipbench.peaks import peaks_for
 from chipbench.spec import ROOT, Bench
@@ -86,6 +87,7 @@ class Ctx:
     model: dict  # the target's sizes (configuration keys)
     peaks: dict
     trace: Optional[tr.DeviceTrace] = None
+    served: Optional[ModuleType] = None  # the configuration's served form
 
     @property
     def window_s(self) -> float:
@@ -151,38 +153,22 @@ class _CompileCounter:
         return sum(t0 <= t < t1 for t in self.times)
 
 
-def model_config(name: str, m: dict, dtype: str):
-    from repro.models.common import Family, ModelConfig
-
-    return ModelConfig(
-        name=name, family=Family.DENSE, n_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], n_heads=m["num_attention_heads"],
-        n_kv=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab=m["vocab_size"], head_dim=m.get("head_dim"),
-        qk_norm=bool(m.get("qk_norm", False)),
-        rope_theta=float(m["rope_theta"]), dtype=dtype,
-    )
-
-
 def max_model_len(config: dict, traffic: dict) -> int:
     return (max(traffic["prompt_len"]["grid"]) + traffic["max_tokens"]["max"]
             + config["engine"].get("draft_len", 3))
 
 
-def build(config: dict, traffic: dict, seed: int):
-    """(target params, Engine) for one cell."""
+def build(config: dict, traffic: dict, seed: int, served: ModuleType):
+    """(target params, Engine) for one cell, its models drawn and shaped by
+    the configuration's served form."""
     import jax
 
     from repro.serving import Engine, EngineConfig
     from repro.serving.engine import ServingModel
 
-    from chipbench import weights
-
     sv = config["serving"]
     key = jax.random.PRNGKey(int(np.random.default_rng([seed, 0]).integers(2**31 - 1)))
-    kt, kd = jax.random.split(key)
-    tparams = weights.w4a8_target(kt, config, sv["dtype"])
-    dparams = weights.bvq_draft(kd, config["draft"], sv["bvq"], sv["dtype"])
+    tparams, dparams = served.draw(key, config)
     jax.block_until_ready((tparams, dparams))
     s_max = max_model_len(config, traffic)
     eng = dict(config["engine"])
@@ -190,10 +176,9 @@ def build(config: dict, traffic: dict, seed: int):
     eng.update(max_batch=traffic["engine"]["max_batch"],
                num_pages=-(-traffic["engine"]["pool_tokens"] // page),
                max_model_len=s_max)
-    target = ServingModel(cfg=model_config(config["name"], config, sv["dtype"]),
+    target = ServingModel(cfg=served.program_config(config, "target"),
                           params=tparams, mode=sv["target"], s_max=s_max)
-    draft = ServingModel(cfg=model_config(config["name"] + "-draft", config["draft"],
-                                          sv["dtype"]),
+    draft = ServingModel(cfg=served.program_config(config, "draft"),
                          params=dparams, mode=sv["draft"], s_max=s_max)
     return tparams, Engine(target, draft, EngineConfig(**eng))
 
@@ -308,16 +293,8 @@ async def serve(engine, records: List[Record], warm: List[Record], traffic: dict
 
 
 def end_to_end(ctx: Ctx, setup_s: float) -> Dict[str, float]:
-    gaps = []
-    for r in ctx.records:
-        gaps.extend(b - a for a, b in zip(r.times, r.times[1:])
-                    if ctx.t_open <= b < ctx.t_close)
     n_out = sum(ctx.t_open <= t < ctx.t_close for r in ctx.records for t in r.times)
-    return {
-        "output_tokens_per_s": n_out / ctx.window_s,
-        "itl_p95_s": float(np.percentile(gaps, 95)) if gaps else math.nan,
-        "setup_s": setup_s,
-    }
+    return {"output_tokens_per_s": n_out / ctx.window_s, "setup_s": setup_s}
 
 
 def _log(msg: str) -> None:
@@ -339,6 +316,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
     config = bench.config(w["config"])
     traffic = bench.traffic(w["traffic"])
     reference = bench.reference(config["reference"])
+    served = bench.served(config["served"])
     dev = jax.devices()[0]
     peaks = peaks_for(dev.device_kind)  # an unknown chip fails before any work
 
@@ -350,7 +328,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
     compiles = _CompileCounter()
     _log(f"{workload} seed {seed} on {dev.device_kind}; compile cache {cache_dir}")
 
-    tparams, engine = build(config, traffic, seed)
+    tparams, engine = build(config, traffic, seed, served)
     _log(f"built in {time.perf_counter() - t_start:.1f} s")
     vocab = config["vocab_size"]
     records = [Record(req=q, due=q.due_s)
@@ -368,7 +346,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
 
     ctx = Ctx(t_open=window.t_open, t_close=window.t_close, records=records,
               counters_open=window.c_open, counters_close=window.c_close,
-              model=config, peaks=peaks)
+              model=config, peaks=peaks, served=served)
     n_compiles = compiles.between(window.t_open, window.t_close)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": memory_peak}

@@ -12,13 +12,16 @@ One general generator for every traffic mix; a mix is only parameters:
 Prompt lengths are a lognormal snapped to the grid (nearest point in log
 space), so set-up can warm every prefill shape.  To keep runs of different
 seeds alike, the ramp and the window are drawn apart, each holding
-``rate * duration`` requests, and every seed gets the SAME multiset of
-prompt lengths, output lengths and inter-arrival gaps in each — stratified
-quantiles of the distributions, the gaps scaled to span the segment — so
-the seed only permutes them and draws the token ids and the per-request
-sampling seeds.  Greedy requests sit at fixed places in the arrival order,
-spread evenly (every second one for a share of 0.5), so every seed has the
-same mix of greedy and sampled rows at every point of the run.
+``rate * duration`` requests, and every seed gets the SAME prompt lengths,
+output lengths and inter-arrival gaps in each, in the SAME order —
+stratified quantiles of the distributions, the gaps scaled to span the
+segment, shuffled once by a fixed generator — so the seed draws only the
+token ids and the per-request sampling seeds.  (A per-seed order let the
+seed choose which requests finish inside the window, and with them how
+many admissions stall every stream there.)  Greedy requests sit at fixed
+places in the arrival order, spread evenly (every second one for a share
+of 0.5), so every seed has the same mix of greedy and sampled rows at
+every point of the run.
 """
 from __future__ import annotations
 
@@ -96,15 +99,16 @@ def greedy_flags(share: float, n: int) -> np.ndarray:
 def schedule(traffic: dict, seed: int, seconds: float, vocab: int) -> List[Request]:
     """The run's requests, in due order: the ramp's, then the window's."""
     rng = np.random.default_rng(seed)
+    order = np.random.default_rng(0)  # one order of the sizes, for every seed
     smp = traffic["sampling"]
     out: List[Request] = []
     start = 0.0
     for n, span in _segments(traffic, seconds):
         u = _quantiles(n)
-        gaps = rng.permutation(-np.log1p(-u))
+        gaps = order.permutation(-np.log1p(-u))
         due = start + span * (np.cumsum(gaps) - gaps[0]) / gaps.sum()
-        plens = rng.permutation(grid_lengths(traffic["prompt_len"], n))
-        olens = rng.permutation(output_lengths(traffic["max_tokens"], n))
+        plens = order.permutation(grid_lengths(traffic["prompt_len"], n))
+        olens = order.permutation(output_lengths(traffic["max_tokens"], n))
         greedy = greedy_flags(smp["greedy_share"], n)
         for i in range(n):
             out.append(Request(

@@ -10,18 +10,19 @@ product at ``Precision.HIGHEST`` and no cache, paging, batching or kernel:
   codes (exact integer sums), and scaled back by the row scale and the
   weight's column scale;
 - attention: optional per-head q/k RMSNorm with gains, rotary position
-  embedding on the two halves of each head (angle ``p * theta^(-i/half)``),
-  causal softmax over grouped K/V heads (query head j reads K/V head
-  ``j // (heads / kv_heads)``);
+  embedding on the two halves of each head (angle ``p * theta^(-i/half)``,
+  with ``p / factor`` in place of ``p`` under a ``rope_scaling`` of type
+  ``linear``), causal softmax over grouped K/V heads (query head j reads
+  K/V head ``j // (heads / kv_heads)``);
 - feed-forward: ``silu(gate) * up``, then the online rotation of the
   configuration's ``online_rotation`` (orthonormal Hadamard blocks of
   ``m * 2^k`` channels, applied twice with the second pass shifted by half a
   block for a "tiled" plan), then the down projection;
 - a final norm and the W4A8 head.
 
-It reads the served weights only as arrays in the layout ``weights.py``
-draws, and imports nothing of the program.  ``act_bits=4`` gives the
-control: the same model with int4 activations.
+It reads the served weights only as arrays in the layout
+``served/dense_w4a8_bvq.py`` draws, and imports nothing of the program.
+``act_bits=4`` gives the control: the same model with int4 activations.
 """
 from __future__ import annotations
 
@@ -120,11 +121,14 @@ def _norm(x, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
 
 
-def _rope(x, theta: float):
+def _rope(x, theta: float, factor: float = 1.0):
     t, _, hd = x.shape
     half = hd // 2
     inv = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    pos = jnp.arange(t, dtype=jnp.float32)
+    if factor != 1.0:
+        pos = pos / factor
+    ang = pos[:, None] * inv[None, :]
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
@@ -156,7 +160,7 @@ def _hidden(params, tokens, hb, m, kind, act_bits):
         if m["qk_norm"]:
             q = _norm(q, eps) * p["qk_extra"]["q_norm"].astype(jnp.float32)
             k = _norm(k, eps) * p["qk_extra"]["k_norm"].astype(jnp.float32)
-        q, k = _rope(q, m["theta"]), _rope(k, m["theta"])
+        q, k = _rope(q, m["theta"], m["rope_factor"]), _rope(k, m["theta"], m["rope_factor"])
         x = x + _linear(_attention(q, k, v), p["wo"], act_bits)
         z = _norm(x, eps)
         hid = (jax.nn.silu(_linear(z, p["w_gate"], act_bits))
@@ -172,6 +176,16 @@ def _hidden(params, tokens, hb, m, kind, act_bits):
 def _head(head, hidden, start, n, act_bits):
     rows = jax.lax.dynamic_slice_in_dim(hidden, start, n, axis=0)
     return _linear(rows, head, act_bits)
+
+
+def _rope_factor(config: dict) -> float:
+    rs = config.get("rope_scaling")
+    if rs is None:
+        return 1.0
+    kind = rs.get("type", rs.get("rope_type"))
+    if kind != "linear":
+        raise ValueError(f"rope_scaling of type {kind!r} is not written out here")
+    return float(rs["factor"])
 
 
 def _bucket(x: int, step: int) -> int:
@@ -193,7 +207,7 @@ def logits_rows(params: dict, config: dict, tokens, start: int, n: int,
              hd=config.get("head_dim")
              or config["hidden_size"] // config["num_attention_heads"],
              eps=float(config["rms_norm_eps"]), theta=float(config["rope_theta"]),
-             qk_norm=bool(config.get("qk_norm", False)))
+             rope_factor=_rope_factor(config), qk_norm=bool(config.get("qk_norm", False)))
     hidden = _hidden(params, jnp.asarray(tok), jnp.asarray(rotation_block(rot)),
                      tuple(sorted(m.items())), rot["kind"], act_bits)
     return _head(params["head"], hidden, start, rows, act_bits)

@@ -1,6 +1,6 @@
 """Compile a cell's programs for a described TPU v5e, without the chip.
 
-    JAX_PLATFORMS=cpu PYTHONPATH=src python3 -m chipbench.rehearse --workload qwen3-8b.chat
+    JAX_PLATFORMS=cpu PYTHONPATH=src python3 -m chipbench.rehearse --workload <cell>
 
 Lowers and compiles, at the cell's real sizes and for one chip of a
 described ``v5e:2x2`` topology, the engine's verify step (target, window
@@ -39,12 +39,13 @@ def main(argv=None) -> int:
     from repro.serving import paged_cache as pc
     from repro.serving.engine import ServingModel, _make_paged_step
 
-    from chipbench import harness, weights
+    from chipbench import harness
     from chipbench.spec import Bench
 
     bench = Bench()
     w = bench.workload(args.workload)
     config, traffic = bench.config(w["config"]), bench.traffic(w["traffic"])
+    served = bench.served(config["served"])
     sv = config["serving"]
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -53,9 +54,7 @@ def main(argv=None) -> int:
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
 
     key = jax.random.PRNGKey(0)
-    tparams = jax.eval_shape(lambda k: weights.w4a8_target(k, config, sv["dtype"]), key)
-    dparams = jax.eval_shape(lambda k: weights.bvq_draft(k, config["draft"], sv["bvq"],
-                                                         sv["dtype"]), key)
+    tparams, dparams = jax.eval_shape(lambda k: served.draw(k, config), key)
     s_max = harness.max_model_len(config, traffic)
     page = config["engine"]["page_size"]
     batch = traffic["engine"]["max_batch"]
@@ -68,11 +67,10 @@ def main(argv=None) -> int:
     # the paged kernel picks interpret mode from the default backend, which
     # here is the CPU: lower it as the chip would
     jax.default_backend = lambda: "tpu"
-    for role, params, m, mode, w_len in (
-        ("verify", tparams, config, sv["target"], window),
-        ("draft", dparams, config["draft"], sv["draft"], 1),
+    for role, params, cfg, mode, w_len in (
+        ("verify", tparams, served.program_config(config, "target"), sv["target"], window),
+        ("draft", dparams, served.program_config(config, "draft"), sv["draft"], 1),
     ):
-        cfg = harness.model_config(role, m, sv["dtype"])
         model = ServingModel(cfg=cfg, params=None, mode=mode, s_max=s_max,
                              paged_attn_impl="pallas")
         kvh, hd = cfg.n_kv, cfg.hd

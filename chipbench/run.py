@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Run one cell of the chip benchmark and print its result line.
 
-    python3 chipbench/run.py --workload qwen3-8b.chat --seed 7 --seconds 30 --trace 0
+    python3 chipbench/run.py --workload <cell> --seed 7 --seconds 30 --trace 0
 
 Runs on the machine it is started on and needs as many TPU chips as the
 cell asks for: it exits non-zero, printing no result, before building

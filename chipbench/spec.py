@@ -5,13 +5,29 @@ metrics.  Everything that belongs to one of them sits in a file of its own
 under this directory, found by its name:
 
 - a configuration: the ``file`` its ``configs`` entry names (JSON sizes),
-  with its plain reference in ``references/<reference>.py``;
+  with its plain reference in ``references/<reference>.py`` and its served
+  form in ``served/<served>.py``;
 - a traffic mix: ``traffic/<traffic>.json``, read by ``loadgen.py``;
 - a per-layer metric: ``metrics/<name>.py``, whose ``read(ctx)`` returns
   the value or ``None`` where it finds nothing to read.
 
 A later cell, configuration or metric is added by adding files and entries;
 no file here changes.
+
+A served-form module says how one kind of model is drawn and served; the
+harness knows a model only through its four names:
+
+- ``draw(key, config) -> (target_params, draft_params)``: both models drawn
+  on the device from the key, already in their served form, one jitted
+  program each;
+- ``program_config(config, role) -> ModelConfig``: the program's shape of
+  the target (``role="target"``) or the draft (``role="draft"``); a key of
+  the configuration the program cannot take raises ``SpecError``;
+- ``model_ops(config, positions) -> float``: the target's model operations
+  for tokens at the given 0-based positions (for a sparse model, those of
+  the parameters one token activates);
+- ``smoke(config) -> dict``: the configuration's CPU copy, small enough for
+  the test suite, of the same kind of model.
 """
 from __future__ import annotations
 
@@ -61,6 +77,9 @@ class Bench:
                 cfg = json.loads((self.root / c["file"]).read_text())
                 if cfg.get("name") != name:
                     raise SpecError(f"{c['file']} names {cfg.get('name')!r}")
+                for key in ("served", "reference"):
+                    if key not in cfg:
+                        raise SpecError(f"{c['file']} names no {key!r} module")
                 return cfg
         raise SpecError(f"no config {name!r} in BENCHMARK.json")
 
@@ -72,6 +91,9 @@ class Bench:
 
     def reference(self, name: str) -> ModuleType:
         return _load_module(self.dir / "references" / f"{name}.py", f"ref_{name}")
+
+    def served(self, name: str) -> ModuleType:
+        return _load_module(self.dir / "served" / f"{name}.py", f"served_{name}")
 
     def metrics_for(self, workload: str, kind: str) -> list:
         """The ``end_to_end`` or ``per_layer`` entries a cell reports: those

@@ -1,6 +1,6 @@
 """Find a cell's knee: the highest open-loop rate its server sustains.
 
-    python3 chipbench/sweep.py --workload qwen3-8b.chat --seed 5 --seconds 30 --rates 0.4,0.6,0.8
+    python3 chipbench/sweep.py --workload <cell> --seed 5 --seconds 30 --rates 0.4,0.6,0.8
 
 One process, one set-up; then one window per rate, each after the cell's
 ramp, with the cell's traffic at that rate.  For each rate it prints one
@@ -48,7 +48,7 @@ def main(argv=None) -> int:
     bench = Bench(ROOT)
     w = bench.workload(args.workload)
     config, traffic = bench.config(w["config"]), bench.traffic(w["traffic"])
-    _, engine = harness.build(config, traffic, args.seed)
+    _, engine = harness.build(config, traffic, args.seed, bench.served(config["served"]))
     vocab = config["vocab_size"]
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         mix = dict(traffic, rate_per_s=rate)

@@ -44,13 +44,14 @@ def test_token_altered_where_produced_fails_the_check(root, monkeypatch):
         return new, n_acc
 
     monkeypatch.setattr(eng, "speculative_accept_greedy_host", wrong)
-    res = harness.run("qwen3-smoke.greedy", 9, 2.0, False, time.perf_counter(), root=root,
+    greedy = [c for c in smoke.cells() if c.endswith("-greedy")][0]
+    res = harness.run(greedy, 9, 2.0, False, time.perf_counter(), root=root,
                       compile_cache=False)
     assert res["correct"] is False
     assert res["checks"]["served_gap"]["value"] > LIMIT
 
 
-@pytest.mark.parametrize("cell", sorted(smoke.SMOKE_CELLS))
+@pytest.mark.parametrize("cell", smoke.cells())
 def test_control_reads_above_the_limit_where_the_program_reads_within(root, cell):
     keep: dict = {}
     res = harness.run(cell, 11, 2.0, False, time.perf_counter(), root=root,

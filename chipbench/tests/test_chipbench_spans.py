@@ -72,7 +72,7 @@ def test_sampling_idle_share_of_a_recorded_engine_window(tmp_path):
 def test_traced_harness_run_reports_sampling_idle_share(tmp_path, monkeypatch):
     monkeypatch.setattr(harness, "peaks_for", lambda kind: PEAKS["TPU v5 lite"])
     root = smoke.make_root(tmp_path)
-    res = harness.run("qwen3-smoke.chat", 6, 2.0, True, time.perf_counter(),
+    res = harness.run("qwen3-8b.chat", 6, 2.0, True, time.perf_counter(),
                       root=root, compile_cache=False)
     m = res["metrics"]
     assert res["correct"] is True

@@ -1,7 +1,8 @@
 """Unit tests of the benchmark's own arithmetic: trace reduction, operation
-count, peaks table, traffic generation, discovery by name, the reference's
-rotation and unpacking, and the command's refusal of a machine without a
-TPU.  CPU only; nothing here loads the TPU library."""
+count, peaks table, traffic generation, discovery by name, the served form's
+draw, the reference's rotation, unpacking and RoPE scaling, and the
+command's refusal of a machine without a TPU.  CPU only; nothing here loads
+the TPU library."""
 from __future__ import annotations
 
 import json
@@ -13,12 +14,13 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from chipbench import flops, loadgen
+from chipbench import loadgen
 from chipbench import trace as tr
 from chipbench.peaks import UnknownDevice, peaks_for
 from chipbench.spec import ROOT, Bench, SpecError
 
 E = tr.Event
+DENSE = Bench().served("dense_w4a8_bvq")
 
 
 def test_union_merges_overlaps_and_clips_to_the_window():
@@ -83,7 +85,7 @@ def _ctx(trace=None, **kw):
     base = dict(t_open=0.0, t_close=10.0, records=[], counters_open={"drafted": 0, "accepted": 0},
                 counters_close={"drafted": 300, "accepted": 120},
                 model=json.loads((ROOT / "chipbench/configs/qwen3-8b-spec.json").read_text()),
-                peaks=peaks_for("TPU v5 lite"), trace=trace)
+                peaks=peaks_for("TPU v5 lite"), trace=trace, served=DENSE)
     base.update(kw)
     return Ctx(**base)
 
@@ -104,6 +106,20 @@ def test_metric_readers_on_a_synthetic_window(name, expect):
     assert read(_ctx(trace=dt)) == pytest.approx(expect)
 
 
+def test_stream_gap_p95_reads_the_gaps_that_end_in_the_window():
+    from chipbench.harness import Record
+
+    req = loadgen.Request(0, 0.0, [1] * 4, 30, 0.0, 1.0, 0, 0)
+    # gaps ending in [0, 10): 1.0 (ends at 1.0) and 0.4 twenty times
+    inside = Record(req=req, due=0.0, times=[0.0, 1.0] + [1.0 + 0.4 * i for i in range(1, 21)],
+                    tokens=[1] * 22)
+    outside = Record(req=req, due=0.0, times=[-5.0, -1.0, 12.0], tokens=[1, 2, 3])
+    read = Bench().reader("stream_gap_p95_s")
+    gaps = [1.0] + [0.4] * 20
+    assert read(_ctx(records=[inside, outside])) == pytest.approx(np.percentile(gaps, 95))
+    assert read(_ctx(records=[outside])) is None
+
+
 def test_trace_readers_find_nothing_without_a_trace():
     bench = Bench()
     for name in ("verify_device_ms", "draft_device_ms", "paged_attn_busy_share",
@@ -115,10 +131,90 @@ def test_operation_count_of_the_target():
     m = json.loads((ROOT / "chipbench/configs/qwen3-8b-spec.json").read_text())
     per_layer = 4096 * 4096 + 2 * 4096 * 1024 + 4096 * 4096 + 3 * 4096 * 12288
     n = 36 * per_layer + 4096 * 151936
-    assert flops.matmul_params(m) == n
+    assert DENSE.matmul_params(m) == n
     # one token at position 0 attends to one key; one at 99 to a hundred
-    assert flops.ops_for_tokens(m, [0]) == 2 * n + 4 * 36 * 32 * 128
-    assert flops.ops_for_tokens(m, [0, 99]) == 4 * n + 4 * 36 * 32 * 128 * 101
+    assert DENSE.model_ops(m, [0]) == 2 * n + 4 * 36 * 32 * 128
+    assert DENSE.model_ops(m, [0, 99]) == 4 * n + 4 * 36 * 32 * 128 * 101
+
+
+def _digest(tree) -> str:
+    import hashlib
+
+    import jax
+
+    h = hashlib.sha256()
+    for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        x = np.asarray(x)
+        h.update(f"{jax.tree_util.keystr(path)}:{x.dtype}:{x.shape}".encode())
+        h.update(x.tobytes())
+    return h.hexdigest()[:16]
+
+
+def _qwen3_smoke():
+    return DENSE.smoke(json.loads((ROOT / "chipbench/configs/qwen3-8b-spec.json").read_text()))
+
+
+def test_dense_draw_is_the_weights_the_qwen3_cell_drew_before():
+    """Digests of ``weights.w4a8_target`` and ``weights.bvq_draft`` (the
+    modules this one replaced) at the Qwen3 pair's smoke sizes, each drawn
+    from its half of ``PRNGKey(7)`` split in two."""
+    import jax
+
+    target, draft = DENSE.draw(jax.random.PRNGKey(7), _qwen3_smoke())
+    assert (_digest(target), _digest(draft)) == ("37e39a95355ca094", "90e3b6afd6c121ab")
+
+
+def test_draft_head_is_tied_or_drawn_on_its_own_as_the_configuration_says():
+    import jax
+    import jax.numpy as jnp
+
+    cfg = _qwen3_smoke()
+    _, tied = DENSE.draw(jax.random.PRNGKey(3), cfg)
+    cfg["draft"]["tie_word_embeddings"] = False
+    _, untied = DENSE.draw(jax.random.PRNGKey(3), cfg)
+    assert tied["head"].dtype == untied["head"].dtype == jnp.bfloat16
+    assert tied["head"].shape == untied["head"].shape == (64, 512)
+    np.testing.assert_array_equal(np.asarray(tied["head"]), np.asarray(tied["embed"]).T)
+    # the other leaves are drawn as before; only the head is new
+    np.testing.assert_array_equal(np.asarray(untied["embed"]), np.asarray(tied["embed"]))
+    assert not np.array_equal(np.asarray(untied["head"]), np.asarray(untied["embed"]).T)
+    rms = float(np.sqrt(np.mean(np.asarray(untied["head"], np.float32) ** 2)))
+    assert rms == pytest.approx(1 / np.sqrt(64), rel=0.1)
+
+
+def test_program_config_passes_the_sizes_and_refuses_unknown_rope_scaling():
+    cfg = json.loads((ROOT / "chipbench/configs/dscoder-6.7b-spec.json").read_text())
+    for m in (cfg, cfg["draft"]):
+        m.pop("rope_scaling")
+    t = DENSE.program_config(cfg, "target")
+    assert (t.n_layers, t.d_model, t.n_heads, t.n_kv, t.d_ff, t.vocab, t.hd) == (
+        32, 4096, 32, 32, 11008, 32256, 128)
+    assert t.rope_theta == 100000.0 and not t.qk_norm and t.name == "dscoder-6.7b-spec"
+    d = DENSE.program_config(cfg, "draft")
+    assert (d.n_layers, d.d_model, d.n_heads, d.n_kv, d.d_ff, d.name) == (
+        24, 2048, 16, 16, 5504, "dscoder-6.7b-spec-draft")
+    cfg["rope_scaling"] = {"type": "yarn", "factor": 4.0}
+    with pytest.raises(SpecError):
+        DENSE.program_config(cfg, "target")
+
+
+def test_reference_rope_divides_positions_by_the_linear_factor():
+    ref = Bench().reference("dense_w4a8")
+    x = np.random.default_rng(2).standard_normal((7, 2, 8)).astype(np.float32)
+    half, theta = 4, 100000.0
+    inv = theta ** (-np.arange(half) / half)
+    for factor in (1.0, 4.0):
+        ang = (np.arange(7) / factor)[:, None, None] * inv
+        x1, x2 = x[..., :half], x[..., half:]
+        want = np.concatenate([x1 * np.cos(ang) - x2 * np.sin(ang),
+                               x2 * np.cos(ang) + x1 * np.sin(ang)], axis=-1)
+        np.testing.assert_allclose(np.asarray(ref._rope(x, theta, factor)), want, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(ref._rope(x, theta, 1.0)),
+                                  np.asarray(ref._rope(x, theta)))
+    assert ref._rope_factor({}) == 1.0
+    assert ref._rope_factor({"rope_scaling": {"type": "linear", "factor": 4.0}}) == 4.0
+    with pytest.raises(ValueError):
+        ref._rope_factor({"rope_scaling": {"rope_type": "yarn", "factor": 4.0}})
 
 
 def test_step_mfu_counts_prompt_and_output_tokens_of_the_window():
@@ -128,7 +224,7 @@ def test_step_mfu_counts_prompt_and_output_tokens_of_the_window():
     req = loadgen.Request(0, 0.0, [1] * 10, 3, 0.0, 1.0, 0, 0)
     rec = Record(req=req, due=0.0, times=[1.0, 2.0, 20.0], tokens=[1, 2, 3])
     ctx = _ctx(records=[rec])
-    want = flops.ops_for_tokens(m, list(range(10)) + [10, 11]) / 10.0 / 393e12 * 100
+    want = DENSE.model_ops(m, list(range(10)) + [10, 11]) / 10.0 / 393e12 * 100
     assert Bench().reader("step_mfu")(ctx) == pytest.approx(want)
 
 
@@ -148,7 +244,13 @@ def test_every_seed_gets_the_same_sizes_in_another_order():
     # greedy requests keep their places in the arrival order
     assert [q.greedy for q in a] == [q.greedy for q in b]
     assert 0 < sum(q.greedy for q in a) < len(a)
-    assert [len(q.prompt) for q in a] != [len(q.prompt) for q in b]
+    # and in the same order: the seed draws only the tokens and sampling seeds
+    for f in (lambda q: len(q.prompt), lambda q: q.max_tokens, lambda q: q.due_s):
+        assert list(map(f, a)) == list(map(f, b))
+    assert [q.prompt for q in a] != [q.prompt for q in b]
+    assert [q.seed for q in a] != [q.seed for q in b]
+    assert len(set(map(lambda q: q.max_tokens, a))) > 1
+    assert [q.max_tokens for q in a] != sorted(q.max_tokens for q in a)
     assert sorted(np.diff([q.due_s for q in a])) != [] and a[0].due_s == 0.0
     grid = set(t["prompt_len"]["grid"])
     assert {len(q.prompt) for q in a} <= grid
@@ -164,10 +266,47 @@ def test_warmup_covers_every_prompt_length_and_decoding_kind():
     assert kinds == {(n, g) for n in t["prompt_len"]["grid"] for g in (True, False)}
 
 
+TOY_SERVED = '''"""A throwaway served form: the dense pair with an expert axis added."""
+from pathlib import Path
+
+import jax.numpy as jnp
+
+from chipbench.spec import _load_module
+
+_dense = _load_module(Path(__file__).with_name("dense_w4a8_bvq.py"), "toy_dense")
+CALLS = []
+
+
+def draw(key, config):
+    CALLS.append("draw")
+    target, draft = _dense.draw(key, config)
+    target["layers"]["experts"] = jnp.zeros((config["num_hidden_layers"], 4, 8), jnp.int8)
+    return target, draft
+
+
+def program_config(config, role):
+    CALLS.append("program_config:" + role)
+    return _dense.program_config(config, role)
+
+
+def model_ops(config, positions):
+    CALLS.append("model_ops")
+    return 1e9 * len(list(positions))
+
+
+def smoke(config):
+    return _dense.smoke(config)
+'''
+
+
 def test_cells_files_and_readers_found_by_name_alone(tmp_path):
-    """A throwaway traffic mix, configuration and metric in a copy of the
-    benchmark directory are picked up with no edit to the harness."""
+    """A throwaway traffic mix, configuration, served form and metric in a
+    copy of the benchmark directory are picked up with no edit to the
+    harness: the harness draws and shapes the models through the served
+    form, and ``step_mfu`` counts through it."""
     import shutil
+
+    from chipbench import harness
 
     shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
@@ -197,6 +336,35 @@ def test_cells_files_and_readers_found_by_name_alone(tmp_path):
     assert bench.reference(bench.config("other-spec")["reference"]).logits_rows
     with pytest.raises(SpecError):
         bench.traffic("missing")
+
+    (tmp_path / "chipbench/served/toy_experts.py").write_text(TOY_SERVED)
+    toy_cfg = dict(DENSE.smoke(cfg), name="toy-spec", served="toy_experts")
+    (tmp_path / "chipbench/configs/toy-spec.json").write_text(json.dumps(toy_cfg))
+    nameless = {k: v for k, v in toy_cfg.items() if k != "served"}
+    nameless["name"] = "nameless-spec"
+    (tmp_path / "chipbench/configs/nameless-spec.json").write_text(json.dumps(nameless))
+    for name in ("toy-spec", "nameless-spec"):
+        spec["configs"].append({"name": name, "source": "x", "reduced": [], "why": "x",
+                                "file": f"chipbench/configs/{name}.json"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    bench = Bench(tmp_path)
+    with pytest.raises(SpecError):
+        bench.config("nameless-spec")
+    toy_cfg = bench.config("toy-spec")
+    toy = bench.served(toy_cfg["served"])
+    tparams, engine = harness.build(toy_cfg, dict(mix, engine=dict(max_batch=2, pool_tokens=64)),
+                                    3, toy)
+    assert toy.CALLS == ["draw", "program_config:target", "program_config:draft"]
+    assert tparams["layers"]["experts"].shape == (2, 4, 8)
+    assert engine.target.cfg.name == "toy-spec" and engine.draft.cfg.n_layers == 2
+    from chipbench.harness import Record
+
+    req = loadgen.Request(0, 0.0, [1] * 10, 2, 0.0, 1.0, 0, 0)
+    rec = Record(req=req, due=0.0, times=[1.0, 2.0], tokens=[1, 2])
+    ctx = _ctx(records=[rec], model=toy_cfg, served=toy)
+    want = 100.0 * 1e9 * 12 / 10.0 / 393e12
+    assert bench.reader("step_mfu")(ctx) == pytest.approx(want)
+    assert toy.CALLS[-1] == "model_ops"
 
 
 def test_benchmark_entries_resolve():
@@ -302,12 +470,15 @@ def test_sample_scores_streams_cut_at_the_close_and_skips_the_rest():
 def test_verdict_holds_each_number_to_its_limit():
     from chipbench import check
 
-    ck = {"served_gap_limit": 0.5, "min_tokens": 3}
+    ck = {"served_gap_limit": 0.5, "mean_gap_limit": 0.2, "min_tokens": 3}
     ok, checks = check.verdict([np.array([0.0, 0.1]), np.array([0.4])], 0, ck)
     assert ok and checks["served_gap"]["value"] == pytest.approx(0.4)
+    assert checks["mean_gap"] == {"value": pytest.approx(0.5 / 3), "limit": 0.2}
     assert checks["sampled_tokens"] == {"value": 3, "limit": 3}
     assert not check.verdict([np.array([0.0, 0.6, 0.0])], 0, ck)[0]  # a wide gap
+    assert not check.verdict([np.array([0.3, 0.3, 0.3])], 0, ck)[0]  # every position off
     assert not check.verdict([np.array([0.0, 0.1])], 0, ck)[0]  # too few tokens
     assert not check.verdict([np.array([0.0, 0.1, 0.0])], 1, ck)[0]  # a broken stream
     ok, checks = check.verdict([], 0, ck)
     assert not ok and checks["served_gap"]["value"] is None
+    assert checks["mean_gap"]["value"] is None
