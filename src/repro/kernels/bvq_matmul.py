@@ -10,7 +10,8 @@ blocks are independent (intra-/inter-layer parallelism).
 Grid: (N blocks, M tiles), N outer.  At the first M tile of a block the
 kernel decodes the block's ``(K, bc)`` tile into the scratch; every M tile
 then takes one dot against it.  K is kept whole per step (draft-scale
-layers).
+layers); the M tile is chosen from K and the call's M (``tile_rows``) so
+that the whole step fits ``VMEM_BUDGET``.
 
 The decode is a lane gather, which Mosaic lowers to ``tpu.dynamic_gather``:
 the codebook is handed in as a table of ``bc``-lane rows, row ``t*H + h``
@@ -29,12 +30,15 @@ f32: 3 x 8 mantissa bits cover f32's 24), each part is dotted with the tile
 with f32 accumulation, and the ``(bm, bc)`` sum is multiplied by the block's
 f32 scale.  Every product is exact and every sum is f32.
 
-VMEM at the served draft shapes (v 8, C 256, bc 128, K <= 3072;
-``vmem_bytes``): at K 3072 the decoded tile (K x 128 bf16) takes 768 KiB,
-the index block (K/8 x 128 int32) 192 KiB and the codebook table 8 KiB,
-each double-buffered, and an M tile of x 48 KiB for a draft step (16 rows)
-and 3 MiB for a prefill tile (256 f32 rows), double-buffered: 7.4 MiB at
-most, under the 16 MiB a kernel may take by default on a v5e.
+VMEM at the served draft shapes (v 8, C 256, bc 128; ``vmem_bytes``): at
+K 3072 the decoded tile (K x 128 bf16) takes 768 KiB, the index block (K/8
+x 128 int32) 192 KiB and the codebook table 8 KiB, each double-buffered,
+and an M tile of x 48 KiB for a draft step (16 rows) and 3 MiB for a
+prefill tile (256 f32 rows), double-buffered: 7.4 MiB at most.  At K 5504
+(deepseek-coder-1.3b's down projection) a 256-row tile of x alone takes
+11 MiB double-buffered, so a prefill runs 224-row tiles (11.7 MiB) and a
+draft step of 8-16 rows takes 2.4-2.7 MiB.  The budget stays under the
+16 MiB a kernel may take by default on a v5e.
 """
 from __future__ import annotations
 
@@ -48,9 +52,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bvq import BVQWeight
 
-__all__ = ["bvq_matmul_pallas", "vmem_bytes"]
+__all__ = ["bvq_matmul_pallas", "vmem_bytes", "tile_rows", "VMEM_BUDGET"]
 
-_BM = 256  # token rows per M tile
+_BM = 256  # most token rows per M tile
+# VMEM the kernel may plan for: well under the 16 MiB a kernel may take by
+# default on a v5e, so a compile never meets the limit
+VMEM_BUDGET = 12 * 2**20
 _CHUNK_ROWS = 64  # index rows decoded per loop step (8 int32 vregs at bc 128)
 
 
@@ -117,6 +124,16 @@ def vmem_bytes(k: int, bc: int, v: int, c: int, bm: int = _BM) -> int:
     return k * bc * 2 + 2 * per_step
 
 
+def tile_rows(m: int, k: int, bc: int, v: int, c: int) -> int:
+    """The M tile for ``m`` rows against a (K, N) weight: the largest
+    multiple of 8, at most ``_BM`` and at most ``m`` rounded up to 8, whose
+    ``vmem_bytes`` fits ``VMEM_BUDGET``; 0 where not even 8 rows fit."""
+    for bm in range(min(_BM, -(-m // 8) * 8), 0, -8):
+        if vmem_bytes(k, bc, v, c, bm) <= VMEM_BUDGET:
+            return bm
+    return 0
+
+
 def _chunk_rows(rows: int) -> int:
     for r in (_CHUNK_ROWS, 32, 16, 8):
         if rows % r == 0:
@@ -124,15 +141,15 @@ def _chunk_rows(rows: int) -> int:
     return rows
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "interpret", "out_dtype"))
+@functools.partial(jax.jit, static_argnames=("interpret", "out_dtype"))
 def bvq_matmul_pallas(
     x: jnp.ndarray,  # (M, K)
     bw: BVQWeight,
-    bm: int = _BM,
     interpret: Optional[bool] = None,
     out_dtype=jnp.float32,
 ) -> jnp.ndarray:
-    """y = x @ reconstruct(bw); each block's tile decoded once per call."""
+    """y = x @ reconstruct(bw); each block's tile decoded once per call,
+    in M tiles of ``tile_rows`` rows."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     m, k = x.shape
@@ -144,7 +161,9 @@ def bvq_matmul_pallas(
     parts = 1 if x.dtype == jnp.bfloat16 else 3
     # K in component-major order, to match the decoded tile's rows
     xp = x.reshape(m, rows, v).swapaxes(1, 2).reshape(m, k)
-    bm = min(bm, -(-m // 8) * 8)
+    bm = tile_rows(m, k, bc, v, bw.codebooks.shape[-2])
+    if bm == 0:
+        raise ValueError(f"no M tile of a ({k}, {n}) BVQ weight fits in VMEM")
     mp = -(-m // bm) * bm
     if mp != m:
         xp = jnp.pad(xp, ((0, mp - m), (0, 0)))

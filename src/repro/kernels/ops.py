@@ -16,7 +16,7 @@ from repro.core import quantization as q
 from repro.core import rotation as rot
 from repro.core.bvq import BVQWeight
 from repro.kernels import ref
-from repro.kernels.bvq_matmul import bvq_matmul_pallas, vmem_bytes
+from repro.kernels.bvq_matmul import bvq_matmul_pallas, tile_rows
 from repro.kernels.fwht import block_rotate_pallas
 from repro.kernels.w4a8_matmul import w4a8_matmul_pallas
 
@@ -27,10 +27,6 @@ __all__ = [
     "bvq_linear",
     "resolve_bvq_impl",
 ]
-
-# the Pallas BVQ kernel's VMEM budget: well under the 16 MiB a kernel may
-# take by default on a v5e, so a compile never meets the limit
-_BVQ_VMEM_BUDGET = 12 * 2**20
 
 
 def lru_rotate(
@@ -110,10 +106,12 @@ def resolve_bvq_impl(
 
     ``use_pallas`` of ``None`` selects by what the program observes: the
     Pallas kernel on a TPU at a shape it compiles for (128-lane blocks, K
-    a whole number of 16-row index tiles, the block's tile within the
-    kernel's VMEM budget), the jnp reference everywhere else.  An explicit
-    ``True``/``False`` always wins (``True`` runs the kernel interpreted
-    off the TPU).  ``backend`` overrides ``jax.default_backend()``."""
+    a whole number of 16-row index tiles, and an M tile of at least 8 rows
+    within the kernel's VMEM budget, by ``tile_rows``, which the kernel
+    itself takes for every call), the jnp reference everywhere else.  An
+    explicit ``True``/``False`` always wins (``True`` runs the kernel
+    interpreted off the TPU).  ``backend`` overrides
+    ``jax.default_backend()``."""
     if use_pallas is not None:
         return "pallas" if use_pallas else "reference"
     if backend is None:
@@ -124,7 +122,7 @@ def resolve_bvq_impl(
     fits = (
         bc % 128 == 0
         and k % (16 * v) == 0
-        and vmem_bytes(k, bc, v, c) <= _BVQ_VMEM_BUDGET
+        and tile_rows(8, k, bc, v, c) > 0
     )
     return "pallas" if backend == "tpu" and fits else "reference"
 

@@ -33,6 +33,7 @@ class ModelConfig:
     head_dim: Optional[int] = None  # default d_model // n_heads
     qk_norm: bool = False  # qwen3
     rope_theta: float = 10000.0
+    rope_scaling: float = 1.0  # linear RoPE scaling: positions divided by it
     # --- FFN
     act: str = "swiglu"  # swiglu | squared_relu | gelu
     # --- MoE

@@ -94,12 +94,20 @@ def rmsnorm(params: Params, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (B, S, H, hd); positions: (B, S) int32."""
+def rope(
+    x: jnp.ndarray, positions: jnp.ndarray, theta: float, scaling: float = 1.0
+) -> jnp.ndarray:
+    """x: (B, S, H, hd); positions: (B, S) int32.  ``scaling`` is the
+    linear RoPE factor: each position is divided by it before the angles
+    are taken, the frequencies unchanged (Hugging Face's ``linear``
+    ``rope_scaling``).  A factor of 1 traces no division."""
     hd = x.shape[-1]
     half = hd // 2
     freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, half)
+    pos = positions[..., None].astype(jnp.float32)  # (B, S, 1)
+    if scaling != 1.0:
+        pos = pos / scaling
+    ang = pos * freqs  # (B, S, half)
     cos = jnp.cos(ang)[..., None, :]  # (B, S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
@@ -554,7 +562,7 @@ def attention_apply(
         k, v = kv_override  # already (B, T, store, hd)
         new_cache = cache
         if use_rope and positions is not None:
-            q = rope(q, positions, cfg.rope_theta)
+            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         out = flash_attention(q, k, v, causal=False)
     else:
         k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
@@ -567,8 +575,8 @@ def attention_apply(
             else:
                 positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
         if use_rope:
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
         k = _repeat_kv(k, store)
         v = _repeat_kv(v, store)
         if isinstance(cache, PagedCache):

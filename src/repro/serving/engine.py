@@ -921,7 +921,14 @@ class Engine:
             "Pallas kernel or the jnp reference",
             ("model", "impl"),
         )
+        # each model's linear RoPE factor (ModelConfig.rope_scaling; 1 = none)
+        rope_factor = m.gauge(
+            "rope_scaling_factor",
+            "Linear RoPE scaling factor each model divides its positions by",
+            ("model",),
+        )
         for role, model in (("target", self.target), ("draft", self.draft)):
+            rope_factor.labels(model=role).set(model.cfg.rope_scaling)
             if model.mode == "bvq" and model.params is not None:
                 counts = qlm.bvq_impl_counts(model.params, model.use_pallas)
                 for impl, n in counts.items():

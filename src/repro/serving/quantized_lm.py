@@ -264,8 +264,8 @@ def apply_quantized_lm(
         if cfg.qk_norm:
             q_ = L._qk_head_norm(q_, p["qk_extra"]["q_norm"])
             k_ = L._qk_head_norm(k_, p["qk_extra"]["k_norm"])
-        q_ = L.rope(q_, positions, cfg.rope_theta)
-        k_ = L.rope(k_, positions, cfg.rope_theta)
+        q_ = L.rope(q_, positions, cfg.rope_theta, cfg.rope_scaling)
+        k_ = L.rope(k_, positions, cfg.rope_theta, cfg.rope_scaling)
         k_ = L._repeat_kv(k_, store)
         v_ = L._repeat_kv(v_, store)
         if pctx is not None:
@@ -414,8 +414,8 @@ def apply_bvq_lm(
         if cfg.qk_norm:
             q_ = L._qk_head_norm(q_, p["qk_extra"]["q_norm"])
             k_ = L._qk_head_norm(k_, p["qk_extra"]["k_norm"])
-        q_ = L.rope(q_, positions, cfg.rope_theta)
-        k_ = L.rope(k_, positions, cfg.rope_theta)
+        q_ = L.rope(q_, positions, cfg.rope_theta, cfg.rope_scaling)
+        k_ = L.rope(k_, positions, cfg.rope_theta, cfg.rope_scaling)
         k_ = L._repeat_kv(k_, store)
         v_ = L._repeat_kv(v_, store)
         if pctx is not None:
@@ -688,8 +688,8 @@ def apply_quantized_moe_lm(
         q_ = _qlinear(z, p["wq"], use_pallas).reshape(b, s, h, hd).astype(xc.dtype)
         k_ = _qlinear(z, p["wk"], use_pallas).reshape(b, s, kv, hd).astype(xc.dtype)
         v_ = _qlinear(z, p["wv"], use_pallas).reshape(b, s, kv, hd).astype(xc.dtype)
-        q_ = L.rope(q_, positions, cfg.rope_theta)
-        k_ = L.rope(k_, positions, cfg.rope_theta)
+        q_ = L.rope(q_, positions, cfg.rope_theta, cfg.rope_scaling)
+        k_ = L.rope(k_, positions, cfg.rope_theta, cfg.rope_scaling)
         k_ = L._repeat_kv(k_, store)
         v_ = L._repeat_kv(v_, store)
         if kvs_ is not None and "k_scale" in kvs_:

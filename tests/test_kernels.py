@@ -7,7 +7,7 @@ from _optional import given, settings, st
 
 from repro.core import bvq, quantization as q, rotation as rot
 from repro.kernels import ops, ref
-from repro.kernels.bvq_matmul import bvq_matmul_pallas
+from repro.kernels.bvq_matmul import bvq_matmul_pallas, tile_rows
 from repro.kernels.fwht import block_rotate_pallas
 from repro.kernels.w4a8_matmul import w4a8_matmul_pallas
 
@@ -180,6 +180,44 @@ def test_bvq_matches_oracle(mk, nn, vec, cbs, bc, layers):
     np.testing.assert_allclose(
         np.asarray(got), want, rtol=1e-5, atol=min(1e-4, 1e-5 * np.abs(want).max())
     )
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("m,bm", [(16, 16), (256, 224)])
+def test_bvq_tiles_rows_to_fit_k_5504(m, bm, dtype):
+    """deepseek-coder-1.3b's down projection (K 5504, N 2048): a draft step
+    keeps its 16 rows in one tile, and a prefill's rows go in tiles of 224,
+    the largest that fits the VMEM budget beside K 5504."""
+    assert tile_rows(m, 5504, 128, 8, 256) == bm
+    rng = np.random.RandomState(11)
+    bw = _random_bvq(rng, 5504, 2048, 8, 256, 128)
+    x = jnp.asarray(rng.randn(m, 5504).astype(np.float32)).astype(dtype)
+    got = np.asarray(bvq_matmul_pallas(x, bw))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref.bvq_matmul_ref2(x, bw))
+    np.testing.assert_allclose(
+        got, want, rtol=1e-5, atol=min(1e-4, 1e-5 * np.abs(want).max())
+    )
+
+
+@pytest.mark.parametrize("config", ["qwen3-8b-spec", "dscoder-6.7b-spec"])
+def test_every_served_draft_linear_takes_the_kernel_on_a_tpu(config):
+    """Each BVQ linear of both benchmark drafts, at its published shape,
+    resolves to the Pallas kernel on a TPU (K 5504 included)."""
+    from chipbench.spec import Bench
+
+    bench = Bench()
+    cfg = bench.config(config)
+    _, draft = jax.eval_shape(
+        lambda k: bench.served(cfg["served"]).draw(k, cfg), jax.random.PRNGKey(0)
+    )
+    linears = [w for w in jax.tree.leaves(
+        draft, is_leaf=lambda t: isinstance(t, bvq.BVQWeight)
+    ) if isinstance(w, bvq.BVQWeight)]
+    assert len(linears) == 7
+    assert {w.shape[0] for w in linears} >= {cfg["draft"]["intermediate_size"]}
+    for w in linears:
+        assert ops.resolve_bvq_impl(w, backend="tpu") == "pallas", w.shape
 
 
 def test_resolve_bvq_impl_branches():

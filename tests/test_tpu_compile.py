@@ -3,12 +3,13 @@
 Nothing runs: each test lowers a Pallas kernel at the paper target's
 widths (llama2-7b: 32 kv heads of 128, 16-slot pages; the W4A8 down/up
 projection shapes), or at the served BVQ drafts' (Qwen3-0.6B: K 1024 and
-3072 over 128-column blocks of 256-entry codebooks; llama-68m: K 768), for
-a v5e that is described, not attached, and asserts
-that the compiled program calls the kernel (``tpu_custom_call``).  What
-interpret-mode tests cannot see — a block shape Mosaic refuses, an
-operand type the MXU lacks — fails here.  The topology is described inside
-a fixture, never at import, so every test worker collects the same tests.
+3072 over 128-column blocks of 256-entry codebooks; llama-68m: K 768;
+deepseek-coder-1.3b: K 5504), for a v5e that is described, not attached,
+and asserts that the compiled program calls the kernel
+(``tpu_custom_call``).  What interpret-mode tests cannot see — a block
+shape Mosaic refuses, an operand type the MXU lacks — fails here.  The
+topology is described inside a fixture, never at import, so every test
+worker collects the same tests.
 """
 import os
 
@@ -117,3 +118,10 @@ def test_bvq_matmul_compiles_for_v5e(one_chip, m, k, n):
         s((m, k), jnp.bfloat16), bw,
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m", [16, 256])
+def test_bvq_matmul_compiles_for_v5e_at_k_5504(one_chip, m):
+    """deepseek-coder-1.3b's down projection (K 5504, N 2048): a draft step,
+    and a prefill whose rows go in 224-row tiles beside K 5504."""
+    test_bvq_matmul_compiles_for_v5e(one_chip, m, 5504, 2048)
