@@ -2,62 +2,69 @@
 
 The continuous-batching runtime (serving/engine.serve_batch) keeps every
 request's KV in a shared block-granular pool (serving/paged_cache.py) that
-is now DEVICE-RESIDENT: the model forward scatters new tokens straight into
-pool pages and this kernel attends in place through the page table.  The
-grid's innermost dimension walks a request's page table and the BlockSpec
-index_map reads the page id from a scalar-prefetched table, so each
-(request, kv-head) pair streams exactly its own pages pool->VMEM once and
-runs online softmax in VREGs — decode attention over the paged pool with
-zero gather materialization (the same trick the dense int8 kernel in
-decode_attn.py plays on a contiguous cache, plus scalar-prefetch
-indirection).
+is DEVICE-RESIDENT: the model forward scatters new tokens straight into
+pool pages and this kernel attends in place through the page table, with
+zero gather materialization.
 
 The kernel generalizes to the speculative VERIFY window: q may carry W > 1
 query tokens per request (the round's [last_tok, drafts...] span), causally
 masked inside the window — query w attends to absolute positions
 <= length - W + w.
 
-Layout (one grid step = one (request, kv-head) pair x one page):
-  page_table (B, max_pages) int32  — scalar-prefetched; unused slots must
-                                     hold any in-range id (masked by length)
+The walk.  One grid step is one request (batch row).  It reads the row's
+length and page-table row from scalar-prefetched SMEM and walks ONLY the
+row's live pages, ``cdiv(length, page_size)`` of them, in blocks of
+``pages_per_block`` pages.  A page of the head-major pool,
+``(KVS, page_size, hd)``, is one contiguous run of bytes, so each page of
+a block is one DMA that brings every KV head at once; the blocks are
+double-buffered (the next block's copies are in flight while the current
+one is attended).  Within a block every KV head runs one online-softmax
+update over the block's ``pages_per_block * page_size`` tokens.  The last
+block of a row is ragged: its pages past the row's live pages are neither
+copied nor read as data — their VMEM slots keep whatever they held, and the
+length mask (scores to -1e30, V rows to 0) makes that content irrelevant,
+NaN included.  ``pages_per_block`` comes from the shapes alone
+(:func:`pages_per_block`): as many pages as cover ``BLOCK_TOKENS`` tokens,
+no more than the table holds, and no more than fit ``BUFFER_BUDGET``.
+
+Layout:
+  page_table (B, max_pages) int32  — scalar-prefetched; slots past a row's
+                                     live pages are never read
   lengths    (B,)           int32  — valid tokens per request INCLUDING the
                                      W window tokens just written
   q          (B, KVS, G, hd)       — single decode token (W = 1), or
              (B, W, KVS, G, hd)    — multi-token verify window
-  k_pool     (P, KVS, page_size, hd)   — head-major pages
+  k_pool     (P, KVS, page_size, hd)   — head-major pages, left in HBM
   v_pool     (P, KVS, page_size, hd)
   k_scale    (P, KVS, page_size, 1) f32, optional — per-slot-per-head
   v_scale    (P, KVS, page_size, 1) f32, optional   dequant scales
   out        same shape as q, f32
 
-The pools are head-major so one grid step's block, ``(1, 1, page_size,
-hd)``, has the page's slots and the head dim as its two minor dimensions:
-Mosaic requires those to be multiples of the (8, 128) tile or the whole
-array dimension, which a token-major ``(P, page_size, KVS, hd)`` pool
-cannot give (its block would carry one of KVS heads as the second-minor
-dimension).
-
 Compressed pools (``kv_quant="int8"``): pass int8 k/v pools plus the scale
-pools and the kernel dequantizes INSIDE the page loop — each page's int8
-bytes stream pool->VMEM compressed (≈4x less traffic than f32) and expand
-to f32 only in registers, right before the QK^T dot.  Both the 4-D decode
-and 5-D verify-window paths share the epilogue.
+pools.  The block's int8 bytes cross HBM->VMEM as they are and expand to
+f32 right before the dots.  The per-slot scales of each row's table are
+gathered outside the kernel into lane-dense ``(KVS, tokens)`` tiles, one
+per block (a one-wide minor dim cannot be sliced for a DMA), and multiply
+their slots' score columns (K) and probability columns (V): the products
+of dequantizing the rows first, up to f32 rounding.  The dots take f32
+operands and accumulate in f32 (q·scale, probabilities, outputs) in every
+variant.
 
 TPU note: compiled (``interpret=False``, the default off the CPU), the
-block's minor dims must be multiples of (8, 128) or whole array dims:
+pool's minor dims must be multiples of (8, 128) or whole array dims:
 page_size a multiple of 8, hd a multiple of 128 or the whole head dim.
 Every variant (decode, verify, int8 scales, tree mask) compiles for a v5e
-at KVS 32, hd 128 and page_size 16 or 32; tests/test_tpu_compile.py keeps
-the page-16 compiles.  Interpret mode (CPU tests) takes any shape.
+at the served models' shapes; tests/test_tpu_compile.py keeps those
+compiles.  Interpret mode (CPU tests) takes any shape.
 
 Invariants (the contract with the serving engine):
 
-* **Page-table lifetime stability** — the scalar-prefetched table is read
-  fresh every call but the engine uploads each request's row exactly once
-  per lifetime (pages are backed at admission and never move); unused table
-  slots must hold ANY in-range page id (the engine points them at its
-  scratch page) because the grid dereferences every slot and relies on the
-  length mask, not the table, for validity.
+* **Only live table slots are read** — a row's first
+  ``cdiv(length, page_size)`` table slots must hold its pages (the engine
+  backs them at admission and never moves them); every later slot is
+  ignored, whatever page id it holds.
+* **Length 0** — a row of length 0 copies nothing and writes zeros, which
+  the caller ignores.
 * **Causal padding** — the fused PAR path always calls with the engine-wide
   fixed window W = max_dl + 1 and per-row lengths counting exactly the
   tokens written; rows whose real window is shorter arrive zero-padded.
@@ -66,10 +73,9 @@ Invariants (the contract with the serving engine):
   bitwise independent of the padding, which is what makes fixed-width
   compilation safe.
 * **Role-masked rows** — rows excluded from a fused dispatch arrive with
-  an all-scratch table row and length 0: every kv position masks out, the
-  softmax degenerates to uniform over -1e30 scores, and the finite garbage
-  output is ignored by the caller.  The kernel itself never needs a role
-  input.
+  an all-scratch table row and a length that counts only the padded window;
+  the finite garbage they produce is ignored by the caller.  The kernel
+  itself never needs a role input.
 """
 from __future__ import annotations
 
@@ -82,112 +88,155 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_decode_attention_pallas"]
+__all__ = ["paged_decode_attention_pallas", "pages_per_block"]
+
+BLOCK_TOKENS = 256  # tokens one block of pages covers at most
+BUFFER_BUDGET = 8 * 2**20  # VMEM for the double-buffered K and V blocks
 
 
-def _attend_page(k, v, len_ref, q_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                 n_pages: int, page_size: int, window: int, group: int,
-                 scale: float, tm=None):
-    """One online-softmax step over one (already dequantized, f32) page.
+def pages_per_block(page_size: int, kvs: int, hd: int, dtype,
+                    max_pages: int) -> int:
+    """Pages the kernel copies and attends per block: enough to cover
+    ``BLOCK_TOKENS`` tokens, at most ``max_pages``, and as many as let two
+    buffers each of K and V fit ``BUFFER_BUDGET``."""
+    page = kvs * page_size * hd * jnp.dtype(dtype).itemsize
+    ppb = max(1, min(BLOCK_TOKENS // page_size, max_pages))
+    while ppb > 1 and 2 * 2 * ppb * page > BUFFER_BUDGET:
+        ppb -= 1
+    return ppb
 
-    ``tm`` (optional, (W, W) f32 for this batch row) replaces the causal
-    window mask with an arbitrary intra-window visibility relation — the
-    speculation-tree ancestor mask.  ``tm=None`` keeps the historical
-    causal path bit-exact."""
+
+def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, *refs, ppb: int,
+            page_size: int, max_pages: int, window: int, group: int,
+            scale: float, quantized: bool, treed: bool):
+    """One batch row: walk its live pages block by block.
+
+    ``refs``: [k_scale, v_scale] (int8) and [tree_mask] (tree) inputs, the
+    output, then the scratch — m, l, acc, the K and V block buffers and the
+    DMA semaphores (one per buffer slot)."""
+    refs = list(refs)
+    ks_ref, vs_ref = (refs.pop(0), refs.pop(0)) if quantized else (None, None)
+    tm_ref = refs.pop(0) if treed else None
+    o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf, sem = refs
+    _, kvs, rows, hd = q_ref.shape
+    tokens = ppb * page_size
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    length = len_ref[b]
+    n_live = jnp.minimum(pl.cdiv(length, page_size), max_pages)
+    n_blocks = pl.cdiv(n_live, ppb)
+    pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def copy_block(blk, slot, wait: bool):
+        # one DMA per live page and pool: (KVS, page_size, hd), every head
+        for j in range(ppb):
+            p = blk * ppb + j
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # (W*G, hd)
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (W*G, page_size)
-    # mask token slots beyond each query's causal horizon: row (w, g) at
-    # absolute position length - W + w sees kv positions <= itself.  This
-    # also covers page-table slots past the request's page count (every
-    # slot is masked) and reduces to `pos < length` when W == 1.
-    pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    w = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) // group
-    if tm is None:
-        scores = jnp.where(pos <= len_ref[b] - window + w, scores, -1e30)
-    else:
+            @pl.when(p < n_live)
+            def _():
+                page = pt_ref[b * max_pages + p]
+                for src, dst in pairs:
+                    c = pltpu.make_async_copy(
+                        src.at[page], dst.at[slot, j], sem.at[slot]
+                    )
+                    if wait:
+                        c.wait()
+                    else:
+                        c.start()
+
+    if treed:
         # Tree mask, gather-free (TPU wants matmuls, not dynamic indexing):
-        # expand tm to query rows with a row-onehot (W*G, W), then project
-        # onto this page's kv slots with a col-onehot (W, page_size) built
-        # from each slot's window-relative index.  Committed-prefix slots
-        # (pos < length - W) stay visible to every query.
-        r_iota = jax.lax.broadcasted_iota(jnp.int32, (window * group, window), 0)
-        j_iota = jax.lax.broadcasted_iota(jnp.int32, (window * group, window), 1)
+        # expand tm to query rows with a row-onehot (W*G, W); each block
+        # projects it onto its kv slots with a col-onehot (W, tokens).
+        r_iota = jax.lax.broadcasted_iota(jnp.int32, (rows, window), 0)
+        j_iota = jax.lax.broadcasted_iota(jnp.int32, (rows, window), 1)
         row_onehot = (r_iota // group == j_iota).astype(jnp.float32)
         mask_rows = jax.lax.dot_general(
-            row_onehot, tm, (((1,), (0,)), ((), ())),
+            row_onehot, tm_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (W*G, W)
-        slot_iota = jax.lax.broadcasted_iota(jnp.int32, (window, page_size), 0)
-        col_iota = jax.lax.broadcasted_iota(jnp.int32, (window, page_size), 1)
-        rel = p * page_size + col_iota - (len_ref[b] - window)
+
+    def visible_in(base):
+        """(W*G, tokens) bool: which of the block's kv slots each query row
+        sees.  Row (w, g) at absolute position length - W + w sees kv
+        positions <= itself (reducing to `pos < length` when W == 1); the
+        tree mask instead shows each query the committed prefix
+        (pos < length - W) and its window ancestors."""
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 1)
+        if not treed:
+            w = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 0) // group
+            return pos <= length - window + w
+        slot_iota = jax.lax.broadcasted_iota(jnp.int32, (window, tokens), 0)
+        col_iota = jax.lax.broadcasted_iota(jnp.int32, (window, tokens), 1)
+        rel = base + col_iota - (length - window)
         col_onehot = (rel == slot_iota).astype(jnp.float32)
         win_vis = jax.lax.dot_general(
             mask_rows, col_onehot, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (W*G, page_size)
-        visible = (pos < len_ref[b] - window) | (win_vis > 0.5)
-        scores = jnp.where(visible, scores, -1e30)
+        )  # (W*G, tokens)
+        return (pos < length - window) | (win_vis > 0.5)
 
-    m_prev = m_ref[...]  # (W*G, 1)
-    m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-    prob = jnp.exp(scores - m_new)  # (W*G, page_size)
-    corr = jnp.exp(m_prev - m_new)  # (W*G, 1)
-    l_ref[...] = l_ref[...] * corr + prob.sum(axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        prob, v,
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )  # (W*G, hd)
-    acc_ref[...] = acc_ref[...] * corr + pv
-    m_ref[...] = m_new
+    def attend_block(blk, slot):
+        base = blk * tokens
+        visible = visible_in(base)
+        # V rows past the length are zeroed: stale or never-copied slots of
+        # a ragged block must not reach the output, not even as 0 * NaN
+        live = base + jax.lax.broadcasted_iota(jnp.int32, (tokens, 1), 0) < length
+        live_cols = base + jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1) < length
 
-    @pl.when(p == n_pages - 1)
-    def _epilogue():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
-            o_ref.dtype
-        )
+        def head(h, carry):
+            # (ppb, page_size, hd) -> (tokens, hd), in f32
+            k = k_buf[slot, :, h].astype(jnp.float32).reshape(tokens, hd)
+            v = v_buf[slot, :, h].astype(jnp.float32).reshape(tokens, hd)
+            v = jnp.where(live, v, 0.0)
+            q = q_ref[0, h].astype(jnp.float32) * scale  # (W*G, hd)
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (W*G, tokens)
+            if quantized:
+                # a slot's scale multiplies its whole K (V) row, so it
+                # multiplies that slot's score (probability) column
+                scores = scores * ks_ref[0, blk, pl.ds(h, 1), :]
+            scores = jnp.where(visible, scores, -1e30)
+            m_prev = m_ref[h]  # (W*G, 1)
+            m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+            prob = jnp.exp(scores - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + prob.sum(axis=-1, keepdims=True)
+            if quantized:
+                v_rows = jnp.where(live_cols, vs_ref[0, blk, pl.ds(h, 1), :], 0.0)
+                prob = prob * v_rows
+            pv = jax.lax.dot_general(
+                prob, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (W*G, hd)
+            acc_ref[h] = acc_ref[h] * corr + pv
+            m_ref[h] = m_new
+            return carry
 
+        jax.lax.fori_loop(0, kvs, head, 0)
 
-def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, **kw):
-    k = k_ref[0, 0].astype(jnp.float32)  # (page_size, hd)
-    v = v_ref[0, 0].astype(jnp.float32)
-    _attend_page(k, v, len_ref, q_ref, o_ref, m_ref, l_ref, acc_ref, **kw)
+    m_ref[...] = jnp.full_like(m_ref, -1e30)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    @pl.when(n_blocks > 0)
+    def _():
+        copy_block(0, 0, wait=False)
 
-def _kernel_quant(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, m_ref, l_ref, acc_ref, **kw):
-    """int8 pools: dequantize this page in VREGs (per-slot scale broadcast
-    over hd) right before the dots — the page crossed HBM->VMEM as int8."""
-    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
-    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
-    _attend_page(k, v, len_ref, q_ref, o_ref, m_ref, l_ref, acc_ref, **kw)
+    def block(blk, carry):
+        slot = blk % 2
 
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            copy_block(blk + 1, 1 - slot, wait=False)
 
-def _kernel_tree(pt_ref, len_ref, q_ref, k_ref, v_ref, tm_ref, o_ref,
-                 m_ref, l_ref, acc_ref, **kw):
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    _attend_page(k, v, len_ref, q_ref, o_ref, m_ref, l_ref, acc_ref,
-                 tm=tm_ref[0], **kw)
+        copy_block(blk, slot, wait=True)
+        attend_block(blk, slot)
+        return carry
 
-
-def _kernel_quant_tree(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                       tm_ref, o_ref, m_ref, l_ref, acc_ref, **kw):
-    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
-    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
-    _attend_page(k, v, len_ref, q_ref, o_ref, m_ref, l_ref, acc_ref,
-                 tm=tm_ref[0], **kw)
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -209,7 +258,7 @@ def paged_decode_attention_pallas(
     counts the window's tokens too — the dense verify-path convention).
 
     With ``k_scale``/``v_scale`` (both or neither) the pools are int8 and
-    each page is dequantized inside the kernel (``value * scale`` per slot
+    each block is dequantized inside the kernel (``value * scale`` per slot
     per kv-head) — the compressed-at-rest path.
 
     ``tree_mask`` (5-D q only) replaces the intra-window causal mask with a
@@ -231,59 +280,67 @@ def paged_decode_attention_pallas(
     assert (pool_kvs, pool_hd) == (kvs, hd), (k_pool.shape, q.shape)
     quantized = k_scale is not None
     assert quantized == (v_scale is not None), "pass both scales or neither"
-    n_pages = page_table.shape[1]
+    max_pages = page_table.shape[1]
     rows = w * g
-    scale = 1.0 / math.sqrt(hd)
-    grid = (b, kvs, n_pages)
-    page_spec = lambda width: pl.BlockSpec(
-        (1, 1, page_size, width), lambda i, j, p, pt, ln: (pt[i, p], j, 0, 0)
-    )
-    in_specs = [
-        pl.BlockSpec((1, 1, rows, hd), lambda i, j, p, pt, ln: (i, j, 0, 0)),
-        page_spec(hd),
-        page_spec(hd),
-    ]
+    ppb = pages_per_block(page_size, kvs, hd, k_pool.dtype, max_pages)
+    row_spec = pl.BlockSpec((1, kvs, rows, hd), lambda i, pt, ln: (i, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [row_spec, hbm, hbm]
     inputs = [qk, k_pool, v_pool]
+    buffers = [
+        pltpu.VMEM((2, ppb, kvs, page_size, hd), k_pool.dtype),
+        pltpu.VMEM((2, ppb, kvs, page_size, hd), v_pool.dtype),
+    ]
     if quantized:
-        in_specs += [page_spec(1), page_spec(1)]
-        inputs += [k_scale, v_scale]
+        # the row's per-slot scales, gathered through its table and laid
+        # out lane-dense, one (KVS, tokens) tile per block:
+        # (B, blocks, KVS, ppb * page_size)
+        n_blocks = -(-max_pages // ppb)
+        table = jnp.pad(page_table, ((0, 0), (0, n_blocks * ppb - max_pages)))
+
+        def by_block(sc):
+            sc = sc[table].astype(jnp.float32)  # (B, n_blocks*ppb, KVS, ps, 1)
+            sc = sc.reshape(b, n_blocks, ppb, kvs, page_size)
+            return sc.transpose(0, 1, 3, 2, 4).reshape(
+                b, n_blocks, kvs, ppb * page_size
+            )
+
+        in_specs += [pl.BlockSpec((1, n_blocks, kvs, ppb * page_size),
+                                  lambda i, pt, ln: (i, 0, 0, 0))] * 2
+        inputs += [by_block(k_scale), by_block(v_scale)]
     treed = tree_mask is not None
     if treed:
         assert windowed, "tree_mask requires a 5-D window q"
         assert tree_mask.shape == (b, w, w), (tree_mask.shape, (b, w, w))
-        in_specs += [
-            pl.BlockSpec((1, w, w), lambda i, j, p, pt, ln: (i, 0, 0))
-        ]
+        in_specs += [pl.BlockSpec((1, w, w), lambda i, pt, ln: (i, 0, 0))]
         inputs += [tree_mask.astype(jnp.float32)]
-    kernels = {
-        (False, False): _kernel,
-        (True, False): _kernel_quant,
-        (False, True): _kernel_tree,
-        (True, True): _kernel_quant_tree,
-    }
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # page_table, lengths
-        grid=grid,
+        num_scalar_prefetch=2,  # page_table (flattened), lengths
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, rows, hd), lambda i, j, p, pt, ln: (i, j, 0, 0)
-        ),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, hd), jnp.float32),
+            pltpu.VMEM((kvs, rows, 1), jnp.float32),
+            pltpu.VMEM((kvs, rows, 1), jnp.float32),
+            pltpu.VMEM((kvs, rows, hd), jnp.float32),
+            *buffers,
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            kernels[(quantized, treed)],
-            n_pages=n_pages, page_size=page_size,
-            window=w, group=g, scale=scale,
+            _kernel, ppb=ppb, page_size=page_size, max_pages=max_pages,
+            window=w, group=g, scale=1.0 / math.sqrt(hd),
+            quantized=quantized, treed=treed,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvs, rows, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), *inputs)
+        name="paged_decode_attention_pallas",
+    )(page_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
+      *inputs)
     if windowed:
         out = out.reshape(b, kvs, w, g, hd).transpose(0, 2, 1, 3, 4)
     return out

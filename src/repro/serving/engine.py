@@ -817,6 +817,24 @@ class Engine:
         self._m_accepted = m.counter(
             "tokens_accepted_total", "Draft tokens accepted by verification"
         )
+        # the paged-attention walk: pages each dispatch's rows hold against
+        # the pages its page table spans (their ratio is the share of a
+        # whole-table walk the kernel takes)
+        self._m_live_pages = m.counter(
+            "paged_attn_live_pages_total",
+            "Live pages the paged dispatches attend: per dispatch, the sum "
+            "over rows of cdiv(length, page_size)",
+            ("model",),
+        )
+        self._m_table_pages = m.counter(
+            "paged_attn_table_pages_total",
+            "Page-table slots of the paged dispatches: rows x table width "
+            "per dispatch",
+            ("model",),
+        )
+        for role in ("target", "draft"):
+            self._m_live_pages.labels(model=role).inc(0)
+            self._m_table_pages.labels(model=role).inc(0)
         self._m_table_upload = m.counter(
             "table_upload_seconds_total",
             "Host seconds uploading page tables / lengths (the only "
@@ -1130,6 +1148,20 @@ class Engine:
 
     # -- the stepwise round --------------------------------------------------
 
+    def _count_pages(self, role: str, lengths: np.ndarray, width: int) -> None:
+        """Advance the paged-walk counters for one dispatch of ``role``'s
+        model (once per storage kind it runs over): ``lengths`` are the rows'
+        host lengths before the dispatch (0 for rows it masks out), and the
+        dispatch writes ``width`` more tokens to every row."""
+        tables = self._t_tables if role == "target" else self._d_tables
+        page_size = (self._t_pool if role == "target" else self._d_pool).page_size
+        pages = np.minimum(-(-(lengths + width) // page_size), tables.max_pages)
+        kinds = len(self._kinds)
+        self._m_live_pages.labels(model=role).inc(kinds * int(pages.sum()))
+        self._m_table_pages.labels(model=role).inc(
+            kinds * lengths.size * tables.max_pages
+        )
+
     def _kvq_mask(self, active):
         """(B,) bool device mask — True where the row's KV is int8 — or
         ``None`` on single-kind engines (which then dispatch exactly the
@@ -1391,6 +1423,7 @@ class Engine:
                     self._d_step, self.draft.params, cur_dev[:, None],
                     self._d_store, d_table, d_len0 + j, kvq_dev,
                 )
+                self._count_pages("draft", self._d_tables.lengths + j, 1)
                 if j < round_dl and not any_sampled:
                     cur_dev = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
                     draft_cols.append(cur_dev)
@@ -1434,6 +1467,7 @@ class Engine:
                 self._t_step, self.target.params, jnp.asarray(window),
                 self._t_store, t_table, t_len0, kvq_dev,
             )
+            self._count_pages("target", self._t_tables.lengths, round_dl + 1)
         with span("engine", "verify_pull", round=rnd, bytes=v_logits.nbytes):
             p_logits = np.asarray(v_logits)  # (B, round_dl+1, V)
         self.tracer.rec(
@@ -1693,6 +1727,7 @@ class Engine:
                     self._d_tree_step, self.draft.params, tok_dev,
                     self._d_store, d_table, d_len0, kvq_dev, pos_dev, tm_dev,
                 )
+                self._count_pages("draft", self._d_tables.lengths, w)
             if j < round_depth:
                 with span("engine", "draft_pull", round=rnd, j=j,
                           bytes=logits.nbytes):
@@ -1715,6 +1750,7 @@ class Engine:
                 self._t_tree_step, self.target.params, tok_dev,
                 self._t_store, t_table, t_len0, kvq_dev, pos_dev, tm_dev,
             )
+            self._count_pages("target", self._t_tables.lengths, w)
         with span("engine", "verify_pull", round=rnd, bytes=v_logits.nbytes):
             p_logits = np.asarray(v_logits)  # (B, W, V)
         self.tracer.rec(
@@ -1869,6 +1905,7 @@ class Engine:
                     d_len[slot] = req.d_seq.length + req.pending_dl
                     d_mask[slot] = True
 
+                self._count_pages("draft", d_len, 1)
                 v_logits = None
                 if plan.verify_rows:
                     v_tok = np.zeros((b, wv), np.int32)
@@ -1893,6 +1930,7 @@ class Engine:
                             t_table, t_len_dev, d_table, d_len_dev,
                             vm_dev, dm_dev,
                         )
+                    self._count_pages("target", t_len, wv)
                     if kvq_dev is None:
                         v_logits, d_logits = vs[self._kinds[0]], ds[self._kinds[0]]
                     else:
@@ -2104,6 +2142,7 @@ class Engine:
                     d_len[slot] = req.d_seq.length
                     d_mask[slot] = True
 
+                self._count_pages("draft", d_len, w)
                 v_logits = None
                 if plan.verify_rows:
                     v_tok = np.zeros((b, w), np.int32)
@@ -2133,6 +2172,7 @@ class Engine:
                             self.target.params, self.draft.params, *heads,
                             self._t_store[k], self._d_store[k], *tails,
                         )
+                    self._count_pages("target", t_len, w)
                     if kvq_dev is None:
                         v_logits, d_logits = vs[self._kinds[0]], ds[self._kinds[0]]
                     else:

@@ -269,6 +269,44 @@ def test_engine_metrics_two_phase_and_round_acceptance(pair):
     assert m.value("table_upload_seconds_total") > 0
 
 
+def test_paged_walk_counters_advance_by_one_round(pair):
+    """One chain round of two requests in three rows: the draft runs dl + 1
+    steps of width 1 and the target one verify of width dl + 1, every row
+    starting from its prompt's length less one (the last prompt token is
+    the round's first input; an empty row from 0).  Each dispatch adds the
+    rows' cdiv(length, page_size) to the live counter and rows x table
+    width to the table counter."""
+    from repro.serving.paged_cache import pages_for
+
+    target, draft = pair
+    ps, rows = 8, 3
+    eng = Engine(target, draft, EngineConfig(max_batch=rows, page_size=ps))
+    prompts = [np.arange(1, 12, dtype=np.int32), np.arange(1, 21, dtype=np.int32)]
+    rids = [eng.add_request(p, SamplingParams(max_tokens=8)) for p in prompts]
+    eng.step()
+    dl = max(eng.request(r).drafted for r in rids)
+    assert dl > 0
+    base = np.zeros(rows, np.int64)
+    base[: len(prompts)] = [len(p) - 1 for p in prompts]
+    cdiv = lambda n: -(-n // ps)
+    width = pages_for(eng.max_model_len, ps)
+    m = eng.metrics
+    assert m.value("paged_attn_live_pages_total", model="draft") == sum(
+        cdiv(base + j + 1).sum() for j in range(dl + 1)
+    )
+    assert m.value("paged_attn_table_pages_total", model="draft") == (
+        (dl + 1) * rows * width
+    )
+    assert m.value("paged_attn_live_pages_total", model="target") == (
+        cdiv(base + dl + 1).sum()
+    )
+    assert m.value("paged_attn_table_pages_total", model="target") == rows * width
+    text = m.render()
+    for name in ("live", "table"):
+        for role in ("target", "draft"):
+            assert f'serving_paged_attn_{name}_pages_total{{model="{role}"}}' in text
+
+
 # ---------------------------------------------------------------------------
 # /metrics scrape through the real HTTP server
 # ---------------------------------------------------------------------------

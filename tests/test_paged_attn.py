@@ -5,7 +5,9 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.paged_attn import paged_decode_attention_pallas
+from repro.kernels.paged_attn import (
+    BLOCK_TOKENS, BUFFER_BUDGET, paged_decode_attention_pallas, pages_per_block,
+)
 from repro.models import layers as L
 
 
@@ -388,3 +390,110 @@ def test_tree_window_matches_oracle_property(data):
     got = paged_decode_attention_pallas(q, kp, vp, pt, lens, tree_mask=tm, **kw)
     want = ref.paged_attn_ref(q, kp, vp, pt, lens, tree_mask=tm, **kw)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The walk: only each row's live pages are read, in blocks of pages
+# ---------------------------------------------------------------------------
+
+WALK_KVS, WALK_G, WALK_HD, WALK_PS, WALK_W = 2, 2, 32, 16, 4
+# pages per block when the table is wide enough not to cap it
+WALK_PPB = pages_per_block(WALK_PS, WALK_KVS, WALK_HD, jnp.float32, 10**6)
+WALK_EDGE = WALK_PPB * WALK_PS  # tokens in one full block
+
+
+def _walk_case(variant, lengths, mp, seed):
+    """Pools whose every page outside the rows' live pages — the scratch
+    page (the pool's last), and a junk page each row's first dead table
+    slot points at — holds NaN (int8: NaN scales).  Returns the kernel's
+    output on those pools and the oracle's on the same pools before the
+    NaN went in."""
+    b = len(lengths)
+    w = 1 if variant == "decode" else WALK_W
+    rng = np.random.RandomState(seed)
+    live = [-(-n // WALK_PS) for n in lengths]
+    pool_pages = sum(live) + b + 1
+    scratch = pool_pages - 1
+    perm = iter(rng.permutation(scratch).tolist())
+    pt = np.full((b, mp), scratch, np.int32)
+    for i, n in enumerate(live):
+        pt[i, :n] = [next(perm) for _ in range(n)]
+    for i, n in enumerate(live):
+        if n < mp:
+            pt[i, n] = next(perm)  # a junk page, not the scratch page
+    used = {int(p) for i, n in enumerate(live) for p in pt[i, :n]}
+    dead = np.array(sorted(set(range(pool_pages)) - used))
+    qshape = (b, WALK_KVS, WALK_G, WALK_HD)
+    if w > 1:
+        qshape = (b, w) + qshape[1:]
+    q = jnp.asarray(rng.randn(*qshape).astype(np.float32))
+    pshape = (pool_pages, WALK_KVS, WALK_PS, WALK_HD)
+    kp = rng.randn(*pshape).astype(np.float32)
+    vp = rng.randn(*pshape).astype(np.float32)
+    kw = {}
+    if variant == "tree":
+        tm = np.stack([
+            tree_ancestor_mask(_random_parents(rng, w - 1), w) for _ in range(b)
+        ])
+        kw["tree_mask"] = jnp.asarray(tm)
+    pt, lens = jnp.asarray(pt), jnp.asarray(np.asarray(lengths, np.int32))
+    if variant == "int8":
+        kq, vq, ks, vs = _quantized_pools(kp, vp)
+        want = ref.paged_attn_ref(q, kq, vq, pt, lens, k_scale=ks, v_scale=vs)
+        got = paged_decode_attention_pallas(
+            q, kq, vq, pt, lens, k_scale=ks.at[dead].set(np.nan),
+            v_scale=vs.at[dead].set(np.nan),
+        )
+        return got, want
+    want = ref.paged_attn_ref(q, jnp.asarray(kp), jnp.asarray(vp), pt, lens, **kw)
+    kp[dead] = np.nan
+    vp[dead] = np.nan
+    got = paged_decode_attention_pallas(
+        q, jnp.asarray(kp), jnp.asarray(vp), pt, lens, **kw
+    )
+    return got, want
+
+
+WALK_CASES = {
+    # lengths one short of, at, and one past a full block of pages
+    "block_edges": ([WALK_EDGE - 1, WALK_EDGE, WALK_EDGE + 1], WALK_PPB + 2),
+    # a table width no block divides: full, ragged, and one-block rows
+    "ragged_table": (
+        [(2 * WALK_PPB + 1) * WALK_PS, (2 * WALK_PPB + 1) * WALK_PS - 3,
+         WALK_EDGE + 7],
+        2 * WALK_PPB + 1,
+    ),
+    # rows of length 0 beside long rows
+    "empty_rows": ([0, 2 * WALK_EDGE + 5, 0, WALK_EDGE], 2 * WALK_PPB + 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+@pytest.mark.parametrize("variant", ["decode", "verify", "int8", "tree"])
+def test_walk_reads_only_live_pages(variant, case):
+    """NaN everywhere past each row's live pages cannot reach the output;
+    rows of length 0 write zeros; every other row matches the oracle at
+    block edges and over a table width no block divides."""
+    lengths, mp = WALK_CASES[case]
+    assert mp % WALK_PPB, "the table width must leave a ragged last block"
+    got, want = _walk_case(variant, lengths, mp, seed=40 + len(case))
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got).all()
+    for i, n in enumerate(lengths):
+        if n == 0:
+            np.testing.assert_array_equal(got[i], 0.0)
+        else:
+            np.testing.assert_allclose(got[i], want[i], atol=2e-5)
+
+
+def test_pages_per_block_follows_the_shapes():
+    """Pages per copy come from the shapes alone: a block covers at most
+    BLOCK_TOKENS tokens and the table's width, and the double-buffered K and
+    V blocks fit the VMEM budget."""
+    assert pages_per_block(16, 8, 128, jnp.bfloat16, 129) * 16 <= BLOCK_TOKENS
+    assert pages_per_block(16, 8, 128, jnp.bfloat16, 3) == 3
+    for kvs, dtype in [(8, jnp.bfloat16), (32, jnp.bfloat16), (32, jnp.float32),
+                       (64, jnp.float32), (32, jnp.int8)]:
+        ppb = pages_per_block(16, kvs, 128, dtype, 145)
+        page = kvs * 16 * 128 * jnp.dtype(dtype).itemsize
+        assert ppb >= 1 and (ppb == 1 or 4 * ppb * page <= BUFFER_BUDGET)

@@ -2,8 +2,10 @@
 
 Nothing runs: each test lowers a Pallas kernel at the paper target's
 widths (llama2-7b: 32 kv heads of 128, 16-slot pages; the W4A8 down/up
-projection shapes), or at the served BVQ drafts' (Qwen3-0.6B: K 1024 and
-3072 over 128-column blocks of 256-entry codebooks; llama-68m: K 768;
+projection shapes), at the served cells' four paged-attention programs
+(Qwen3-8B / 0.6B and deepseek-coder-6.7b / 1.3b, verify and draft step),
+or at the served BVQ drafts' (Qwen3-0.6B: K 1024 and 3072 over
+128-column blocks of 256-entry codebooks; llama-68m: K 768;
 deepseek-coder-1.3b: K 5504), for a v5e that is described, not attached,
 and asserts that the compiled program calls the kernel
 (``tpu_custom_call``).  What interpret-mode tests cannot see — a block
@@ -58,26 +60,38 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("variant", ["decode", "verify", "int8", "tree"])
+# the served cells' four paged programs: (B, KVS, G, W, table pages)
+PROGRAMS = {
+    "qwen3-target": (16, 8, 4, 4, 129),
+    "qwen3-draft": (16, 8, 2, 1, 129),
+    "dscoder-target": (8, 32, 1, 4, 145),
+    "dscoder-draft": (8, 16, 1, 1, 145),
+}
+
+
+@pytest.mark.parametrize(
+    "variant", ["decode", "verify", "int8", "tree", *PROGRAMS]
+)
 def test_paged_attention_compiles_for_v5e(one_chip, variant):
+    b, kvs, g, w, max_pages = PROGRAMS.get(variant, (B, KVS, G, W, MAX_PAGES))
     s = lambda shape, dtype: _spec(one_chip, shape, dtype)
-    pool = s((PAGES, KVS, PAGE, HD), jnp.bfloat16)
-    table = s((B, MAX_PAGES), jnp.int32)
-    lengths = s((B,), jnp.int32)
-    q = s((B, W, KVS, G, HD), jnp.bfloat16)
+    pool = s((PAGES, kvs, PAGE, HD), jnp.bfloat16)
+    table = s((b, max_pages), jnp.int32)
+    lengths = s((b,), jnp.int32)
+    q = s((b, w, kvs, g, HD), jnp.bfloat16)
     attend = lambda *a, **kw: paged_decode_attention_pallas(
         *a, interpret=False, **kw
     )
-    if variant == "decode":
+    if variant == "decode" or (variant in PROGRAMS and w == 1):
         text = _compiled_text(
-            attend, s((B, KVS, G, HD), jnp.bfloat16), pool, pool, table,
+            attend, s((b, kvs, g, HD), jnp.bfloat16), pool, pool, table,
             lengths,
         )
-    elif variant == "verify":
+    elif variant in ("verify", *PROGRAMS):
         text = _compiled_text(attend, q, pool, pool, table, lengths)
     elif variant == "int8":
-        qpool = s((PAGES, KVS, PAGE, HD), jnp.int8)
-        scale = s((PAGES, KVS, PAGE, 1), jnp.float32)
+        qpool = s((PAGES, kvs, PAGE, HD), jnp.int8)
+        scale = s((PAGES, kvs, PAGE, 1), jnp.float32)
         text = _compiled_text(
             lambda q_, k, v, t, n, ks, vs: attend(
                 q_, k, v, t, n, k_scale=ks, v_scale=vs
@@ -87,8 +101,9 @@ def test_paged_attention_compiles_for_v5e(one_chip, variant):
     else:
         text = _compiled_text(
             lambda q_, k, v, t, n, m: attend(q_, k, v, t, n, tree_mask=m),
-            q, pool, pool, table, lengths, s((B, W, W), jnp.float32),
+            q, pool, pool, table, lengths, s((b, w, w), jnp.float32),
         )
+    assert "%paged_decode_attention_pallas" in text
     assert "tpu_custom_call" in text
 
 
